@@ -49,7 +49,7 @@ test:
 # tier-1 excludes as `slow` (multi-second hang injection / drain
 # subprocesses).  JAX_PLATFORMS=cpu: chaos scenarios are deterministic
 # CPU reproductions; real-hardware recovery is soaked separately via
-# `tools/soak.py --modes elastic` under tools/tpu_watch.py windows.
+# `tools/soak.py --modes elastic --platform default` on the chip.
 chaos-test: registry-smoke serve-smoke fleet-smoke guardrails-smoke rollover-smoke obs-smoke reshard-smoke
 	JAX_PLATFORMS=cpu python -m pytest tests/test_chaos.py \
 	    tests/test_materialize_chaos.py tests/test_failures.py \

@@ -126,11 +126,10 @@ def test_all_candidates_noise_returns_smallest(cache_dir, monkeypatch):
 
 
 def test_all_candidates_compile_failing_raises(cache_dir, monkeypatch):
-    # EVERY config crashing the compiler is systemic (broken helper
-    # env, a Mosaic bug) — tuning must not "succeed" with the smallest
-    # tile as if it had measured something.
+    # EVERY config failing to compile is systemic — tuning must not
+    # "succeed" with the smallest tile as if it had measured something.
     def boom(*a, **k):
-        raise autotune.BlockConfigError("tpu_compile_helper subprocess exit code 1")
+        raise autotune.BlockConfigError("exceeded scoped vmem limit")
 
     monkeypatch.setattr(autotune, "_measure", boom)
     with pytest.raises(autotune.BlockConfigError):
@@ -176,10 +175,11 @@ def test_vmem_trigger_reports_matched_substring():
     assert autotune._vmem_trigger(
         RuntimeError("Scoped allocation with size 9 exceeded the limit")
     ) == "Scoped allocation"
+    # A compile crash that does not name vmem is a bug, not a block
+    # size to step down from.
     assert autotune._vmem_trigger(
-        RuntimeError("HTTP 500: tpu_compile_helper subprocess exit code 1")
-    ) == "tpu_compile_helper subprocess exit code"
-    assert autotune._vmem_trigger(RuntimeError("connection reset")) is None
+        RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+    ) is None
     assert autotune._is_vmem_error(RuntimeError("VMEM overflow"))
     assert not autotune._is_vmem_error(RuntimeError("RESOURCE_EXHAUSTED: HBM"))
 
@@ -224,10 +224,10 @@ def test_timed_loop_non_vmem_error_propagates(monkeypatch):
     def scripted(carry, n):
         calls["n"] += 1
         if calls["n"] > 2:
-            raise RuntimeError("tunnel reset by peer")
+            raise RuntimeError("INTERNAL: device halted")
         return 0.0
 
     monkeypatch.setattr(autotune, "jax", _ScriptedJit(scripted))
     q = k = v = jnp.zeros((1, 8, 1, 8), jnp.float32)
-    with pytest.raises(RuntimeError, match="tunnel reset"):
+    with pytest.raises(RuntimeError, match="device halted"):
         autotune._measure(lambda *c: c, q, k, v)

@@ -302,11 +302,14 @@ d = sys.argv[1]
 
 def stepf(state, batch):
     time.sleep(0.15)
+    # "started" means the LOOP is running: run_elastic's own set-up
+    # (checkpoint library imports, about 1 s on this installation) comes
+    # first, and a SIGTERM that lands inside it drains at step 0.
+    with open(os.path.join(d, "started"), "w") as f:
+        f.write("1")
     return {"x": state["x"] + batch}, {}
 
 batches = [jnp.float32(i) for i in range(1, 41)]
-with open(os.path.join(d, "started"), "w") as f:
-    f.write("1")
 run_elastic(stepf, {"x": jnp.float32(0.0)}, batches,
             checkpoint_dir=d, checkpoint_every=100, exit_on_drain=True)
 print("RAN-TO-COMPLETION")  # only reachable if the signal was missed

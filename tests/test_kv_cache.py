@@ -23,7 +23,7 @@ def test_config_math():
     assert cfg.pages_for(1) == 1
     assert cfg.pages_for(4) == 1
     assert cfg.pages_for(5) == 2
-    assert cfg.pool_shape() == (2, 8, 4, 2, 8)
+    assert cfg.pool_shape() == (2, 8, 2, 4, 8)  # [L, P, KV, page, D]
 
 
 def test_null_page_reserved_and_validation():

@@ -396,7 +396,7 @@ class TestTraceCLI:
             time.sleep(0.002)
         observe.counter("tdx.jax.compile_cache_hit").inc(3)
         observe.counter("tdx.jax.compile_cache_miss").inc()
-        observe.counter("tdx.bench.platform_fallback").inc()
+        observe.counter("tdx.ops.interpreted_calls").inc()
         d = tmp_path / "traces"
         observe.flush(trace_dir=str(d))
         return d
@@ -412,7 +412,7 @@ class TestTraceCLI:
         assert "jax.compile" in out.stdout
         assert "3 hit / 1 miss" in out.stdout
         assert "75% hit ratio" in out.stdout
-        assert "platform fallbacks: 1" in out.stdout
+        assert "interpreted kernel calls: 1" in out.stdout
 
     def test_chrome_merge(self, telemetry, tmp_path):
         d = self._make_trace_dir(tmp_path)

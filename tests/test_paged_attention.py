@@ -18,11 +18,17 @@ from torchdistx_tpu.ops import (
 from torchdistx_tpu.models.layers import default_attention
 
 
+def _to_pool(tok_major):
+    """Token-major pages [P, page, KV, D] -> the pool layout
+    [P, KV, page, D] the kernel and the serving programs use."""
+    return tok_major.transpose(0, 2, 1, 3)
+
+
 def _rand_case(seed, *, B, H, KV, D, page, n_pages, maxp, lengths, dtype):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(B, H, D), dtype)
-    kp = jnp.asarray(rng.randn(n_pages, page, KV, D), dtype)
-    vp = jnp.asarray(rng.randn(n_pages, page, KV, D), dtype)
+    kp = _to_pool(jnp.asarray(rng.randn(n_pages, page, KV, D), dtype))
+    vp = _to_pool(jnp.asarray(rng.randn(n_pages, page, KV, D), dtype))
     # Page tables point at a shuffled, non-overlapping page assignment —
     # physical discontiguity is the point of the paged layout.
     perm = rng.permutation(n_pages - 1) + 1  # never the null page
@@ -94,7 +100,7 @@ def test_matches_flash_attention_contiguous_single_page(dtype, atol):
     k = jnp.asarray(rng.randn(B, S, KV, D), dtype)
     v = jnp.asarray(rng.randn(B, S, KV, D), dtype)
     table = jnp.arange(B, dtype=jnp.int32)[:, None]
-    out = paged_attention(qf[:, -1], k, v,
+    out = paged_attention(qf[:, -1], _to_pool(k), _to_pool(v),
                           jnp.full((B,), S, jnp.int32), table)
     fl = flash_attention(qf, k, v, causal=True)[:, -1]
     np.testing.assert_allclose(
@@ -128,7 +134,7 @@ def test_matches_dense_attention_ragged_lengths():
                 v[b, lo: lo + page])
     q_last = jnp.stack([qf[b, L - 1] for b, L in enumerate(lengths)])
     out = paged_attention(
-        q_last, jnp.asarray(kp), jnp.asarray(vp),
+        q_last, _to_pool(jnp.asarray(kp)), _to_pool(jnp.asarray(vp)),
         jnp.asarray(lengths, jnp.int32), jnp.asarray(table),
     )
     for b, L in enumerate(lengths):
@@ -151,8 +157,9 @@ def test_reference_gqa_grouping_matches_per_head_loop():
     )
     ref = paged_attention_reference(q, kp, vp, lens, table)
     groups = H // KV
-    k = kp[table].reshape(B, maxp * page, KV, D)
-    v = vp[table].reshape(B, maxp * page, KV, D)
+    # Undo the pool layout by hand: [B, maxp, KV, page, D] -> token-major.
+    k = kp[table].transpose(0, 1, 3, 2, 4).reshape(B, maxp * page, KV, D)
+    v = vp[table].transpose(0, 1, 3, 2, 4).reshape(B, maxp * page, KV, D)
     for b in range(B):
         L = int(lens[b])
         for h in range(H):
@@ -168,7 +175,7 @@ def test_reference_gqa_grouping_matches_per_head_loop():
 
 def test_shape_validation():
     q = jnp.zeros((2, 4, 8))
-    kp = jnp.zeros((4, 8, 2, 8))
+    kp = jnp.zeros((4, 2, 8, 8))
     lens = jnp.zeros((2,), jnp.int32)
     table = jnp.zeros((2, 2), jnp.int32)
     with pytest.raises(ValueError, match="multiple of KV heads"):

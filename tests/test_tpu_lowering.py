@@ -7,14 +7,18 @@ BlockSpec that real TPUs reject (ADVICE r1).  ``jax.export`` with
 tiling rules, layout checks, kernel jaxpr lowering) on a CPU-only host,
 so every kernel flavor gets its TPU lowering exercised in CI even though
 no chip is present.  (The final Mosaic→binary compile still only happens
-on hardware; the bench phases cover that.)
+on hardware; ``chip_smoke.py`` covers that.)
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from torchdistx_tpu.ops import flash_attention
+from torchdistx_tpu.models import LLAMA3_8B
+from torchdistx_tpu.ops import flash_attention, paged_attention
+from torchdistx_tpu.serve import ServeConfig, serve_program_specs
 
 B, S, H, D = 2, 512, 8, 64
 
@@ -103,3 +107,45 @@ def test_flash_bias_and_segments_lower_for_tpu(bias_heads):
         return out, grads
 
     assert _export(fwd_and_grads, q, k, v, bias, seg).mlir_module()
+
+
+# -- serving decode kernel ----------------------------------------------------
+#
+# The guard PR 7 lacked: the flash kernels above were cross-lowered from
+# the start, the paged decode kernel never was, and its [P, page, KV, D]
+# pool put a one-kv-head block on the second-minor dim — refused by the
+# Mosaic lowering for every KV > 1, unnoticed while the kernel only ever
+# ran interpreted.
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("page", [8, 16, 128])
+@pytest.mark.parametrize("H,KV,D", [(32, 8, 128),    # chip_smoke / Llama-3-8B
+                                    (12, 12, 64)])   # GPT-2
+def test_paged_attention_lowers_for_tpu(H, KV, D, page, dtype):
+    B, P, maxp = 4, 64, 8
+    q = jax.ShapeDtypeStruct((B, H, D), dtype)
+    pool = jax.ShapeDtypeStruct((P, KV, page, D), dtype)
+    lens = jax.ShapeDtypeStruct((B,), jnp.int32)
+    table = jax.ShapeDtypeStruct((B, maxp), jnp.int32)
+    fn = functools.partial(paged_attention, interpret=False)
+    assert _export(fn, q, pool, pool, lens, table).mlir_module()
+
+
+@pytest.mark.parametrize("program", ["decode", "verify-2"])
+def test_serving_program_lowers_for_tpu(program, monkeypatch):
+    # The whole program as the replica compiles it (2-layer Llama-width
+    # config, bf16 params): scatter into the pool, kernel, head.  The
+    # kernel resolves its own interpret mode inside the program, so the
+    # backend it consults is pinned to "tpu" for the trace.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LLAMA3_8B.replace(n_layers=2)
+    scfg = ServeConfig(max_batch=4, page_size=16, n_pages=16,
+                       max_pages_per_seq=4, prefill_buckets=(16,))
+    specs = {s.name: s for s in serve_program_specs(
+        "llama", cfg, scfg, param_dtype=jnp.bfloat16, include_init=False)}
+    spec = specs[program]
+    module = _export(spec.fn, *spec.args).mlir_module()
+    # decode carries the compiled kernel; verify attends through the
+    # gather-based jnp path and must lower without one.
+    assert ("tpu_custom_call" in module) == (program == "decode")

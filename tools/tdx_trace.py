@@ -163,7 +163,6 @@ def _slo_digest(counters: Dict[str, float], indent: str = "  ") -> List[str]:
 
 def summarize(events: List[dict], top: int = 15) -> str:
     spans = [e for e in events if e.get("ph") == "X"]
-    instants = [e for e in events if e.get("ph") == "i"]
     counters = _final_counters(events)
     lines: List[str] = []
 
@@ -295,16 +294,12 @@ def summarize(events: List[dict], top: int = 15) -> str:
     if dumps:
         lines.append(f"flight-recorder dumps: {int(dumps)}")
 
-    # Counter preferred; the instant events are the same occurrences
-    # (counting both would double), and only the exact platform event
-    # qualifies — bench.cache_fallback is a different condition.
-    fallbacks = counters.get("tdx.bench.platform_fallback")
-    if fallbacks is None:
-        fallbacks = sum(
-            1 for e in instants
-            if e.get("name") == "bench.platform_fallback"
-        )
-    lines.append(f"platform fallbacks: {int(fallbacks)}")
+    # The one way a run ends up off the chip without failing: pallas
+    # kernels resolved to interpret mode (ops/_interpret.py).
+    lines.append(
+        "interpreted kernel calls: "
+        f"{int(counters.get('tdx.ops.interpreted_calls', 0))}"
+    )
     verify = sum(
         v for k, v in counters.items()
         if k.startswith("tdx.graph.verify_failures")

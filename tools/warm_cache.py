@@ -2,7 +2,12 @@
 
 The cold half of the north-star workflow: a login host deferred-inits a
 model (fakes, zero storage), lowers its init programs, and compiles them
-into the persistent cache directory (``--cache-dir`` / TDX_CACHE_DIR).  A
+into the persistent cache directory (``--cache-dir``; default: wherever
+``torchdistx_tpu.config.compile_cache_dir()`` resolves —
+``JAX_COMPILATION_CACHE_DIR``, else ``TDX_CACHE_DIR``, else
+``<checkout>/.jax_cache``).  A cache placed from outside with
+``JAX_COMPILATION_CACHE_DIR`` is the only one the program will bind, so
+a different ``--cache-dir`` is refused rather than silently ignored.  A
 later ``materialize_module_jax`` on any host sharing that cache — the pod
 restart path, a CI cold start — then hits every entry instead of paying
 XLA compilation, the dominant cost of the cold path.
@@ -56,8 +61,8 @@ Usage::
 
 Cache-key caveats: entries are keyed on backend, topology, and compile
 options — warm on the platform (and device count) the consumer will see.
-XLA:CPU entries are additionally host-ISA-specific AOT code (bench.py
-partitions its CPU cache by ISA tag for exactly this reason).  The
+XLA:CPU entries are additionally host-ISA-specific AOT code: do not
+carry a CPU-warmed directory to a host with another CPU.  The
 registry composes the same identity into its keys (``registry.env_key``),
 so a mismatched fetch is impossible by construction.
 """
@@ -83,8 +88,9 @@ def _parse_args(argv):
                    help="custom factory 'pkg.mod:fn' returning an "
                         "(eagerly constructible) torch.nn.Module; recorded "
                         "under deferred_init")
-    p.add_argument("--cache-dir", required=True,
-                   help="persistent compilation cache directory to fill")
+    p.add_argument("--cache-dir", default=None,
+                   help="persistent compilation cache directory to fill "
+                        "(default: config.compile_cache_dir())")
     p.add_argument("--mesh", default=None,
                    help="mesh axes, e.g. fsdp=4,tp=2 (omit for single-device)")
     p.add_argument("--plan", default="fsdp", choices=("fsdp", "gspmd2d"),
@@ -109,9 +115,12 @@ def _parse_args(argv):
     p.add_argument("--host-id", type=int, default=0,
                    help="this host's 0-based id in [0, hosts)")
     p.add_argument("--spawn-shards", action="store_true",
-                   help="single-machine pod rehearsal: spawn all --hosts "
-                        "shard invocations as concurrent subprocesses "
-                        "(each gets its --host-id), hand each the causal "
+                   help="single-machine pod rehearsal ON THE CPU: spawn "
+                        "all --hosts shard invocations as concurrent "
+                        "subprocesses pinned to the cpu platform (a chip "
+                        "belongs to one process; N children taking the "
+                        "default backend would fight over it), each with "
+                        "its --host-id; hand each the causal "
                         "trace context (TDX_TRACE_PARENT), and exit "
                         "non-zero if any shard does — the merged Chrome "
                         "trace then draws flow arrows from this parent's "
@@ -222,26 +231,6 @@ def _probe_cache_dir(cache_dir: str) -> None:
         ) from e
 
 
-class _persist_everything:
-    """The tool exists to persist: never let jax's 0.1 s min-compile-time
-    threshold silently skip writing the fast-compiling programs this run
-    claims to have warmed (explicit env wins; the prior value is
-    restored on exit — the warm entry points are documented as
-    importable, and an in-process caller must keep the documented
-    persist boundary).  Publishing rides on the same boundary: only
-    persisted entries can be published to the registry."""
-
-    def __enter__(self):
-        self._prior = os.environ.get("TDX_CACHE_MIN_COMPILE_S")
-        os.environ.setdefault("TDX_CACHE_MIN_COMPILE_S", "0")
-
-    def __exit__(self, *exc):
-        if self._prior is None:
-            os.environ.pop("TDX_CACHE_MIN_COMPILE_S", None)
-        else:
-            os.environ["TDX_CACHE_MIN_COMPILE_S"] = self._prior
-
-
 def warm(factory, cache_dir, *, mesh=None, plan=None, param_dtype=None,
          skip_whole=False, skip_groups=False, registry_dir=None,
          hosts=1, host_id=0, steal_after_s=120.0, poll_s=0.5) -> dict:
@@ -254,14 +243,13 @@ def warm(factory, cache_dir, *, mesh=None, plan=None, param_dtype=None,
     from torchdistx_tpu.registry import warm_sharded
 
     _probe_cache_dir(cache_dir)
-    with _persist_everything():
-        return warm_sharded(
-            factory, cache_dir, registry_dir=registry_dir,
-            hosts=hosts, host_id=host_id, mesh=mesh, plan=plan,
-            param_dtype=param_dtype, skip_whole=skip_whole,
-            skip_groups=skip_groups, steal_after_s=steal_after_s,
-            poll_s=poll_s,
-        )
+    return warm_sharded(
+        factory, cache_dir, registry_dir=registry_dir,
+        hosts=hosts, host_id=host_id, mesh=mesh, plan=plan,
+        param_dtype=param_dtype, skip_whole=skip_whole,
+        skip_groups=skip_groups, steal_after_s=steal_after_s,
+        poll_s=poll_s,
+    )
 
 
 def warm_decode(model_name, cache_dir, *, registry_dir=None, serve_cfg=None,
@@ -285,12 +273,11 @@ def warm_decode(model_name, cache_dir, *, registry_dir=None, serve_cfg=None,
             f"{sorted(k for k, v in PRESETS.items() if isinstance(v, TransformerConfig) and v.moe is None)})"
         )
     _probe_cache_dir(cache_dir)
-    with _persist_everything():
-        return warm_serving(
-            model_family(model_name), cfg, cache_dir,
-            registry_dir=registry_dir, serve_cfg=serve_cfg, seed=seed,
-            param_dtype=param_dtype, mesh=mesh, plan=plan,
-        )
+    return warm_serving(
+        model_family(model_name), cfg, cache_dir,
+        registry_dir=registry_dir, serve_cfg=serve_cfg, seed=seed,
+        param_dtype=param_dtype, mesh=mesh, plan=plan,
+    )
 
 
 def _spawn_shards(args, argv) -> None:
@@ -333,6 +320,9 @@ def _spawn_shards(args, argv) -> None:
             flow_id = (tracectx.flow_start("warm.spawn_shard")
                        if observe.enabled() else None)
             env = tracectx.child_env(flow_id)
+            # A rehearsal, not a device warm: concurrent children that
+            # each took the default backend would contend for one chip.
+            env["JAX_PLATFORMS"] = "cpu"
             procs.append(subprocess.Popen(
                 [sys.executable, script, *base, "--host-id", str(host_id)],
                 env=env,
@@ -374,6 +364,21 @@ def main(argv=None) -> None:
 
         param_dtype = getattr(jnp, args.param_dtype)
 
+    from torchdistx_tpu import config as tdx_config
+
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if args.cache_dir is None:
+        args.cache_dir = tdx_config.compile_cache_dir()
+        if not args.cache_dir:
+            raise SystemExit('no cache directory: TDX_CACHE_DIR="" disables '
+                             "the cache; pass --cache-dir")
+    elif placed and os.path.realpath(placed) != os.path.realpath(
+            args.cache_dir):
+        raise SystemExit(
+            f"JAX_COMPILATION_CACHE_DIR={placed!r} places the compile cache "
+            f"from outside; warming --cache-dir {args.cache_dir!r} instead "
+            f"is not possible (unset the variable or drop --cache-dir)"
+        )
     os.makedirs(args.cache_dir, exist_ok=True)
     if args.decode:
         if args.model is None:

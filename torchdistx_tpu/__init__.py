@@ -38,19 +38,17 @@ def _read_version() -> str:
 __version__ = _read_version()
 
 # Deferred init promises the SAME parameter values whatever mesh they
-# materialize onto.  jax 0.4.x still defaults to the legacy
-# (non-partitionable) threefry, and under XLA:CPU SPMD a jitted
-# random.normal with sharded out_shardings actually produces different
-# draws per sharding — breaking that promise (and any cross-mesh loss
-# oracle built on it).  Partitionable threefry is sharding-invariant by
-# construction and is the default on newer jax; opt in explicitly.
+# materialize onto.  Only the partitionable threefry is sharding-invariant
+# by construction: under the legacy one a jitted random.normal with sharded
+# out_shardings produces different draws per sharding — breaking that
+# promise (and any cross-mesh loss oracle built on it).  It is jax's
+# default; pin it so an environment override cannot void the contract.
 def _configure_jax() -> None:
     try:
         import jax
-
-        jax.config.update("jax_threefry_partitionable", True)
-    except Exception:  # jax absent (pure-torch-frontend installs): fine
-        pass
+    except ImportError:  # pure-torch-frontend installs carry no jax
+        return
+    jax.config.update("jax_threefry_partitionable", True)
 
 
 _configure_jax()

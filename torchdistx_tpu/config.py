@@ -17,7 +17,12 @@ Environment variables (read at first import):
 ``TDX_NATIVE``          "0" disables the C++ graph engine (default on when
                         the library is built).
 ``TDX_CACHE_DIR``       Persistent XLA compilation-cache directory used by
-                        the jax bridge's materializers ("" disables).
+                        the compile service (materializers and serving
+                        programs).  Unset means ``<checkout>/.jax_cache``;
+                        "" disables.  ``JAX_COMPILATION_CACHE_DIR``, when
+                        set, overrides both — a cache placed from outside
+                        is never re-pointed in code (see
+                        :func:`compile_cache_dir`).
 ``TDX_REGISTRY_DIR``    Shared compile-artifact registry directory
                         (:mod:`torchdistx_tpu.registry`): when set (and a
                         local ``TDX_CACHE_DIR`` is bound), both
@@ -209,7 +214,21 @@ import threading
 from dataclasses import dataclass, replace
 from typing import Iterator, Optional
 
-__all__ = ["Config", "bind", "expand_path", "get", "override", "set_flags"]
+__all__ = [
+    "Config",
+    "bind",
+    "compile_cache_dir",
+    "expand_path",
+    "get",
+    "override",
+    "set_flags",
+]
+
+# The checkout root (the package's parent directory): what the program
+# compiles is built from the files there and cached beside them.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
 @dataclass(frozen=True)
@@ -245,7 +264,7 @@ class Config:
 
 
 def _from_env() -> Config:
-    cache = os.environ.get("TDX_CACHE_DIR", "")
+    cache = os.environ.get("TDX_CACHE_DIR", _DEFAULT_CACHE_DIR)
     return Config(
         native=os.environ.get("TDX_NATIVE", "1") != "0",
         cache_dir=cache or None,
@@ -311,6 +330,17 @@ def expand_path(path: Optional[str]) -> Optional[str]:
     if "%p" in path:
         path = path.replace("%p", str(os.getpid()))
     return path
+
+
+def compile_cache_dir() -> Optional[str]:
+    """THE persistent compile-cache directory, resolved in one place:
+    ``JAX_COMPILATION_CACHE_DIR`` if set (whoever placed the cache from
+    outside — the chip tool, a CI runner — finds its entries again only
+    if nothing re-points it, and the path is part of no key but of every
+    lookup); else the effective ``cache_dir`` (``TDX_CACHE_DIR`` or an
+    :func:`override`; unset env means ``<checkout>/.jax_cache``); None
+    when that was disabled with ``""``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or get().cache_dir
 
 
 def get() -> Config:

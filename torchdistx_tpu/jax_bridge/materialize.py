@@ -177,10 +177,30 @@ _cache_enabled = False
 _cache_latch_lock = threading.Lock()
 
 
+def _bind_cache_dir(cache_dir: Optional[str]) -> None:
+    """Point jax's persistent compilation cache at ``cache_dir`` (None
+    unbinds).  ``jax_persistent_cache_enable_xla_caches="none"`` goes
+    with every binding: at its default jax embeds the cache-dir PATH
+    into CompileOptions (the XLA-side autotune/kernel caches, GPU-only
+    amenities), which makes the compile-cache key a function of the
+    local path — a cache warmed under one directory (a login host, the
+    artifact registry's install target) could then never be hit from
+    another.  jax memoizes a once-per-process "cache used?" decision at
+    the FIRST compile, so any compile before the binding (even the
+    PRNGKey seed computation) latches it to "unused"; ``reset_cache()``
+    un-latches it so the directory set here actually binds."""
+    from jax._src import compilation_cache as _cc
+
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    _cc.reset_cache()
+
+
 def _maybe_enable_cache() -> None:
-    """Point jax's persistent compilation cache at config.cache_dir
-    (TDX_CACHE_DIR) so repeated materializations of the same model skip
-    XLA compilation — the dominant cost of the cold path.  Guarded: the
+    """Bind jax's persistent compilation cache to the directory
+    :func:`..config.compile_cache_dir` resolves, so repeated
+    materializations and replica bring-ups of the same model skip XLA
+    compilation — the dominant cost of the cold path.  Guarded: the
     pipelined engine's workers must not race the once-per-process latch."""
     global _cache_enabled
     with _cache_latch_lock:
@@ -188,62 +208,37 @@ def _maybe_enable_cache() -> None:
             return
         from .. import config
 
-        cache_dir = config.get().cache_dir
+        cache_dir = config.compile_cache_dir()
         if cache_dir:
             _install_cache_guard()
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # jax ≥0.4.36 embeds the cache-dir PATH into CompileOptions
-            # (debug_options.xla_gpu_per_fusion_autotune_cache_dir) when
-            # the persistent cache is on — which makes the compile-cache
-            # key a function of the LOCAL PATH, so a cache warmed under
-            # one directory (a login host, the artifact registry's
-            # install target) could never be hit from another.  The
-            # XLA-side caches are GPU-only amenities; disable them so
-            # cache keys are path-independent and cross-host stable.
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_enable_xla_caches", "none"
-                )
-            except Exception:
-                pass
-            # TDX_CACHE_MIN_COMPILE_S=0 persists even trivial programs —
-            # tests use it to exercise the compile-cache hit/miss telemetry
-            # deterministically with toy models.
+            # Persist every program, however fast it compiled: jax's own
+            # 0.1 s threshold would leave a quick one (the serving `cow`
+            # program compiles in under that on a v5e) a "miss" on every
+            # bring-up, and "second bring-up: all hit, zero local
+            # compiles" must not depend on a program being slow to
+            # compile.  TDX_CACHE_MIN_COMPILE_S restores a threshold.
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs",
-                float(os.environ.get("TDX_CACHE_MIN_COMPILE_S", "0.1")),
+                float(os.environ.get("TDX_CACHE_MIN_COMPILE_S", "0")),
             )
-            # jax memoizes a once-per-process "cache used?" decision at the
-            # FIRST compile; any compile before this point (even the
-            # PRNGKey seed computation) latches it to "unused" and every
-            # later materialize silently skips the cache.  reset_cache()
-            # un-latches so the dir set above actually binds.
-            try:
-                from jax._src import compilation_cache as _cc
-
-                _cc.reset_cache()
-            except Exception:
-                pass
+            _bind_cache_dir(cache_dir)
             _cache_enabled = True
 
 
 def _reset_cache_binding() -> None:
-    """Un-latch the cache binding so the NEXT materialize re-reads
-    config.cache_dir (tests, tools/warm_cache.py, and bench variants
-    that switch cache dirs mid-process; normal runs never need this).
-    Also unbinds the jax-level directory: a later materialize with no
-    cache configured must report ``uncached`` and stop persisting into
-    the previously bound dir, not keep using it by inertia."""
+    """Un-latch the cache binding so the NEXT materialize re-resolves
+    the cache directory (tests and tools/warm_cache.py switch
+    ``cache_dir`` mid-process; normal runs never need this).  Also
+    unbinds the jax-level directory: a later materialize with the cache
+    disabled must report ``uncached`` and stop persisting into the
+    previously bound dir, not keep using it by inertia.  A directory
+    placed from outside (``JAX_COMPILATION_CACHE_DIR``) is the one
+    binding there is and is never unbound or replaced."""
     global _cache_enabled
     with _cache_latch_lock:
         _cache_enabled = False
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            _bind_cache_dir(None)
 
 
 # -- corrupt-cache quarantine ------------------------------------------------
@@ -259,7 +254,7 @@ def _reset_cache_binding() -> None:
 # reported as a miss so the ladder recompiles and re-persists a clean
 # entry in its place.
 
-_cache_guard_state: Optional[bool] = None  # None = not yet attempted
+_cache_guard_installed = False
 _cache_guard_lock = threading.Lock()
 
 
@@ -267,7 +262,7 @@ def _quarantine_cache_entry(cache_key: str) -> List[str]:
     """Rename the on-disk entry file(s) for ``cache_key`` to
     ``<name>.corrupt``; returns the names moved (empty when no cache dir
     is bound or the entry has already vanished)."""
-    d = getattr(jax.config, "jax_compilation_cache_dir", None)
+    d = jax.config.jax_compilation_cache_dir
     if not d:
         return []
     moved: List[str] = []
@@ -296,7 +291,8 @@ def _note_cache_key(cache_key: str) -> None:
         rec.append(cache_key)
 
 
-def _registry_direct_serve(cache_key, compile_options, backend):
+def _registry_direct_serve(cache_key, compile_options, backend,
+                           executable_devices):
     """Serve the current compile's executable straight from the fetched
     registry artifact when the local cache load missed.
 
@@ -322,18 +318,19 @@ def _registry_direct_serve(cache_key, compile_options, backend):
                 _cc.decompress_executable(data)
             )
             executable = backend.deserialize_executable(
-                serialized, compile_options
+                serialized, executable_devices, compile_options
             )
-        except Exception as e:  # noqa: BLE001 — wrong/unloadable payload
-            get_logger().debug(
+        except jax.errors.JaxRuntimeError as e:
+            # A payload XLA refuses to load (another topology's artifact,
+            # a truncated blob the CRC did not cover): try the next one,
+            # else the caller compiles.
+            get_logger().warning(
                 "registry: direct-serve payload rejected (%s: %s)",
                 type(e).__name__, str(e)[:120],
             )
             continue
-        d = getattr(jax.config, "jax_compilation_cache_dir", None)
+        d = jax.config.jax_compilation_cache_dir
         if d:
-            # LRUCache naming; on a jax whose cache stores bare keys the
-            # healed file is inert junk, and direct-serve still served.
             dst = os.path.join(d, f"{cache_key}-cache")
             tmp = f"{dst}.tdx-tmp-{os.getpid()}-{threading.get_ident()}"
             try:
@@ -354,63 +351,66 @@ def _registry_direct_serve(cache_key, compile_options, backend):
     return None, None
 
 
-def _install_cache_guard() -> bool:
+def _install_cache_guard() -> None:
     """Wrap ``jax._src.compilation_cache.get_executable_and_time`` with
     the quarantine-on-corrupt behavior (plus cache-key recording for the
-    artifact registry, also hooked into ``put_executable_and_time``);
-    installed once per process, a no-op when jax's internals moved
-    (False)."""
-    global _cache_guard_state
+    artifact registry, also hooked into ``put_executable_and_time``, and
+    the ladder's per-thread cache bypass); installed once per process.
+    Written against the installed jax's signatures: a moved internal is
+    an ImportError/AttributeError here, not a silently cold cache."""
+    global _cache_guard_installed
     with _cache_guard_lock:
-        if _cache_guard_state is not None:
-            return _cache_guard_state
-        try:
-            from jax._src import compilation_cache as _cc
+        if _cache_guard_installed:
+            return
+        from jax._src import compilation_cache as _cc
 
-            _orig = _cc.get_executable_and_time
-            _orig_put = _cc.put_executable_and_time
+        _orig = _cc.get_executable_and_time
+        _orig_put = _cc.put_executable_and_time
 
-            def _recording_put(cache_key, module_name, executable, backend,
-                               compile_time):
-                _note_cache_key(cache_key)
-                return _orig_put(cache_key, module_name, executable,
-                                 backend, compile_time)
+        def _recording_put(cache_key, module_name, executable, backend,
+                           compile_time):
+            if getattr(_mon_tls, "bypass", False):
+                return None  # the fresh-compile rung persists nothing
+            _note_cache_key(cache_key)
+            return _orig_put(cache_key, module_name, executable, backend,
+                             compile_time)
 
-            def _guarded(cache_key, compile_options, backend):
-                _note_cache_key(cache_key)
-                try:
-                    result = _orig(cache_key, compile_options, backend)
-                except Exception as e:  # noqa: BLE001 — any load error
-                    moved = _quarantine_cache_entry(cache_key)
-                    observe.counter("tdx.jax.cache_quarantined").inc(
-                        max(1, len(moved))
-                    )
-                    observe.instant(
-                        "jax.cache_quarantined", category="jax",
-                        key=cache_key, error=f"{type(e).__name__}: {e}"[:200],
-                        moved=len(moved),
-                    )
-                    get_logger().warning(
-                        "materialize: corrupt persistent-cache entry %s "
-                        "(%s: %s); quarantined %s and recompiling",
-                        cache_key, type(e).__name__, str(e)[:120],
-                        [m + ".corrupt" for m in moved] or "(file gone)",
-                    )
-                    result = (None, None)  # a miss: the caller recompiles
-                if result[0] is None:
-                    # Local miss (or quarantine): a verified registry
-                    # artifact staged for this compile serves it directly.
-                    result = _registry_direct_serve(
-                        cache_key, compile_options, backend
-                    )
-                return result
+        def _guarded(cache_key, compile_options, backend,
+                     executable_devices):
+            if getattr(_mon_tls, "bypass", False):
+                return None, None  # the fresh-compile rung reads nothing
+            _note_cache_key(cache_key)
+            try:
+                result = _orig(cache_key, compile_options, backend,
+                               executable_devices)
+            except Exception as e:  # noqa: BLE001 — any load error
+                moved = _quarantine_cache_entry(cache_key)
+                observe.counter("tdx.jax.cache_quarantined").inc(
+                    max(1, len(moved))
+                )
+                observe.instant(
+                    "jax.cache_quarantined", category="jax",
+                    key=cache_key, error=f"{type(e).__name__}: {e}"[:200],
+                    moved=len(moved),
+                )
+                get_logger().warning(
+                    "materialize: corrupt persistent-cache entry %s "
+                    "(%s: %s); quarantined %s and recompiling",
+                    cache_key, type(e).__name__, str(e)[:120],
+                    [m + ".corrupt" for m in moved] or "(file gone)",
+                )
+                result = (None, None)  # a miss: the caller recompiles
+            if result[0] is None:
+                # Local miss (or quarantine): a verified registry
+                # artifact staged for this compile serves it directly.
+                result = _registry_direct_serve(
+                    cache_key, compile_options, backend, executable_devices
+                )
+            return result
 
-            _cc.get_executable_and_time = _guarded
-            _cc.put_executable_and_time = _recording_put
-            _cache_guard_state = True
-        except Exception:  # pragma: no cover — jax internals moved
-            _cache_guard_state = False
-        return _cache_guard_state
+        _cc.get_executable_and_time = _guarded
+        _cc.put_executable_and_time = _recording_put
+        _cache_guard_installed = True
 
 
 # -- self-healing ladder ------------------------------------------------------
@@ -429,18 +429,8 @@ def _retryable_errors() -> tuple:
     bug and fails fast."""
     global _retryable_cache
     if _retryable_cache is None:
-        errs: list = [CompileHangError, chaos.InjectedRuntimeError]
-        try:
-            errs.append(jax.errors.JaxRuntimeError)
-        except AttributeError:
-            pass
-        try:
-            from jax._src.lib import xla_client
-
-            errs.append(xla_client.XlaRuntimeError)
-        except Exception:
-            pass
-        _retryable_cache = tuple(errs)
+        _retryable_cache = (CompileHangError, chaos.InjectedRuntimeError,
+                            jax.errors.JaxRuntimeError)
     return _retryable_cache
 
 
@@ -482,7 +472,7 @@ def _run_ladder(attempt_fn, *, retries: int, retryable: tuple,
 def _chaos_cache_path() -> Optional[str]:
     """The bound persistent-cache dir, the target of cache-corruption
     faults at the materialization sites."""
-    return getattr(jax.config, "jax_compilation_cache_dir", None)
+    return jax.config.jax_compilation_cache_dir
 
 
 def _bounded_stage(stage: str, fn, *, deadline: Optional[float], group: int):
@@ -534,35 +524,6 @@ def _bounded_stage(stage: str, fn, *, deadline: Optional[float], group: int):
     return box["result"]
 
 
-_bypass_lock = threading.Lock()
-
-
-class _cache_bypass:
-    """Temporarily unbind the persistent compile cache — the ladder's
-    fresh-compile rung: the final retry of a repeatedly failing program
-    must not be able to fail through a poisoned cache entry the
-    quarantine guard could not catch.  Serialized under a lock; a
-    concurrent compile during the window merely skips the cache (slower,
-    never wrong)."""
-
-    def __enter__(self):
-        _bypass_lock.acquire()
-        self._prev = getattr(jax.config, "jax_compilation_cache_dir", None)
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
-        return self
-
-    def __exit__(self, *exc):
-        try:
-            jax.config.update("jax_compilation_cache_dir", self._prev)
-        except Exception:
-            pass
-        _bypass_lock.release()
-        return False
-
-
 # -- compile-cache outcome accounting ---------------------------------------
 #
 # The hit/miss oracle is jax's own monitoring stream: a persistent-cache
@@ -570,15 +531,14 @@ class _cache_bypass:
 # records '/jax/compilation_cache/cache_misses', both synchronously on the
 # thread running the compile — so attributing events through a
 # thread-local keeps the counters EXACT even with TDX_COMPILE_WORKERS
-# compiles in flight at once (the old before/after directory differencing
-# could misattribute entries written by a concurrent compile).  A miss too
-# fast/small to persist records nothing and still counts as "miss", the
-# same boundary bench.py's warm stamp documents.
+# compiles in flight at once.  A miss too fast/small to persist records
+# nothing and still counts as "miss", the same boundary bench.py's warm
+# stamp documents.
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _mon_tls = threading.local()
-_listener_state: Optional[bool] = None  # None = not yet attempted
+_listener_installed = False
 _listener_lock = threading.Lock()
 
 
@@ -588,33 +548,15 @@ def _on_jax_event(event: str, **kw) -> None:
         rec.append(event)
 
 
-def _install_cache_listener() -> bool:
-    """Register the jax monitoring listener once; False when this jax has
-    no monitoring API (the caller falls back to directory differencing)."""
-    global _listener_state
+def _install_cache_listener() -> None:
+    """Register the jax monitoring listener once per process."""
+    global _listener_installed
     with _listener_lock:
-        if _listener_state is None:
-            try:
-                from jax._src import monitoring
+        if not _listener_installed:
+            from jax._src import monitoring
 
-                monitoring.register_event_listener(_on_jax_event)
-                _listener_state = True
-            except Exception:
-                _listener_state = False
-        return _listener_state
-
-
-def _persistent_cache_entries() -> Optional[set]:
-    """Filenames in jax's persistent compilation cache dir, or None when
-    no cache is configured.  Only the monitoring-less fallback path still
-    differences this before/after a compile."""
-    d = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if not d:
-        return None
-    try:
-        return set(os.listdir(d))
-    except OSError:
-        return set()
+            monitoring.register_event_listener(_on_jax_event)
+            _listener_installed = True
 
 
 # -- pod-scale artifact registry (docs/registry.md) --------------------------
@@ -798,8 +740,11 @@ def _compile_program(init_fn, key, out_shardings, label=None, *,
     ``fault_plan`` pins the chaos plan for the ``lower`` / ``cache`` /
     ``compile`` / ``registry`` injection sites (group-number keyed; the
     monolith is group 1); ``deadline`` arms the stage watchdog;
-    ``bypass_cache`` compiles with the persistent cache unbound — the
-    ladder's fresh-compile rung (the registry is also skipped on that
+    ``bypass_cache`` compiles with the persistent cache neither read nor
+    written on the compiling thread — the ladder's fresh-compile rung:
+    the final retry of a repeatedly failing program must not be able to
+    fail through a poisoned cache entry the quarantine guard could not
+    catch (the registry is also skipped on that
     rung: a poisoned artifact must not be able to fail every attempt).
     ``program_fp`` makes the program registry-eligible: when a registry
     is configured, its artifact is fetched into the local cache before
@@ -829,11 +774,7 @@ def _compile_program(init_fn, key, out_shardings, label=None, *,
         lowered = _bounded_stage("lower", _do_lower, deadline=deadline,
                                  group=gno)
     t_lower = time.perf_counter() - t0
-    exact = _install_cache_listener()
-    # Captured OUTSIDE the compile closure: during the ladder's bypass
-    # rung the cache dir is temporarily unbound, and a cache-corruption
-    # fault still pending on the final retry must target the REAL
-    # configured dir, not fail on path=None.
+    _install_cache_listener()
     cdir = _chaos_cache_path()
     reg = regkey = reg_payload = None
     if program_fp is not None and not bypass_cache:
@@ -865,17 +806,16 @@ def _compile_program(init_fn, key, out_shardings, label=None, *,
     with observe.span("jax.compile", category="jax", **attrs) as csp:
         events: List[str] = []
         cache_keys: List[str] = []
-        before = None if exact else _persistent_cache_entries()
 
         def _do_compile():
-            if exact:
-                _mon_tls.events = events
             # Installed on whichever thread RUNS the compile (the
-            # watchdog may be an inner thread), exactly like `events`.
+            # watchdog may be an inner thread).
+            _mon_tls.events = events
             _mon_tls.cache_keys = cache_keys
             _mon_tls.registry_payload = (
                 list(reg_payload.values()) if reg_payload else None
             )
+            _mon_tls.bypass = bypass_cache
             try:
                 chaos.maybe_inject("cache", gno, path=cdir, plan=fault_plan)
                 chaos.maybe_inject("compile", gno, path=cdir, plan=fault_plan)
@@ -884,30 +824,20 @@ def _compile_program(init_fn, key, out_shardings, label=None, *,
                     if opts is not None else lowered.compile()
                 )
             finally:
-                if exact:
-                    _mon_tls.events = None
+                _mon_tls.events = None
                 _mon_tls.cache_keys = None
                 _mon_tls.registry_payload = None
+                _mon_tls.bypass = False
 
+        compiled = _bounded_stage(
+            "compile", _do_compile, deadline=deadline, group=gno
+        )
         if bypass_cache:
-            with _cache_bypass():
-                compiled = _bounded_stage(
-                    "compile", _do_compile, deadline=deadline, group=gno
-                )
             outcome = "bypass"
+        elif not jax.config.jax_compilation_cache_dir:
+            outcome = "uncached"  # no persistent cache dir configured
         else:
-            compiled = _bounded_stage(
-                "compile", _do_compile, deadline=deadline, group=gno
-            )
-            if not getattr(jax.config, "jax_compilation_cache_dir", None):
-                outcome = "uncached"  # no persistent cache dir configured
-            elif exact:
-                outcome = "hit" if _HIT_EVENT in events else "miss"
-            else:
-                # Monitoring-less jax: the legacy directory differencing
-                # (exact serially; approximate if compiles run concurrently).
-                after = _persistent_cache_entries()
-                outcome = "miss" if (after != before or not before) else "hit"
+            outcome = "hit" if _HIT_EVENT in events else "miss"
         csp.set(cache=outcome)
         # Compiler-reported accounting — probed unconditionally: the one
         # call per program compile is noise next to the compile itself,

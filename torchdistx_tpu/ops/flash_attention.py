@@ -36,8 +36,9 @@ FlashAttention scheme laid out for the TPU memory hierarchy:
 
 Matches the model layer ``AttnFn`` signature (`models/layers.py`), so any
 family runs on it by constructor argument, including under `jax.grad`.
-On non-TPU backends the kernels run in interpreter mode, which keeps the
-CPU test suite meaningful.
+On non-TPU backends the kernels run in interpreter mode (decided and
+counted by :func:`._interpret.resolve_interpret`), which keeps the CPU test
+suite meaningful.
 """
 
 from __future__ import annotations
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._interpret import resolve_interpret
 from .segments import normalize_segment_ids
 
 _NEG = -1e30
@@ -777,8 +779,7 @@ def flash_attention(
             f"Query heads ({H}) must be a multiple of KV heads ({KV})."
         )
     groups = H // KV
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     bq = min(block_q, _round8(S))
     bk = min(block_k, _round8(T))
 
@@ -822,8 +823,18 @@ def flash_attention(
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
 
 
-def make_flash_attention(*, block_q: int = 1024, block_k: int = 1024):
-    """An ``AttnFn`` with fixed block sizes, for model constructors."""
+def make_flash_attention(*, block_q: int = 1024, block_k: int = 1024,
+                         mesh=None, batch_axes=("dp", "fsdp"),
+                         head_axis: str = "tp"):
+    """An ``AttnFn`` with fixed block sizes, for model constructors.
+
+    With a multi-device ``mesh`` the kernel runs under ``shard_map``:
+    batch split over the ``batch_axes`` present on the mesh, heads over
+    ``head_axis`` when it is — each device runs the kernel on its own
+    shard.  A Mosaic call is opaque to the SPMD partitioner: left to
+    GSPMD, a batch-sharded step would gather the whole batch onto every
+    device and run the kernel replicated — right answers, n-fold work,
+    no error."""
 
     def attn_fn(q, k, v, *, causal=True, bias=None, segment_ids=None):
         return flash_attention(
@@ -831,4 +842,18 @@ def make_flash_attention(*, block_q: int = 1024, block_k: int = 1024):
             block_q=block_q, block_k=block_k,
         )
 
-    return attn_fn
+    if mesh is None or mesh.devices.size == 1:
+        return attn_fn
+    from jax.sharding import PartitionSpec as P
+
+    # Imported here: parallel/ imports this module (ring_flash).
+    from ..parallel._attn_wrap import wrap_seq_parallel_attn
+
+    b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
+    h = head_axis if head_axis in mesh.axis_names else None
+    return wrap_seq_parallel_attn(
+        mesh, name="flash_attention", spec=P(b, None, h, None),
+        per_device=lambda q, k, v, causal, bias, segs: attn_fn(
+            q, k, v, causal=causal, bias=bias, segment_ids=segs),
+        bias_spec=P(h, None, None), seg_specs=(P(b, None), P(b, None)),
+    )

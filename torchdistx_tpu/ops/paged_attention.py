@@ -32,12 +32,19 @@ the TPU-native formulation of Ragged Paged Attention (arXiv:2604.15464):
 dense masked softmax); the parity tests pin kernel == reference across
 dtypes and ragged shapes, and kernel == ``flash_attention``'s last-token
 output on contiguous single-page layouts.  On non-TPU backends the
-kernel runs in interpreter mode, keeping the CPU suite meaningful.
+kernel runs in interpreter mode (decided and counted by
+:func:`._interpret.resolve_interpret`), keeping the CPU suite meaningful.
 
 Conventions shared with the serving engine:
 
 * ``q``: [B, H, D] — one decode token per sequence;
-* ``k_pages`` / ``v_pages``: [P, page_size, KV, D] — the global pool;
+* ``k_pages`` / ``v_pages``: [P, KV, page_size, D] — the global pool.
+  The page's token rows and the head dim are the two minor dims, so one
+  (page, kv head) block is ``(1, 1, page_size, D)``: its last two dims
+  equal the array's, which is what the Mosaic lowering requires of a
+  block for every page size, head dim and dtype the repo serves (a
+  ``[P, page, KV, D]`` pool would put one kv head on the second-minor
+  dim — a block Mosaic refuses whenever ``KV > 1``);
 * ``lengths``: [B] int32 — tokens of context per sequence INCLUDING the
   one ``q`` belongs to (its K/V must already be written to its page);
 * ``page_table``: [B, max_pages] int32 — pool page ids per sequence, in
@@ -58,15 +65,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._interpret import resolve_interpret
+
 _NEG = -1e30
 _LANES = 128  # lane-broadcast scratch carriers, like flash_attention
 _SUBLANES = 8  # f32 sublane tile: the query-group dim is padded to this
 
 
+def _gather_context(pages: jax.Array, page_table: jax.Array) -> jax.Array:
+    """[P, KV, page, D] pool + [B, max_pages] table -> the sequences'
+    contiguous contexts [B, KV, max_pages * page, D] in f32."""
+    B, maxp = page_table.shape
+    _, KV, page, D = pages.shape
+    ctx = pages[page_table]  # [B, maxp, KV, page, D]
+    ctx = ctx.transpose(0, 2, 1, 3, 4).reshape(B, KV, maxp * page, D)
+    return ctx.astype(jnp.float32)
+
+
 def paged_attention_reference(
     q: jax.Array,  # [B, H, D]
-    k_pages: jax.Array,  # [P, page, KV, D]
-    v_pages: jax.Array,  # [P, page, KV, D]
+    k_pages: jax.Array,  # [P, KV, page, D]
+    v_pages: jax.Array,  # [P, KV, page, D]
     lengths: jax.Array,  # [B] int32
     page_table: jax.Array,  # [B, max_pages] int32
 ) -> jax.Array:
@@ -74,28 +93,26 @@ def paged_attention_reference(
     f32 softmax — numerically the same computation as
     ``default_attention`` on the gathered layout."""
     B, H, D = q.shape
-    page = k_pages.shape[1]
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[1]
     groups = H // KV
-    maxp = page_table.shape[1]
-    T = maxp * page
 
-    k = k_pages[page_table].reshape(B, T, KV, D).astype(jnp.float32)
-    v = v_pages[page_table].reshape(B, T, KV, D).astype(jnp.float32)
+    k = _gather_context(k_pages, page_table)  # [B, KV, T, D]
+    v = _gather_context(v_pages, page_table)
+    T = k.shape[2]
     qf = q.astype(jnp.float32) * (1.0 / math.sqrt(D))
     qf = qf.reshape(B, KV, groups, D)
-    logits = jnp.einsum("bkgd,btkd->bkgt", qf, k)
+    logits = jnp.einsum("bkgd,bktd->bkgt", qf, k)
     mask = jnp.arange(T)[None, :] < lengths[:, None]  # [B, T]
     logits = jnp.where(mask[:, None, None], logits, _NEG)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", probs, v)
+    out = jnp.einsum("bkgt,bktd->bkgd", probs, v)
     return out.reshape(B, H, D).astype(q.dtype)
 
 
 def paged_prefill_attention(
     q: jax.Array,  # [B, S, H, D] — a chunk of query tokens per sequence
-    k_pages: jax.Array,  # [P, page, KV, D]
-    v_pages: jax.Array,  # [P, page, KV, D]
+    k_pages: jax.Array,  # [P, KV, page, D]
+    v_pages: jax.Array,  # [P, KV, page, D]
     q_positions: jax.Array,  # [B, S] int32 — absolute positions of q
     lengths: jax.Array,  # [B] int32 — valid context INCLUDING the chunk
     page_table: jax.Array,  # [B, max_pages] int32
@@ -110,24 +127,22 @@ def paged_prefill_attention(
     ignored by the caller (position 0 always satisfies the mask, so no
     row softmaxes over an empty set)."""
     B, S, H, D = q.shape
-    page = k_pages.shape[1]
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[1]
     groups = H // KV
-    maxp = page_table.shape[1]
-    T = maxp * page
 
-    k = k_pages[page_table].reshape(B, T, KV, D).astype(jnp.float32)
-    v = v_pages[page_table].reshape(B, T, KV, D).astype(jnp.float32)
+    k = _gather_context(k_pages, page_table)  # [B, KV, T, D]
+    v = _gather_context(v_pages, page_table)
+    T = k.shape[2]
     qf = q.astype(jnp.float32) * (1.0 / math.sqrt(D))
     qf = qf.reshape(B, S, KV, groups, D)
-    logits = jnp.einsum("bskgd,btkd->bskgt", qf, k)
+    logits = jnp.einsum("bskgd,bktd->bskgt", qf, k)
     tpos = jnp.arange(T)[None, None, :]
     mask = (tpos <= q_positions[:, :, None]) & (
         tpos < lengths[:, None, None]
     )  # [B, S, T]
     logits = jnp.where(mask[:, :, None, None, :], logits, _NEG)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bskgt,btkd->bskgd", probs, v)
+    out = jnp.einsum("bskgt,bktd->bskgd", probs, v)
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
@@ -135,8 +150,8 @@ def _decode_kernel(
     lengths_ref,  # SMEM [B] i32 (scalar prefetch)
     table_ref,  # SMEM [B, max_pages] i32 (scalar prefetch)
     q_ref,  # [1, Gp, D]
-    k_ref,  # [1, page, 1, D] — the page the index map selected
-    v_ref,  # [1, page, 1, D]
+    k_ref,  # [1, 1, page, D] — the (page, kv head) the index map selected
+    v_ref,  # [1, 1, page, D]
     o_ref,  # [1, Gp, D]
     acc_ref,  # VMEM [Gp, D] f32
     m_ref,  # VMEM [Gp, _LANES] f32
@@ -161,7 +176,7 @@ def _decode_kernel(
     @pl.when(j * page_size < seq_len)
     def _page():
         q = q_ref[0].astype(jnp.float32) * sm_scale  # [Gp, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [page, D]
+        k = k_ref[0, 0].astype(jnp.float32)  # [page, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [Gp, page]
@@ -180,7 +195,7 @@ def _decode_kernel(
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p,
-            v_ref[0, :, 0].astype(jnp.float32),
+            v_ref[0, 0].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [Gp, D]
@@ -197,8 +212,8 @@ def _decode_kernel(
 
 def paged_attention(
     q: jax.Array,  # [B, H, D]
-    k_pages: jax.Array,  # [P, page, KV, D]
-    v_pages: jax.Array,  # [P, page, KV, D]
+    k_pages: jax.Array,  # [P, KV, page, D]
+    v_pages: jax.Array,  # [P, KV, page, D]
     lengths: jax.Array,  # [B] int32
     page_table: jax.Array,  # [B, max_pages] int32
     *,
@@ -208,7 +223,7 @@ def paged_attention(
     against its page-table-mapped context.  See the module docstring for
     the layout contract; output is [B, H, D] in ``q``'s dtype."""
     B, H, D = q.shape
-    P, page_size, KV, Dk = k_pages.shape
+    P, KV, page_size, Dk = k_pages.shape
     if Dk != D:
         raise ValueError(f"head_dim mismatch: q has {D}, pages have {Dk}")
     if v_pages.shape != k_pages.shape:
@@ -226,8 +241,7 @@ def paged_attention(
         )
     groups = H // KV
     maxp = page_table.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     sm_scale = 1.0 / math.sqrt(D)
 
     # [B, H, D] -> [B*KV, Gp, D]: head h of sequence b is (kv = h //
@@ -243,8 +257,8 @@ def paged_attention(
     # Index maps see the scalar-prefetch refs after the grid indices; the
     # page id for (sequence, page ordinal) comes straight from SMEM.
     kv_spec = pl.BlockSpec(
-        (1, page_size, 1, D),
-        lambda i, j, lens, table: (table[i // KV, j], 0, i % KV, 0),
+        (1, 1, page_size, D),
+        lambda i, j, lens, table: (table[i // KV, j], i % KV, 0, 0),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

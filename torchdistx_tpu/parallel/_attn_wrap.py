@@ -7,9 +7,8 @@ from functools import partial
 from typing import Callable, Optional, Tuple
 
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._shard_map_compat import shard_map
 
 from ..ops.segments import normalize_segment_ids
 

@@ -28,10 +28,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._shard_map_compat import shard_map
 
 from .. import observe
 from ..models.configs import TransformerConfig

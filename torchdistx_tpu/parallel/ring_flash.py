@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops._interpret import resolve_interpret
 from ..ops.flash_attention import (
     _LANES,
     _bwd_call,
@@ -303,8 +304,7 @@ def ring_flash_attention(
             "use the dense ring for causal cross-attention."
         )
     groups = H // KV
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     bq = min(block_q, _round8(s))
     bk = min(block_k, _round8(t))
 

@@ -93,7 +93,7 @@ from .. import chaos, observe
 from ..observe import reqledger
 from ..models import PRESETS, TransformerConfig
 from ..utils.logging import get_logger
-from .kv_cache import OutOfPages, PagedKVCache, init_pools
+from .kv_cache import OutOfPages, PagedKVCache, init_pools, pool_sharding
 from .prefix import NgramDrafter, PrefixCache
 from .programs import (
     ResolvedServeConfig,
@@ -185,8 +185,9 @@ class ServeEngine:
         self._draining = False
         self.kv = PagedKVCache(self.scfg.kv_config(cfg))
         self.prefix = PrefixCache(self.kv)
-        self.k_pages, self.v_pages = init_pools(self.scfg.kv_config(cfg),
-                                                cfg.dtype)
+        self.k_pages, self.v_pages = init_pools(
+            self.scfg.kv_config(cfg), cfg.dtype,
+            pool_sharding(mesh, cfg.kv_heads))
         # Chunk-boundary chaos faults (``serve@N=raise:chunk``) are
         # deferred here by step() and fired BETWEEN prefill chunks —
         # the mid-chunked-prefill fault the failure matrix pins.
@@ -205,6 +206,10 @@ class ServeEngine:
         self.spec_accepted = 0     # draft tokens accepted
         self.spec_verify_ticks = 0  # batched verify calls
         self._programs: Dict[str, object] = {}
+        # name -> how often the compiled program was dispatched (every
+        # call site goes through _program): what chip_smoke.py reads to
+        # prove each program family executed.
+        self.program_calls: Dict[str, int] = {}
         self._spec_cache: Optional[Dict[str, object]] = None
         self.waiting: deque[Request] = deque()
         self.active: Dict[int, _Lane] = {}      # slot -> lane
@@ -276,6 +281,7 @@ class ServeEngine:
                 raise ValueError(f"unknown serving program {name!r}")
             prog, _ = compile_serving_program(spec)
             self._programs[name] = prog
+        self.program_calls[name] = self.program_calls.get(name, 0) + 1
         return prog
 
     def warmup(self) -> Dict[str, str]:

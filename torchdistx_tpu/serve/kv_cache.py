@@ -3,7 +3,7 @@
 The serving engine's memory manager.  Instead of one contiguous
 [B, max_seq, KV, D] cache per sequence (whose worst-case reservation is
 what kills batch size), K/V live in a pool of fixed-size **pages**
-([n_pages, page_size, kv_heads, head_dim] per layer, allocated once at
+([n_pages, kv_heads, page_size, head_dim] per layer, allocated once at
 replica bring-up), and each sequence owns an ordered list of page ids —
 its **page table**.  Admission cost is ``ceil(len / page_size)`` pages,
 growth is one page at a time, retirement returns pages to the free list
@@ -57,7 +57,8 @@ import numpy as np
 
 from .. import observe
 
-__all__ = ["KVCacheConfig", "OutOfPages", "PagedKVCache"]
+__all__ = ["KVCacheConfig", "OutOfPages", "PagedKVCache", "init_pools",
+           "pool_sharding"]
 
 
 class OutOfPages(RuntimeError):
@@ -90,9 +91,12 @@ class KVCacheConfig:
         return max(0, -(-n_tokens // self.page_size))
 
     def pool_shape(self) -> Tuple[int, int, int, int, int]:
-        """[L, P, page, KV, D] — the per-pool (K or V) array shape."""
-        return (self.n_layers, self.n_pages, self.page_size,
-                self.kv_heads, self.head_dim)
+        """[L, P, KV, page, D] — the per-pool (K or V) array shape.
+        Token rows and head dim are the minor dims: the layout the
+        decode kernel's (page, kv head) block needs to be Mosaic-legal
+        (:mod:`torchdistx_tpu.ops.paged_attention`)."""
+        return (self.n_layers, self.n_pages, self.kv_heads,
+                self.page_size, self.head_dim)
 
 
 @dataclass
@@ -404,9 +408,28 @@ class PagedKVCache:
         observe.gauge("tdx.serve.kv_pages_shared").set(self.shared_pages)
 
 
-def init_pools(cfg: KVCacheConfig, dtype) -> Tuple["jax.Array", "jax.Array"]:
-    """The zeroed device pools (k_pages, v_pages), [L, P, page, KV, D]."""
+def pool_sharding(mesh, kv_heads: int, tp_axis: str = "tp"):
+    """Where the pools live on a replica mesh — decided here, not left
+    to sharding propagation: kv heads split over the tensor-parallel
+    axis when it divides them (each tp shard then attends its own heads;
+    :func:`..programs.build_decode_fn` runs the decode kernel per shard),
+    replicated over every other axis.  None without a mesh."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    tp = mesh.shape.get(tp_axis, 1)
+    if tp > 1 and kv_heads % tp == 0:
+        return NamedSharding(mesh, P(None, None, tp_axis))
+    return NamedSharding(mesh, P())
+
+
+def init_pools(cfg: KVCacheConfig, dtype,
+               sharding=None) -> Tuple["jax.Array", "jax.Array"]:
+    """The zeroed device pools (k_pages, v_pages), [L, P, KV, page, D],
+    committed to ``sharding`` (:func:`pool_sharding`) when given."""
     import jax.numpy as jnp
 
     shape = cfg.pool_shape()
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    return (jnp.zeros(shape, dtype, device=sharding),
+            jnp.zeros(shape, dtype, device=sharding))

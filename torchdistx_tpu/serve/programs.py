@@ -62,6 +62,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from .. import abstract, chaos, observe
 from .. import config as tdx_config
@@ -69,7 +71,7 @@ from ..models import TransformerConfig, make_gpt2, make_llama
 from ..models.layers import MLP, apply_rope, default_attention, make_norm
 from ..ops import paged_attention, paged_prefill_attention
 from ..utils.logging import get_logger
-from .kv_cache import KVCacheConfig
+from .kv_cache import KVCacheConfig, pool_sharding
 
 __all__ = [
     "ServeConfig",
@@ -267,13 +269,32 @@ def _mlp(cfg: TransformerConfig, blk, x):
     return MLP(cfg).apply({"params": blk["mlp"]}, x)
 
 
+def _decode_attention(mesh, kv_heads: int) -> Callable:
+    """:func:`..ops.paged_attention` as the decode program calls it.  A
+    Mosaic call is opaque to the SPMD partitioner, which would gather a
+    sharded pool onto every device to run it; on a mesh whose tp axis
+    splits the kv heads (:func:`.kv_cache.pool_sharding`) the kernel
+    therefore runs under ``shard_map``, each tp shard on its own query
+    and kv heads (contiguous head blocks keep every query head with its
+    kv group), lengths and page table replicated."""
+    sh = pool_sharding(mesh, kv_heads)
+    if sh is None or not any(sh.spec):
+        return paged_attention
+    heads = P(None, sh.spec[2], None)        # q / out [B, H, D]
+    pool = P(None, sh.spec[2], None, None)   # layer slice [P, KV, page, D]
+    return shard_map(
+        paged_attention, mesh=mesh, in_specs=(heads, pool, pool, P(), P()),
+        out_specs=heads, check_vma=False,
+    )
+
+
 def _decode_block(cfg, blk, x, kp, vp, *, angles, positions, lengths,
-                  page_table):
+                  page_table, attend):
     """One layer of the decode step: x [B, 1, d]; writes this token's
     K/V at (page, slot) and attends the whole context through the page
     table."""
     n0, n1 = _norm_keys(cfg)
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     B = x.shape[0]
     h = make_norm(cfg).apply({"params": blk[n0]}, x)
     q, k, v = _qkv(cfg, blk["attn"], h)
@@ -282,9 +303,11 @@ def _decode_block(cfg, blk, x, kp, vp, *, angles, positions, lengths,
         k = apply_rope(k, angles)
     page = page_table[jnp.arange(B), positions // page_size]
     slot = positions % page_size
-    kp = kp.at[page, slot].set(k[:, 0])
-    vp = vp.at[page, slot].set(v[:, 0])
-    attn = paged_attention(q[:, 0], kp, vp, lengths, page_table)
+    # Pool layer slice is [P, KV, page, D]: (page, slot) address a
+    # token row across every kv head.
+    kp = kp.at[page, :, slot].set(k[:, 0])
+    vp = vp.at[page, :, slot].set(v[:, 0])
+    attn = attend(q[:, 0], kp, vp, lengths, page_table)
     x = x + _attn_out(cfg, blk["attn"], attn[:, None])
     h2 = make_norm(cfg).apply({"params": blk[n1]}, x)
     x = x + _mlp(cfg, blk, h2)
@@ -298,7 +321,7 @@ def _prefill_block(cfg, blk, x, kp, vp, *, angles, positions, length,
     position's K/V scattered into its page; padded positions write the
     null page and are segment-masked out of the valid rows."""
     n0, n1 = _norm_keys(cfg)
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     maxp = page_table.shape[1]
     B = x.shape[0]
     h = make_norm(cfg).apply({"params": blk[n0]}, x)
@@ -310,8 +333,8 @@ def _prefill_block(cfg, blk, x, kp, vp, *, angles, positions, length,
     pidx = jnp.minimum(positions // page_size, maxp - 1)
     page = jnp.where(valid, jnp.take_along_axis(page_table, pidx, axis=1), 0)
     slot = jnp.where(valid, positions % page_size, 0)
-    kp = kp.at[page, slot].set(k)
-    vp = vp.at[page, slot].set(v)
+    kp = kp.at[page, :, slot].set(k)
+    vp = vp.at[page, :, slot].set(v)
     attn = default_attention(q, k, v, causal=True,
                              segment_ids=valid.astype(jnp.int32))
     x = x + _attn_out(cfg, blk["attn"], attn)
@@ -330,7 +353,7 @@ def _chunk_block(cfg, blk, x, kp, vp, *, angles, positions, end,
     causal self-context — which is what lets a suffix prefill skip the
     prefix's FLOPs entirely."""
     n0, n1 = _norm_keys(cfg)
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     maxp = page_table.shape[1]
     h = make_norm(cfg).apply({"params": blk[n0]}, x)
     q, k, v = _qkv(cfg, blk["attn"], h)
@@ -341,8 +364,8 @@ def _chunk_block(cfg, blk, x, kp, vp, *, angles, positions, end,
     pidx = jnp.minimum(positions // page_size, maxp - 1)
     page = jnp.where(valid, jnp.take_along_axis(page_table, pidx, axis=1), 0)
     slot = jnp.where(valid, positions % page_size, 0)
-    kp = kp.at[page, slot].set(k)
-    vp = vp.at[page, slot].set(v)
+    kp = kp.at[page, :, slot].set(k)
+    vp = vp.at[page, :, slot].set(v)
     attn = paged_prefill_attention(q, kp, vp, positions, end, page_table)
     x = x + _attn_out(cfg, blk["attn"], attn)
     h2 = make_norm(cfg).apply({"params": blk[n1]}, x)
@@ -366,7 +389,7 @@ def _scan_blocks(decomp, p, x, k_pages, v_pages, block_step):
 
 
 def build_decode_fn(family: str, cfg: TransformerConfig,
-                    scfg: ResolvedServeConfig) -> Callable:
+                    scfg: ResolvedServeConfig, mesh=None) -> Callable:
     """The batched decode-step program:
     ``(params, k_pages, v_pages, tokens [B], positions [B],
     page_table [B, maxp]) -> (logits [B, vocab], k_pages, v_pages)``.
@@ -374,6 +397,7 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
     lanes carry position 0 and a null page table (their writes land in
     the null page, their logits are ignored)."""
     decomp = make_model(family, cfg).decode_decomposition()
+    attend = _decode_attention(mesh, cfg.kv_heads)
 
     def decode_fn(params, k_pages, v_pages, tokens, positions, page_table):
         p = params["params"]
@@ -388,7 +412,7 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
         def step(blk, x, kp, vp):
             return _decode_block(
                 cfg, blk, x, kp, vp, angles=angles, positions=positions,
-                lengths=lengths, page_table=page_table,
+                lengths=lengths, page_table=page_table, attend=attend,
             )
 
         x, k_pages, v_pages = _scan_blocks(
@@ -571,7 +595,9 @@ def _fp(kind: str, family: str, cfg: TransformerConfig,
         scfg.max_batch, scfg.page_size, scfg.n_pages,
         scfg.max_pages_per_seq, scfg.prefill_buckets,
     )
-    h = hashlib.sha1(b"tdx-serve-program-fp-v1")
+    # v2: the [L, P, KV, page, D] pool layout — same key material, other
+    # compiled bytes, so artifacts published under v1 must not be served.
+    h = hashlib.sha1(b"tdx-serve-program-fp-v2")
     h.update(repr((kind, family, cfg, shape, extra)).encode())
     return h.hexdigest()
 
@@ -658,7 +684,13 @@ def serve_program_specs(
         init_dtype=init_dtype,
     )
     kv = scfg.kv_config(cfg)
-    pool_sds = jax.ShapeDtypeStruct(kv.pool_shape(), cfg.dtype)
+    # The pools' placement is part of every program's contract: committed
+    # inputs, and the same sharding pinned on the way out, so where the
+    # cache lives never depends on what GSPMD happens to propagate.
+    pool_sh = pool_sharding(mesh, cfg.kv_heads)
+    pool_sds = jax.ShapeDtypeStruct(kv.pool_shape(), cfg.dtype,
+                                    sharding=pool_sh)
+    step_out = None if pool_sh is None else (None, pool_sh, pool_sh)
     i32 = jnp.int32
     B, maxp = scfg.max_batch, scfg.max_pages_per_seq
     # The OUTPUT CONTRACT is part of every fingerprint, exactly as the
@@ -699,7 +731,7 @@ def serve_program_specs(
                   jax.ShapeDtypeStruct((1, b), i32),
                   jax.ShapeDtypeStruct((1,), i32),
                   jax.ShapeDtypeStruct((1, maxp), i32)),
-            out_shardings=None,
+            out_shardings=step_out,
             program_fp=_fp(f"prefill-{b}", family, cfg, scfg, extra),
             init_options=False,
         ))
@@ -712,7 +744,7 @@ def serve_program_specs(
                   jax.ShapeDtypeStruct((1,), i32),
                   jax.ShapeDtypeStruct((1,), i32),
                   jax.ShapeDtypeStruct((1, maxp), i32)),
-            out_shardings=None,
+            out_shardings=step_out,
             program_fp=_fp(f"chunk-{b}", family, cfg, scfg, extra),
             init_options=False,
         ))
@@ -722,18 +754,18 @@ def serve_program_specs(
         args=(pool_sds, pool_sds,
               jax.ShapeDtypeStruct((1,), i32),
               jax.ShapeDtypeStruct((1,), i32)),
-        out_shardings=None,
+        out_shardings=None if pool_sh is None else (pool_sh, pool_sh),
         program_fp=_fp("cow", family, cfg, scfg, extra),
         init_options=False,
     ))
     specs.append(ServeProgramSpec(
         name="decode",
-        fn=build_decode_fn(family, cfg, scfg),
+        fn=build_decode_fn(family, cfg, scfg, mesh),
         args=(params_abs, pool_sds, pool_sds,
               jax.ShapeDtypeStruct((B,), i32),
               jax.ShapeDtypeStruct((B,), i32),
               jax.ShapeDtypeStruct((B, maxp), i32)),
-        out_shardings=None,
+        out_shardings=step_out,
         program_fp=_fp("decode", family, cfg, scfg, extra),
         init_options=False,
     ))
@@ -750,7 +782,7 @@ def serve_program_specs(
                   jax.ShapeDtypeStruct((B,), i32),
                   jax.ShapeDtypeStruct((B,), i32),
                   jax.ShapeDtypeStruct((B, maxp), i32)),
-            out_shardings=None,
+            out_shardings=step_out,
             program_fp=_fp(f"verify-{k}", family, cfg, scfg, extra),
             init_options=False,
         ))
