@@ -433,3 +433,298 @@ class TestTraceCLI:
             capture_output=True, text=True, timeout=60,
         )
         assert out.returncode == 2
+
+
+# -- spans on the profiler's clock, inside a serving tick, and the compile log
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what the
+    spans open and close."""
+
+    opened: list = []
+    closed: list = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+        type(self).opened.append((name, kw))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        type(self).closed.append(self.name)
+        return False
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    from torchdistx_tpu.observe import spans
+
+    _CountingAnnotation.opened, _CountingAnnotation.closed = [], []
+    monkeypatch.setattr(spans, "_annotation_cls", _CountingAnnotation)
+    return _CountingAnnotation
+
+
+class TestProfilerMirror:
+    def test_spans_land_on_host_plane_of_the_xplane(self, telemetry, tmp_path):
+        """A span opened while a jax.profiler session runs is found by name
+        on /host:CPU of the .xplane.pb, nested as the spans were, on a clock
+        that agrees with the tracer's to a millisecond."""
+        import glob
+
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with observe.span("mirror.outer", category="t", k=3):
+                time.sleep(0.004)
+                with observe.span("mirror.inner", category="t", prog="decode"):
+                    time.sleep(0.003)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        found = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("mirror."):
+                        found[e.name] = (e.start_ns, e.duration_ns,
+                                         {k: v for k, v in e.stats})
+        assert set(found) == {"mirror.outer", "mirror.inner"}
+        (o0, od, ostats), (i0, idur, istats) = (
+            found["mirror.outer"], found["mirror.inner"])
+        assert o0 <= i0 and i0 + idur <= o0 + od  # nested as the spans were
+        assert ostats["k"] == 3 and istats["prog"] == "decode"
+        mine = {e["name"]: e for e in observe.tracer().events
+                if e["ph"] == "X"}
+        for name, (_s, dur_ns, _st) in found.items():
+            assert abs(dur_ns / 1e3 - mine[name]["dur"]) < 1000.0
+        # ... and the same offset between the two clocks for both spans.
+        off = [found[n][0] / 1e3 - mine[n]["ts"] for n in found]
+        assert abs(off[0] - off[1]) < 1000.0
+
+    def test_annotation_carries_opening_args_and_nests(self, telemetry,
+                                                       annotations):
+        with observe.span("a", category="t", x=1) as sp:
+            sp.set(late=2)
+            with observe.span("b", category="t"):
+                pass
+        assert annotations.opened == [("a", {"x": 1}), ("b", {})]
+        assert annotations.closed == ["b", "a"]
+
+    def test_clock_conversion_is_the_events_clock(self, telemetry):
+        from torchdistx_tpu.observe import spans
+
+        t0 = time.perf_counter()
+        with observe.span("clocked"):
+            pass
+        t1 = time.perf_counter()
+        (ev,) = [e for e in observe.tracer().events if e["ph"] == "X"]
+        assert spans.from_perf_counter(t0) <= ev["ts"]
+        assert ev["ts"] + ev["dur"] <= spans.from_perf_counter(t1)
+
+    def test_process_without_jax_mirrors_nothing(self):
+        code = (
+            "import sys, importlib.util, os\n"
+            "p = os.path.join(%r, 'torchdistx_tpu', 'observe', 'spans.py')\n"
+            "spec = importlib.util.spec_from_file_location('spans', p)\n"
+            "m = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(m)\n"
+            "t = m.Tracer()\n"
+            "with t.span('x'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules and m._annotation_cls is None\n"
+            "assert [e['name'] for e in t.events] == ['x']\n" % REPO)
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, timeout=60)
+        assert out.returncode == 0, out.stderr
+
+
+@pytest.fixture(scope="module")
+def tick_replica():
+    import jax.numpy as jnp
+
+    from torchdistx_tpu.models import TransformerConfig
+    from torchdistx_tpu.serve import ServeConfig, spin_up_replica
+
+    cfg = TransformerConfig(
+        vocab_size=128, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=64, max_seq_len=64, dtype=jnp.float32)
+    scfg = ServeConfig(
+        max_batch=2, page_size=8, n_pages=16, max_pages_per_seq=3,
+        prefill_buckets=(8, 16), spec_decode=False)
+    return spin_up_replica(cfg, family="llama", serve_cfg=scfg), scfg
+
+
+@pytest.fixture()
+def tick_engine(tick_replica):
+    """A fresh engine on the replica's weights and compiled programs: its
+    counter handles are resolved now, after any ``observe.reset()``."""
+    from torchdistx_tpu.serve import ServeEngine
+
+    warm, scfg = tick_replica
+    eng = ServeEngine("llama", warm.cfg, warm.params, serve_cfg=scfg)
+    eng._programs.update(warm._programs)
+    return eng
+
+
+TICK_SPANS = ("serve.step", "serve.admit", "serve.tick.tables",
+              "serve.program", "serve.tick.d2h", "serve.tick.emit")
+
+
+class TestServeTickSpans:
+    def _two_lanes(self, eng, tag):
+        from torchdistx_tpu.serve import Request
+
+        eng.submit(Request(f"{tag}-short", [3, 1, 4], max_new_tokens=3))
+        eng.submit(Request(f"{tag}-long", [2, 7, 1, 8, 2], max_new_tokens=6))
+        eng.step()  # admits both, prefills, and decodes once
+        assert len(eng.active) == 2
+
+    def test_tick_yields_the_spans_and_counts_a_retiring_lane(
+            self, telemetry, tick_engine):
+        eng = tick_engine
+        self._two_lanes(eng, "on")
+        lengths = {l.req.rid: l.length for l in eng.active.values()}
+        before = {n: observe.counter(n).value for n in (
+            "tdx.serve.attended_tokens", "tdx.serve.decode_lane_ticks")}
+        n0 = len(observe.tracer().events)
+        eng.step()  # a decode tick in which `on-short` retires
+        assert [l.req.rid for l in eng.active.values()] == ["on-long"]
+        evs = [e for e in list(observe.tracer().events)[n0:]
+               if e["ph"] == "X"]
+        assert sorted(e["name"] for e in evs) == sorted(TICK_SPANS)
+        by = {e["name"]: e for e in evs}
+        # Each lane attends over its context with the new token in it.
+        want = sum(n + 1 for n in lengths.values())
+        prog = by["serve.program"]["args"]
+        assert (prog["program"], prog["lanes"]) == ("decode", 2)
+        assert prog["attended_tokens"] == want
+        assert by["serve.tick.emit"]["args"]["tokens"] == 2
+        assert by["serve.tick.d2h"]["args"]["bytes"] == 2 * 128 * 4
+        assert by["serve.admit"]["args"]["admitted"] == 0
+        step = by["serve.step"]
+        for name in TICK_SPANS[1:]:  # all children of serve.step
+            e = by[name]
+            assert step["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 1.0
+        assert observe.counter(
+            "tdx.serve.attended_tokens").value - before[
+                "tdx.serve.attended_tokens"] == want
+        assert observe.counter(
+            "tdx.serve.decode_lane_ticks").value - before[
+                "tdx.serve.decode_lane_ticks"] == 2
+        eng.run()
+
+    def test_prefill_and_chunk_paths_open_the_same_children(
+            self, telemetry, tick_engine):
+        from torchdistx_tpu.serve import Request
+
+        eng = tick_engine
+        eng.submit(Request("wide", [(7 * i) % 128 for i in range(18)],
+                           max_new_tokens=2))
+        eng.run()
+        progs = [e["args"] for e in observe.tracer().events
+                 if e["ph"] == "X" and e["name"] == "serve.program"]
+        names = [a["program"] for a in progs]
+        assert names[:2] == ["chunk-16", "chunk-8"]
+        assert [a["attended_tokens"] for a in progs[:2]] == [16, 18]
+        kinds = {e["name"] for e in observe.tracer().events if e["ph"] == "X"}
+        assert set(TICK_SPANS) | {"serve.prefill"} <= kinds
+        # The first chunk's logits are read by nobody: no copy, no emit.
+        d2h = [e["args"]["program"] for e in observe.tracer().events
+               if e["ph"] == "X" and e["name"] == "serve.tick.d2h"]
+        assert "chunk-16" not in d2h and "chunk-8" in d2h
+
+    def test_telemetry_off_records_and_annotates_nothing(
+            self, tick_engine, annotations):
+        from torchdistx_tpu.observe.spans import _NOOP_SPAN
+
+        observe.enable(False)
+        try:
+            assert observe.span("serve.program", program="decode") is _NOOP_SPAN
+            n0 = len(observe.tracer().events)
+            before = observe.counter("tdx.serve.decode_lane_ticks").value
+            self._two_lanes(tick_engine, "off")
+            tick_engine.run()
+            assert len(observe.tracer().events) == n0
+            assert annotations.opened == []
+            # The operators' counters are always on.
+            assert observe.counter(
+                "tdx.serve.decode_lane_ticks").value > before
+        finally:
+            observe.enable(None)
+
+
+class TestCompileLog:
+    def test_fresh_jit_is_logged_with_its_seconds_and_only_once(self):
+        import jax
+        import jax.numpy as jnp
+
+        from torchdistx_tpu.observe import compilelog
+
+        compilelog.install()
+        salt = float(time.time_ns() % 9973)  # a program no cache has seen
+
+        def _tdx_fresh_fn(x):
+            return jnp.sin(x) * salt + jnp.cumsum(x)
+
+        f = jax.jit(_tdx_fresh_fn)
+        x = jnp.arange(8.0)
+        x.block_until_ready()
+        n0 = len(compilelog.entries())
+        c0 = observe.counter("tdx.jax.backend_compiles").value
+        s0 = observe.counter("tdx.jax.backend_compile_s").value
+        l0 = observe.counter("tdx.jax.lower_s").value
+        t_before = time.perf_counter()
+        f(x).block_until_ready()
+        new = compilelog.entries()[n0:]
+        mine = [e for e in new if "_tdx_fresh_fn" in e[3]]
+        assert [e[1] for e in mine] == ["trace", "lower", "backend_compile"]
+        assert all(e[2] > 0 and t_before <= e[0] <= time.perf_counter()
+                   for e in mine)
+        assert set(e[1] for e in new) <= set(compilelog.EVENTS)
+        assert observe.counter("tdx.jax.backend_compiles").value == c0 + sum(
+            e[1] == "backend_compile" for e in new)
+        assert observe.counter(
+            "tdx.jax.backend_compile_s").value - s0 == pytest.approx(
+                sum(e[2] for e in new if e[1] == "backend_compile"))
+        assert observe.counter("tdx.jax.lower_s").value - l0 == pytest.approx(
+            sum(e[2] for e in new if e[1] in ("trace", "lower")))
+        n1 = len(compilelog.entries())
+        f(x).block_until_ready()
+        assert len(compilelog.entries()) == n1  # nothing compiled again
+
+    def test_cache_load_is_one_retrieval_entry_not_a_compile(self):
+        from torchdistx_tpu.observe import compilelog
+
+        n0 = len(compilelog.entries())
+        c0 = observe.counter("tdx.jax.backend_compiles").value
+        compilelog.on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.2)
+        compilelog.on_duration(
+            "/jax/core/compile/backend_compile_duration", 0.25,
+            fun_name="jit(tdx_serve_decode)")
+        compilelog.on_duration("/some/other/event", 1.0)
+        (entry,) = compilelog.entries()[n0:]
+        assert entry[1:] == ("cache_retrieval", 0.25, "jit(tdx_serve_decode)")
+        assert observe.counter("tdx.jax.backend_compiles").value == c0
+
+    def test_nested_traces_are_covered_by_the_outermost(self):
+        from torchdistx_tpu.observe import compilelog
+
+        ev = "/jax/core/compile/jaxpr_trace_duration"
+        n0 = len(compilelog.entries())
+        compilelog.on_scalar(ev, 0.0, fun_name="outer")
+        compilelog.on_scalar(ev, 0.0, fun_name="_where")
+        compilelog.on_duration(ev, 0.001, fun_name="_where")
+        compilelog.on_duration(ev, 0.5, fun_name="outer")
+        assert [e[1:] for e in compilelog.entries()[n0:]] == [
+            ("trace", 0.5, "outer")]

@@ -58,6 +58,36 @@ class TestProfiling:
             st.stop(jnp.ones(4) + 1)
         assert st.steps == 3 and st.mean > 0
 
+    def test_trace_holds_the_programs_spans_and_no_python_calls(self, tmp_path):
+        """``trace`` starts the profiler as the benchmark does: the
+        program's spans are on /host:CPU, and no event per Python call."""
+        import glob
+        import os
+
+        from torchdistx_tpu import observe
+        from torchdistx_tpu.utils.profiling import trace
+
+        def some_python_function_of_the_test():
+            return sum(range(10))
+
+        observe.enable(True)
+        try:
+            with trace(str(tmp_path)):
+                with observe.span("operator.capture", category="t"):
+                    some_python_function_of_the_test()
+        finally:
+            observe.enable(None)
+            observe.reset()
+        (path,) = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        names = {e.name for plane in jax.profiler.ProfileData.from_file(
+            path).planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events}
+        assert "operator.capture" in names
+        assert not any("some_python_function_of_the_test" in n for n in names)
+        assert not hasattr(__import__("torchdistx_tpu.utils").utils,
+                           "annotate")
+
 
 class TestMetrics:
     def test_jsonl_sink(self, tmp_path):

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from .observe import compilelog
 from .parallel.sharding import ShardingPlan
 
 __all__ = [
@@ -137,6 +138,9 @@ def deferred_init(init_fn: Callable, *args: Any, **kwargs: Any):
         # params: pytree of DeferredArray — zero bytes allocated
         real = materialize(params, mesh=mesh, plan=plan)
     """
+    # The first step of the jax-native chain, which never loads the compile
+    # service: from here on what is traced, lowered and compiled is logged.
+    compilelog.install()
     out = jax.eval_shape(init_fn, *args, **kwargs)
     leaves, treedef = jax.tree.flatten(out)
     paths_leaves = jax.tree_util.tree_flatten_with_path(out)[0]

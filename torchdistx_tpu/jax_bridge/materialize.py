@@ -549,13 +549,16 @@ def _on_jax_event(event: str, **kw) -> None:
 
 
 def _install_cache_listener() -> None:
-    """Register the jax monitoring listener once per process."""
+    """Register the jax monitoring listeners once per process: the
+    cache-outcome events above, and the durations of every trace,
+    lowering and backend compile (``observe.compilelog``)."""
     global _listener_installed
     with _listener_lock:
         if not _listener_installed:
             from jax._src import monitoring
 
             monitoring.register_event_listener(_on_jax_event)
+            observe.compilelog.install()
             _listener_installed = True
 
 
@@ -763,6 +766,7 @@ def _compile_program(init_fn, key, out_shardings, label=None, *,
     jitted = jax.jit(init_fn, **kw)
     opts = _compiler_options() if init_compiler_options else None
     attrs = {} if label is None else {"group": label}
+    _install_cache_listener()  # before the lowering: its trace is logged too
     t0 = time.perf_counter()
     with observe.span("jax.lower", category="jax", **attrs):
         def _do_lower():
@@ -774,7 +778,6 @@ def _compile_program(init_fn, key, out_shardings, label=None, *,
         lowered = _bounded_stage("lower", _do_lower, deadline=deadline,
                                  group=gno)
     t_lower = time.perf_counter() - t0
-    _install_cache_listener()
     cdir = _chaos_cache_path()
     reg = regkey = reg_payload = None
     if program_fp is not None and not bypass_cache:

@@ -56,7 +56,9 @@ per-group compiles, the ``tdx.jax.pipeline_overlap`` gauge (busy/wall;
 > 1 means trace, compile, and execute genuinely overlapped), and the
 ``tdx.jax.compile_cache_*`` counters — which stay EXACT under concurrent
 compiles because the oracle is jax's monitoring stream attributed per
-compiling thread, not cache-directory differencing.
+compiling thread, not cache-directory differencing.  What compiled, and
+for how long, is :mod:`.compilelog`: jax's own trace / lowering /
+backend-compile durations with the function's name, always on.
 """
 
 from __future__ import annotations
@@ -66,7 +68,8 @@ import os
 import threading
 from typing import Optional
 
-from . import costmodel, flightrec, health, httpd, reqledger, slo, tracectx
+from . import (compilelog, costmodel, flightrec, health, httpd, reqledger, slo,
+               tracectx)
 from .metrics import Counter, Counters, Gauge, Histogram, JsonlSink
 from .spans import Span, Tracer, _NOOP_SPAN, set_drop_hook, set_flight_feed
 from .step import StepMeter, peak_tflops_for
@@ -81,6 +84,7 @@ __all__ = [
     "Span",
     "StepMeter",
     "Tracer",
+    "compilelog",
     "costmodel",
     "counter",
     "counters",
@@ -266,6 +270,7 @@ def reset() -> None:
     _COUNTERS.clear()
     flightrec.clear()
     reqledger.reset()
+    compilelog.clear()
     _last_counters_sig = None
 
 

@@ -9,12 +9,23 @@ a process-wide event log, and Chrome-trace export loadable in
 Timestamps are epoch-anchored microseconds measured on the monotonic clock
 (``perf_counter`` delta from an import-time epoch pairing), so traces from
 several processes of one run — bench phases each run in a subprocess —
-merge into a coherent timeline.
+merge into a coherent timeline.  :func:`from_perf_counter` is the one
+conversion onto that clock, for a reader that holds ``perf_counter``
+readings of its own (a benchmark's window) and wants the events inside.
+
+A span has a second sink: once jax is loaded, every :class:`Span` also
+opens a ``jax.profiler.TraceAnnotation`` of its name, so that while a
+profiler session runs the program's spans lie on ``/host:CPU`` of the
+same ``.xplane.pb`` as the device's operations, on the profiler's clock.
+The annotation carries the arguments the span was OPENED with (what
+``set`` adds later reaches the tracer's event only).  jax is looked up,
+never imported: a process that has not loaded it mirrors nothing.
 """
 
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -58,9 +69,28 @@ def set_trace_label(label: Optional[str]) -> None:
     _trace_label = label
 
 
+def from_perf_counter(t: float) -> float:
+    """A ``time.perf_counter()`` reading as this module's timestamp
+    (epoch-anchored microseconds), the ``ts`` of every recorded event."""
+    return (_EPOCH0 + (t - _PERF0)) * 1e6
+
+
 def now_us() -> float:
     """Epoch-anchored monotonic timestamp in microseconds."""
-    return (_EPOCH0 + (time.perf_counter() - _PERF0)) * 1e6
+    return from_perf_counter(time.perf_counter())
+
+
+_annotation_cls = None
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` once the process has loaded jax,
+    else None."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation_cls = getattr(profiler, "TraceAnnotation", None)
+    return _annotation_cls
 
 
 class Span:
@@ -70,7 +100,7 @@ class Span:
 
     __slots__ = (
         "name", "category", "args", "t0_us", "dur_us",
-        "_tracer", "_child_us", "_blocked", "_entered",
+        "_tracer", "_child_us", "_blocked", "_entered", "_note",
     )
 
     def __init__(self, tracer: "Tracer", name: str, category: str,
@@ -84,6 +114,7 @@ class Span:
         self._child_us = 0.0
         self._blocked: Any = None
         self._entered = False
+        self._note: Any = None
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
@@ -103,6 +134,12 @@ class Span:
     def __enter__(self) -> "Span":
         self._entered = True
         self._tracer._push(self)
+        note = _profiler_annotation()
+        if note is not None:
+            # Opened before the span's own start and closed after its end,
+            # so the annotations nest exactly as the spans do.
+            self._note = note(self.name, **self.args)
+            self._note.__enter__()
         self.t0_us = now_us()
         return self
 
@@ -113,6 +150,9 @@ class Span:
             jax.block_until_ready(self._blocked)
             self._blocked = None  # don't pin device arrays past the scope
         self.dur_us = now_us() - self.t0_us
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+            self._note = None
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         self._tracer._pop(self)

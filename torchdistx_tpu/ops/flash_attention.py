@@ -494,6 +494,7 @@ def _fwd_call(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="tdx_flash_attention_fwd",
     )(*operands)
     return out[:, :S], lse  # lse stays padded; backward re-pads to match
 
@@ -550,6 +551,7 @@ def _bwd_call(
         out_shape=jax.ShapeDtypeStruct(qp.shape, qh.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="tdx_flash_attention_dq",
     )(*dq_operands)
 
     # Query-head row for (kv head bkv, group g) is bkv*groups + g; the
@@ -607,6 +609,7 @@ def _bwd_call(
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="tdx_flash_attention_dkv",
     )(*dkv_operands)
 
     if not want_dbias:
@@ -682,6 +685,7 @@ def _dbias_call(
         out_shape=jax.ShapeDtypeStruct((Hb, qp.shape[1], kp.shape[1]), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
         interpret=interpret,
+        name="tdx_flash_attention_dbias",
     )(*operands)
     return dbias[:, :S, :T]
 

@@ -285,6 +285,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, gp, D), q.dtype),
         interpret=interpret,
+        name="tdx_paged_attention_decode",
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), qh,
       k_pages, v_pages)
     return out[:, :groups].reshape(B, KV * groups, D)

@@ -68,10 +68,15 @@ Telemetry (docs/observability.md): ``tdx.serve.tokens_per_s``,
 ``ttft_s`` / ``queue_wait_s`` / ``token_latency_s`` (histograms),
 ``queue_depth``, ``kv_pages_in_use`` (from the allocator),
 ``preempted_requests``, plus ``requests_completed`` / ``prefills`` /
-``decode_steps`` counters and ``serve.step`` / ``serve.prefill`` /
-``serve.spin_up`` spans.  SLOs (docs/observability.md §SLOs): every
-engine feeds sliding windows over TTFT, per-token latency, and queue
-wait (:class:`~torchdistx_tpu.observe.slo.ServeSLO`), published as
+``decode_steps`` / ``attended_tokens`` / ``decode_lane_ticks`` counters
+and ``serve.step`` / ``serve.prefill`` / ``serve.spin_up`` spans; inside
+a step, ``serve.admit``, ``serve.tick.tables``, ``serve.program``,
+``serve.tick.d2h`` and ``serve.tick.emit`` split the host's part of a
+tick from the device's (``serve.program`` waits for the logits while
+telemetry is on, so what lies outside it is the time the chip idles).
+SLOs (docs/observability.md §SLOs): every engine feeds sliding windows
+over TTFT, per-token latency, and queue wait
+(:class:`~torchdistx_tpu.observe.slo.ServeSLO`), published as
 ``tdx.serve.slo.*_p{50,95,99}_s`` gauges — live via the periodic
 exporter when ``TDX_METRICS_EXPORT_S`` is set.  A step fault or a
 preemption also dumps the flight recorder (``TDX_FLIGHT_DIR``), so a
@@ -240,6 +245,12 @@ class ServeEngine:
             "tdx.serve.token_latency_s",
             buckets=(0.001, 0.005, 0.02, 0.1, 0.5, 2.0, 10.0),
         )
+        self._decode_steps = observe.counter("tdx.serve.decode_steps")
+        # Per decode / verify tick: the context tokens its lanes attend
+        # over and the lanes it decodes (the work a tick's time buys).
+        self._attended = observe.counter("tdx.serve.attended_tokens")
+        self._lane_ticks = observe.counter("tdx.serve.decode_lane_ticks")
+        self._n_admitted = 0  # lifetime; serve.admit reports a tick's share
 
     # -- program cache ------------------------------------------------------
 
@@ -532,9 +543,13 @@ class ServeEngine:
         ):
             try:
                 self._take_serve_faults()
-                self._expire_deadlines()
-                self._advance_prefill()
-                self._admit()
+                admitted = self._n_admitted
+                with observe.span("serve.admit", category="serve") as sp:
+                    self._expire_deadlines()
+                    self._advance_prefill()
+                    self._admit()
+                    sp.set(admitted=self._n_admitted - admitted,
+                           waiting=len(self.waiting))
                 if self._pending_chunk_faults:
                     # A chunk fault due on a step with no chunk
                     # boundary to defer to still fires (a plan's fault
@@ -587,6 +602,27 @@ class ServeEngine:
                 self._pending_verify_faults.append(fault)
             else:
                 chaos.execute(fault)
+
+    def _run_program(self, name: str, *args, lanes: int, attended: int,
+                     fetch: bool = True) -> Optional[np.ndarray]:
+        """Call the compiled model program ``name`` on the params, the
+        pools and ``args`` under ``serve.program`` and bring its logits to
+        the host under ``serve.tick.d2h`` (``fetch`` False: a chunk that
+        is not a prompt's last, whose logits nobody reads).  While
+        telemetry is on ``serve.program`` ends when the logits are ready,
+        so the two spans split device time from the copy; off, nothing
+        waits before the fetch.  ``attended`` is the context the program's
+        ``lanes`` attend over, counted before anything retires."""
+        with observe.span("serve.program", category="serve", program=name,
+                          lanes=lanes, attended_tokens=attended) as sp:
+            logits, self.k_pages, self.v_pages = self._program(name)(
+                self.params, self.k_pages, self.v_pages, *args)
+            sp.block_on(logits)
+        if not fetch:
+            return None
+        with observe.span("serve.tick.d2h", category="serve", program=name,
+                          bytes=logits.nbytes):
+            return np.asarray(logits)
 
     def _free_slot(self) -> Optional[int]:
         for s in range(self.scfg.max_batch):
@@ -675,19 +711,21 @@ class ServeEngine:
                         and L <= self._chunk_cap()):
                     # Classic single-shot path: fresh prompt, one chunk.
                     bucket = self.scfg.bucket_for(L)
-                    toks = np.zeros((1, bucket), np.int32)
-                    toks[0, :L] = req.tokens
-                    row = np.asarray(
-                        [self.kv.table_row(sid,
-                                           self.scfg.max_pages_per_seq)],
-                        np.int32,
-                    )
-                    logits, self.k_pages, self.v_pages = self._program(
-                        f"prefill-{bucket}"
-                    )(self.params, self.k_pages, self.v_pages,
-                      jnp.asarray(toks), jnp.asarray([L], jnp.int32),
-                      jnp.asarray(row))
-                    logits = np.asarray(logits)
+                    name = f"prefill-{bucket}"
+                    with observe.span("serve.tick.tables", category="serve",
+                                      program=name):
+                        toks = np.zeros((1, bucket), np.int32)
+                        toks[0, :L] = req.tokens
+                        row = np.asarray(
+                            [self.kv.table_row(sid,
+                                               self.scfg.max_pages_per_seq)],
+                            np.int32,
+                        )
+                        args = (jnp.asarray(toks),
+                                jnp.asarray([L], jnp.int32),
+                                jnp.asarray(row))
+                    logits = self._run_program(name, *args, lanes=1,
+                                               attended=L)
                     lane.length = L
                     reqledger.on_event(req.rid, "prefill", bucket=bucket,
                                        n=L, replica=self.slo.name)
@@ -712,6 +750,7 @@ class ServeEngine:
                                reason="prefill_fault")
             raise
         self.active[slot] = lane
+        self._n_admitted += 1
         observe.counter("tdx.serve.prefills").inc()
         observe.counter("tdx.serve.prefill_tokens").inc(L - start)
         if logits is not None:
@@ -727,28 +766,28 @@ class ServeEngine:
         s = lane.length
         n = min(L - s, self._chunk_cap())
         bucket = self.scfg.bucket_for(n)
-        # Only a chunk's FIRST page can be shared (later pages were
-        # written by this very sequence's earlier chunks); cow_page
-        # no-ops at refcount 1, so this is unconditional.
-        self._cow_for(lane, s // self.scfg.page_size)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = req.tokens[s:s + n]
-        row = np.asarray(
-            [self.kv.table_row(lane.seq_id, self.scfg.max_pages_per_seq)],
-            np.int32,
-        )
-        logits, self.k_pages, self.v_pages = self._program(
-            f"chunk-{bucket}"
-        )(self.params, self.k_pages, self.v_pages, jnp.asarray(toks),
-          jnp.asarray([s], jnp.int32), jnp.asarray([s + n], jnp.int32),
-          jnp.asarray(row))
+        name = f"chunk-{bucket}"
+        with observe.span("serve.tick.tables", category="serve",
+                          program=name):
+            # Only a chunk's FIRST page can be shared (later pages were
+            # written by this very sequence's earlier chunks); cow_page
+            # no-ops at refcount 1, so this is unconditional.
+            self._cow_for(lane, s // self.scfg.page_size)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :n] = req.tokens[s:s + n]
+            row = np.asarray(
+                [self.kv.table_row(lane.seq_id, self.scfg.max_pages_per_seq)],
+                np.int32,
+            )
+            args = (jnp.asarray(toks), jnp.asarray([s], jnp.int32),
+                    jnp.asarray([s + n], jnp.int32), jnp.asarray(row))
+        logits = self._run_program(name, *args, lanes=1, attended=s + n,
+                                   fetch=s + n >= L)
         lane.length = s + n
         observe.counter("tdx.serve.prefill_chunks").inc()
         reqledger.on_chunk(req.rid, bucket=bucket, n_tokens=n,
                            replica=self.slo.name)
-        if lane.length >= L:
-            return np.asarray(logits)
-        return None
+        return logits
 
     def _cow_for(self, lane: _Lane, page_index: int) -> None:
         """Give ``lane`` a private copy of its ``page_index``-th page if
@@ -771,10 +810,15 @@ class ServeEngine:
                 raise  # pragma: no cover — ref>1 implies an evictee
         if moved is not None:
             src, dst = moved
-            self.k_pages, self.v_pages = self._program("cow")(
-                self.k_pages, self.v_pages,
-                jnp.asarray([src], jnp.int32), jnp.asarray([dst], jnp.int32),
-            )
+            with observe.span("serve.program", category="serve",
+                              program="cow", lanes=1,
+                              attended_tokens=0) as sp:
+                self.k_pages, self.v_pages = self._program("cow")(
+                    self.k_pages, self.v_pages,
+                    jnp.asarray([src], jnp.int32),
+                    jnp.asarray([dst], jnp.int32),
+                )
+                sp.block_on(self.k_pages)
             observe.counter("tdx.serve.cow_copies").inc()
             reqledger.on_cow(lane.req.rid, replica=self.slo.name)
 
@@ -808,26 +852,28 @@ class ServeEngine:
         lane.prefilling = False
         req = lane.req
         L = len(req.tokens)
-        nfull = L // self.scfg.page_size
-        if nfull and self.scfg.prefix_cache:
-            self.prefix.insert(
-                req.tokens[:nfull * self.scfg.page_size],
-                self.kv.page_ids(lane.seq_id)[:nfull],
-            )
-        # A re-prefill after preemption replays a first token the client
-        # already received — it must not contribute a (huge, bogus) TTFT
-        # sample; prefills/prefill_tokens keep counting, they measure
-        # engine work, not delivery.
-        first_delivery = self._delivered.get(req.rid, 0) == 0
-        self._emit(lane, int(np.argmax(logits)), logits)
-        if first_delivery:
-            # One clock read; no fabricated zero sample for a request
-            # that never passed submit() (same contract as queue wait).
-            sub = getattr(req, "_submit_t", None)
-            if sub is not None:
-                ttft = time.perf_counter() - sub
-                observe.histogram("tdx.serve.ttft_s").observe(ttft)
-                self.slo.observe_ttft(ttft)
+        with observe.span("serve.tick.emit", category="serve",
+                          program="prefill", tokens=1):
+            nfull = L // self.scfg.page_size
+            if nfull and self.scfg.prefix_cache:
+                self.prefix.insert(
+                    req.tokens[:nfull * self.scfg.page_size],
+                    self.kv.page_ids(lane.seq_id)[:nfull],
+                )
+            # A re-prefill after preemption replays a first token the
+            # client already received — it must not contribute a (huge,
+            # bogus) TTFT sample; prefills/prefill_tokens keep counting,
+            # they measure engine work, not delivery.
+            first_delivery = self._delivered.get(req.rid, 0) == 0
+            self._emit(lane, int(np.argmax(logits)), logits)
+            if first_delivery:
+                # One clock read; no fabricated zero sample for a request
+                # that never passed submit() (same contract as queue wait).
+                sub = getattr(req, "_submit_t", None)
+                if sub is not None:
+                    ttft = time.perf_counter() - sub
+                    observe.histogram("tdx.serve.ttft_s").observe(ttft)
+                    self.slo.observe_ttft(ttft)
 
     # -- decode ---------------------------------------------------------------
 
@@ -869,56 +915,63 @@ class ServeEngine:
             self._plain_decode_step()
 
     def _plain_decode_step(self) -> None:
-        self._ensure_capacity()
-        slots = self._decodable()
-        if not slots:
-            return
-        t_step = time.perf_counter()
-        B = self.scfg.max_batch
-        maxp = self.scfg.max_pages_per_seq
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        table = np.zeros((B, maxp), np.int32)
-        # One batched table build for the whole tick (the per-lane
-        # Python loop was the decode hot path's host-side tax).
-        table[slots] = self.kv.table_rows(
-            [self.active[s].seq_id for s in slots], maxp
-        )
-        for slot in slots:
-            lane = self.active[slot]
-            tokens[slot] = (lane.generated[-1] if lane.generated
-                            else lane.req.tokens[-1])
-            positions[slot] = lane.length
-        logits, self.k_pages, self.v_pages = self._program("decode")(
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(table),
-        )
-        logits = np.asarray(logits)
-        # Per-token latency: every lane's next token took this step's
-        # wall time (np.asarray above forced the device work) — one
-        # sample PER LANE, so the distribution weights a 4-wide step as
-        # the four token deliveries it was.
-        dt = time.perf_counter() - t_step
+        with observe.span("serve.tick.tables", category="serve",
+                          program="decode"):
+            self._ensure_capacity()
+            slots = self._decodable()
+            if not slots:
+                return
+            t_step = time.perf_counter()
+            B = self.scfg.max_batch
+            maxp = self.scfg.max_pages_per_seq
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            table = np.zeros((B, maxp), np.int32)
+            # One batched table build for the whole tick (the per-lane
+            # Python loop was the decode hot path's host-side tax).
+            table[slots] = self.kv.table_rows(
+                [self.active[s].seq_id for s in slots], maxp
+            )
+            for slot in slots:
+                lane = self.active[slot]
+                tokens[slot] = (lane.generated[-1] if lane.generated
+                                else lane.req.tokens[-1])
+                positions[slot] = lane.length
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(table))
         n_lanes = len(slots)
-        if n_lanes:
+        # A lane at position p attends over p + 1 tokens, its new one
+        # included (idle lanes sit at 0 and attend over nothing).
+        attended = int(positions.sum()) + n_lanes
+        logits = self._run_program("decode", *args, lanes=n_lanes,
+                                   attended=attended)
+        with observe.span("serve.tick.emit", category="serve",
+                          program="decode", tokens=n_lanes):
+            # Per-token latency: every lane's next token took this step's
+            # wall time (the fetch above forced the device work) — one
+            # sample PER LANE, so the distribution weights a 4-wide step
+            # as the four token deliveries it was.
+            dt = time.perf_counter() - t_step
             self._tok_hist.observe(dt, n=n_lanes)
             self.slo.observe_token_latency(dt, n=n_lanes)
-        if reqledger.enabled():
-            # One coalesced timeline event per decode stretch per lane;
-            # the enabled() gate is hoisted so the off path costs one
-            # check per tick, not one per lane.
+            if reqledger.enabled():
+                # One coalesced timeline event per decode stretch per
+                # lane; the enabled() gate is hoisted so the off path
+                # costs one check per tick, not one per lane.
+                for slot in slots:
+                    lane = self.active.get(slot)
+                    if lane is not None:
+                        reqledger.on_decode(lane.req.rid, n_lanes=n_lanes,
+                                            replica=self.slo.name)
             for slot in slots:
                 lane = self.active.get(slot)
-                if lane is not None:
-                    reqledger.on_decode(lane.req.rid, n_lanes=n_lanes,
-                                        replica=self.slo.name)
-        for slot in slots:
-            lane = self.active.get(slot)
-            if lane is None:  # pragma: no cover — nothing retires mid-loop
-                continue
-            lane.length += 1
-            self._emit(lane, int(np.argmax(logits[slot])), logits[slot])
-        observe.counter("tdx.serve.decode_steps").inc()
+                if lane is None:  # pragma: no cover — nothing retires mid-loop
+                    continue
+                lane.length += 1
+                self._emit(lane, int(np.argmax(logits[slot])), logits[slot])
+            self._decode_steps.inc()
+            self._attended.inc(attended)
+            self._lane_ticks.inc(n_lanes)
 
     # -- speculative decode (docs/serving.md §Speculative decoding) ---------
 
@@ -988,108 +1041,117 @@ class ServeEngine:
             # the same tick at width 1, without the rollback tax.
             self._plain_decode_step()
             return
-        self._ensure_spec_capacity(drafts)
-        # COW guard for the write at position ``length`` (no-op at
-        # refcount 1, like the chunk path) — BEFORE the page tables are
-        # snapshotted: a cow under pool pressure can preempt a lane, and
-        # a stale table row would let the verify tick scatter a dead
-        # lane's K/V into a freshly reused page.
-        for slot in self._decodable():
-            lane = self.active.get(slot)
-            if lane is not None:
-                self._cow_for(lane, lane.length // self.scfg.page_size)
-        slots = self._decodable()
-        if not slots:
-            return
-        if self._pending_verify_faults:
-            # The deferred ``raise:verify`` chaos fault: after drafting
-            # and capacity growth, before the verify call — the step
-            # fault handler must requeue lanes whose KV already covers
-            # speculative positions.
-            chaos.execute(self._pending_verify_faults.pop(0))
-        t_step = time.perf_counter()
-        B = self.scfg.max_batch
-        maxp = self.scfg.max_pages_per_seq
-        kb = self.scfg.spec_bucket_for(
-            max(len(drafts.get(s, ())) for s in slots) or 1)
-        tokens = np.zeros((B, kb + 1), np.int32)
-        start = np.zeros((B,), np.int32)
-        end = np.zeros((B,), np.int32)
-        table = np.zeros((B, maxp), np.int32)
-        table[slots] = self.kv.table_rows(
-            [self.active[s].seq_id for s in slots], maxp
-        )
-        for slot in slots:
-            lane = self.active[slot]
-            d = drafts.get(slot, ())
-            tokens[slot, 0] = (lane.generated[-1] if lane.generated
-                               else lane.req.tokens[-1])
-            if d:
-                tokens[slot, 1:1 + len(d)] = d
-            start[slot] = lane.length
-            end[slot] = lane.length + len(d) + 1
-        logits, self.k_pages, self.v_pages = self._program(f"verify-{kb}")(
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(end),
-            jnp.asarray(table),
-        )
-        logits = np.asarray(logits)
-        dt = time.perf_counter() - t_step
+        with observe.span("serve.tick.tables", category="serve",
+                          program="verify"):
+            self._ensure_spec_capacity(drafts)
+            # COW guard for the write at position ``length`` (no-op at
+            # refcount 1, like the chunk path) — BEFORE the page tables
+            # are snapshotted: a cow under pool pressure can preempt a
+            # lane, and a stale table row would let the verify tick
+            # scatter a dead lane's K/V into a freshly reused page.
+            for slot in self._decodable():
+                lane = self.active.get(slot)
+                if lane is not None:
+                    self._cow_for(lane, lane.length // self.scfg.page_size)
+            slots = self._decodable()
+            if not slots:
+                return
+            if self._pending_verify_faults:
+                # The deferred ``raise:verify`` chaos fault: after
+                # drafting and capacity growth, before the verify call —
+                # the step fault handler must requeue lanes whose KV
+                # already covers speculative positions.
+                chaos.execute(self._pending_verify_faults.pop(0))
+            t_step = time.perf_counter()
+            B = self.scfg.max_batch
+            maxp = self.scfg.max_pages_per_seq
+            kb = self.scfg.spec_bucket_for(
+                max(len(drafts.get(s, ())) for s in slots) or 1)
+            tokens = np.zeros((B, kb + 1), np.int32)
+            start = np.zeros((B,), np.int32)
+            end = np.zeros((B,), np.int32)
+            table = np.zeros((B, maxp), np.int32)
+            table[slots] = self.kv.table_rows(
+                [self.active[s].seq_id for s in slots], maxp
+            )
+            for slot in slots:
+                lane = self.active[slot]
+                d = drafts.get(slot, ())
+                tokens[slot, 0] = (lane.generated[-1] if lane.generated
+                                   else lane.req.tokens[-1])
+                if d:
+                    tokens[slot, 1:1 + len(d)] = d
+                start[slot] = lane.length
+                end[slot] = lane.length + len(d) + 1
+            args = (jnp.asarray(tokens), jnp.asarray(start),
+                    jnp.asarray(end), jnp.asarray(table))
         n_lanes = len(slots)
-        ledger_on = reqledger.enabled()
-        total_emitted = 0
-        for slot in slots:
-            lane = self.active.get(slot)
-            if lane is None:  # pragma: no cover — nothing retires mid-loop
-                continue
-            d = drafts.get(slot, [])
-            rows = logits[slot]  # [kb+1, vocab]
-            accepted = 0
-            emitted: List[int] = []
-            for i, guess in enumerate(d):
-                t = int(np.argmax(rows[i]))
-                emitted.append(t)
-                if t != guess:
-                    break  # first wrong draft; t is the corrected token
-                accepted += 1
-            if accepted == len(d):
-                # Clean sweep: the last verified position yields one
-                # bonus token for free.
-                emitted.append(int(np.argmax(rows[len(d)])))
-            self.spec_drafted += len(d)
-            self.spec_accepted += accepted
-            if d:
-                # Per-lane k adaptation on the trailing outcome: grow
-                # back toward the configured cap on a clean sweep, back
-                # off when under half the draft survived.
+        # A lane verifying d drafts from position p attends over
+        # p + d + 1 tokens at its last row: its ``end``.
+        attended = int(end.sum())
+        logits = self._run_program(f"verify-{kb}", *args, lanes=n_lanes,
+                                   attended=attended)
+        with observe.span("serve.tick.emit", category="serve",
+                          program=f"verify-{kb}") as sp:
+            dt = time.perf_counter() - t_step
+            ledger_on = reqledger.enabled()
+            total_emitted = 0
+            for slot in slots:
+                lane = self.active.get(slot)
+                if lane is None:  # pragma: no cover — nothing retires mid-loop
+                    continue
+                d = drafts.get(slot, [])
+                rows = logits[slot]  # [kb+1, vocab]
+                accepted = 0
+                emitted: List[int] = []
+                for i, guess in enumerate(d):
+                    t = int(np.argmax(rows[i]))
+                    emitted.append(t)
+                    if t != guess:
+                        break  # first wrong draft; t is the corrected token
+                    accepted += 1
                 if accepted == len(d):
-                    lane.spec_k = min(lane.spec_k + 1, self.scfg.spec_k)
-                elif accepted * 2 < len(d):
-                    lane.spec_k = max(1, lane.spec_k - 1)
-            if ledger_on:
-                reqledger.on_spec(lane.req.rid, drafted=len(d),
-                                  accepted=accepted, emitted=len(emitted),
-                                  n_lanes=n_lanes, replica=self.slo.name)
-            # Token-level rollback: the verify tick wrote K/V for every
-            # position in [length, length+len(d)]; positions past the
-            # accepted prefix hold rejected-draft state — retract them
-            # so the cache is bitwise what plain decode would have
-            # built before the next tick can read it.
-            self.kv.rollback(lane.seq_id, lane.length + accepted + 1)
-            for i, tok in enumerate(emitted):
-                lane.length += 1
-                self._emit(lane, tok, rows[i])
-                total_emitted += 1
-                if lane.slot not in self.active:
-                    break  # retired (eos / budget); KV already freed
-        self.spec_verify_ticks += 1
-        if total_emitted:
-            # Every token delivered this tick took the tick's wall time
-            # (they arrive together — that IS the speedup): one sample
-            # per token, the plain path's weighting contract.
-            self._tok_hist.observe(dt, n=total_emitted)
-            self.slo.observe_token_latency(dt, n=total_emitted)
-        observe.counter("tdx.serve.decode_steps").inc()
+                    # Clean sweep: the last verified position yields one
+                    # bonus token for free.
+                    emitted.append(int(np.argmax(rows[len(d)])))
+                self.spec_drafted += len(d)
+                self.spec_accepted += accepted
+                if d:
+                    # Per-lane k adaptation on the trailing outcome: grow
+                    # back toward the configured cap on a clean sweep,
+                    # back off when under half the draft survived.
+                    if accepted == len(d):
+                        lane.spec_k = min(lane.spec_k + 1, self.scfg.spec_k)
+                    elif accepted * 2 < len(d):
+                        lane.spec_k = max(1, lane.spec_k - 1)
+                if ledger_on:
+                    reqledger.on_spec(lane.req.rid, drafted=len(d),
+                                      accepted=accepted,
+                                      emitted=len(emitted),
+                                      n_lanes=n_lanes, replica=self.slo.name)
+                # Token-level rollback: the verify tick wrote K/V for
+                # every position in [length, length+len(d)]; positions
+                # past the accepted prefix hold rejected-draft state —
+                # retract them so the cache is bitwise what plain decode
+                # would have built before the next tick can read it.
+                self.kv.rollback(lane.seq_id, lane.length + accepted + 1)
+                for i, tok in enumerate(emitted):
+                    lane.length += 1
+                    self._emit(lane, tok, rows[i])
+                    total_emitted += 1
+                    if lane.slot not in self.active:
+                        break  # retired (eos / budget); KV already freed
+            self.spec_verify_ticks += 1
+            if total_emitted:
+                # Every token delivered this tick took the tick's wall
+                # time (they arrive together — that IS the speedup): one
+                # sample per token, the plain path's weighting contract.
+                self._tok_hist.observe(dt, n=total_emitted)
+                self.slo.observe_token_latency(dt, n=total_emitted)
+            sp.set(tokens=total_emitted)
+            self._decode_steps.inc()
+            self._attended.inc(attended)
+            self._lane_ticks.inc(n_lanes)
 
     def _emit(self, lane: _Lane, token: int, logits: np.ndarray) -> None:
         lane.generated.append(token)
@@ -1253,46 +1315,57 @@ def spin_up_replica(
         "serve.spin_up", category="serve", family=family,
         warm=bool(warm),
     ) as sp:
-        specs = serve_program_specs(
-            family, cfg, serve_cfg, seed=seed, param_dtype=param_dtype,
-            mesh=mesh, plan=plan, sample_len=sample_len,
-        )
+        # Children for what a clock around the bring-up cannot split:
+        # the deferred-init trace that builds the specs, the init
+        # program's load (compile or cache fetch), its run, the engine
+        # with its pools, and the program set's warm-up.
+        with observe.span("serve.spin_up.specs", category="serve"):
+            specs = serve_program_specs(
+                family, cfg, serve_cfg, seed=seed, param_dtype=param_dtype,
+                mesh=mesh, plan=plan, sample_len=sample_len,
+            )
         init = specs[0]
         assert init.name == "init"
-        compiled, init_outcome = compile_serving_program(init)
-        values = compiled()
-        if init.tplan is not None:
-            # Low-precision transport (TDX_MATERIALIZE_INIT_DTYPE): the
-            # init program delivered eligible params in the init dtype;
-            # upcast them on device to the contract dtypes the lowered
-            # prefill/decode signatures expect (donated staging buffers,
-            # same retry contract as the materialization engines).
-            from .. import config as _tdx_config
-            from ..jax_bridge import transport as _transport
-            from ..jax_bridge.materialize import _retryable_errors
+        with observe.span("serve.spin_up.init_load", category="serve"):
+            compiled, init_outcome = compile_serving_program(init)
+        with observe.span("serve.spin_up.init_run", category="serve"):
+            values = compiled()
+            if init.tplan is not None:
+                # Low-precision transport (TDX_MATERIALIZE_INIT_DTYPE): the
+                # init program delivered eligible params in the init dtype;
+                # upcast them on device to the contract dtypes the lowered
+                # prefill/decode signatures expect (donated staging buffers,
+                # same retry contract as the materialization engines).
+                from .. import config as _tdx_config
+                from ..jax_bridge import transport as _transport
+                from ..jax_bridge.materialize import _retryable_errors
 
-            cfg_eff = _tdx_config.get()
-            values, _donated = _transport.commit_outputs(
-                values, init.tplan,
-                donate=cfg_eff.materialize_donate,
-                producer=lambda: compiled(),
-                retries=max(0, cfg_eff.materialize_retries),
-                retryable=_retryable_errors(),
+                cfg_eff = _tdx_config.get()
+                values, _donated = _transport.commit_outputs(
+                    values, init.tplan,
+                    donate=cfg_eff.materialize_donate,
+                    producer=lambda: compiled(),
+                    retries=max(0, cfg_eff.materialize_retries),
+                    retryable=_retryable_errors(),
+                )
+            params = jax.tree.unflatten(init.treedef, list(values))
+            jax.block_until_ready(values)
+        with observe.span("serve.spin_up.pools", category="serve") as psp:
+            engine = ServeEngine(
+                family, cfg, params, serve_cfg=serve_cfg, mesh=mesh,
+                plan=plan, seed=seed, param_dtype=param_dtype,
+                on_token=on_token, on_complete=on_complete,
+                on_cancel=on_cancel, slo_name=slo_name,
             )
-        params = jax.tree.unflatten(init.treedef, list(values))
-        jax.block_until_ready(values)
-        engine = ServeEngine(
-            family, cfg, params, serve_cfg=serve_cfg, mesh=mesh, plan=plan,
-            seed=seed, param_dtype=param_dtype, on_token=on_token,
-            on_complete=on_complete, on_cancel=on_cancel, slo_name=slo_name,
-        )
+            psp.block_on((engine.k_pages, engine.v_pages))
         # The spec list above already paid the model's deferred-init
         # trace; hand it to the engine so warmup/lazy compiles reuse it.
         engine._spec_cache = {s.name: s for s in specs if s.name != "init"}
         outcomes = {"init": init_outcome}
         observe.health.set_state(health_component, "warming")
         if warm:
-            outcomes.update(engine.warmup())
+            with observe.span("serve.spin_up.warmup", category="serve"):
+                outcomes.update(engine.warmup())
         engine.bring_up_outcomes = outcomes
         engine.bring_up_seconds = time.perf_counter() - t0
         observe.health.set_state(health_component, "serving")

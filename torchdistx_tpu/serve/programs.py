@@ -373,6 +373,23 @@ def _chunk_block(cfg, blk, x, kp, vp, *, angles, positions, end,
     return x, kp, vp
 
 
+def _program_name(name: str) -> Callable:
+    """Decorator for a serving program's body: run it under
+    ``jax.named_scope(name)``, which prefixes the ``op_name`` metadata of
+    every operation it stages, and call it ``name``, which ``jax.jit``
+    makes the HLO module's name (``jit_<name>``).  The module's name is
+    what the profiler's ``XLA Modules`` line and the compile log show, so
+    two prefill buckets can be told apart there; the scope is what an
+    operation's metadata carries."""
+
+    def wrap(fn: Callable) -> Callable:
+        fn = jax.named_scope(name)(fn)
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    return wrap
+
+
 def _scan_blocks(decomp, p, x, k_pages, v_pages, block_step):
     """Thread x through the scan-stacked layers; the per-layer pool
     slices ride the scan as mapped inputs/outputs, so the whole stack's
@@ -399,6 +416,7 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
     decomp = make_model(family, cfg).decode_decomposition()
     attend = _decode_attention(mesh, cfg.kv_heads)
 
+    @_program_name("tdx_serve_decode")
     def decode_fn(params, k_pages, v_pages, tokens, positions, page_table):
         p = params["params"]
         x = decomp.embed(p, tokens[:, None], positions[:, None])
@@ -432,6 +450,7 @@ def build_prefill_fn(family: str, cfg: TransformerConfig,
     logits are the LAST VALID position's (the first generated token)."""
     decomp = make_model(family, cfg).decode_decomposition()
 
+    @_program_name(f"tdx_serve_prefill_{bucket}")
     def prefill_fn(params, k_pages, v_pages, tokens, length, page_table):
         p = params["params"]
         S = tokens.shape[1]
@@ -470,6 +489,7 @@ def build_chunk_prefill_fn(family: str, cfg: TransformerConfig,
     generated token) only on the final chunk, ignored otherwise."""
     decomp = make_model(family, cfg).decode_decomposition()
 
+    @_program_name(f"tdx_serve_chunk_{bucket}")
     def chunk_fn(params, k_pages, v_pages, tokens, start, end, page_table):
         p = params["params"]
         S = tokens.shape[1]
@@ -515,6 +535,7 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
     at once and the head applied to every position instead of the last."""
     decomp = make_model(family, cfg).decode_decomposition()
 
+    @_program_name(f"tdx_serve_verify_{k}")
     def verify_fn(params, k_pages, v_pages, tokens, start, end, page_table):
         p = params["params"]
         S = tokens.shape[1]  # k + 1
@@ -544,6 +565,7 @@ def build_cow_fn() -> Callable:
     grower about to write into a shared page writes into its private
     copy instead.  Pure pool-to-pool; no params, one donated update."""
 
+    @_program_name("tdx_serve_cow")
     def cow_fn(k_pages, v_pages, src, dst):
         k_pages = k_pages.at[:, dst[0]].set(k_pages[:, src[0]])
         v_pages = v_pages.at[:, dst[0]].set(v_pages[:, src[0]])

@@ -4,7 +4,7 @@ recovery, profiling, logging/metrics."""
 from .checkpoint import AsyncCheckpointSaver, restore_checkpoint, save_checkpoint
 from .failures import FailureDetector, device_health, run_elastic
 from .logging import Metrics, get_logger
-from .profiling import StepTimer, Timer, annotate, trace
+from .profiling import StepTimer, Timer, trace
 
 __all__ = [
     "AsyncCheckpointSaver",
@@ -12,7 +12,6 @@ __all__ = [
     "Metrics",
     "StepTimer",
     "Timer",
-    "annotate",
     "device_health",
     "get_logger",
     "restore_checkpoint",

@@ -1,14 +1,16 @@
 """Profiling / tracing hooks (XLA-level) + deprecated host-timer shims.
 
-The XLA-level story stays here and is first-class: ``trace`` wraps
-``jax.profiler`` (view in TensorBoard/XProf) and ``annotate`` adds named
-regions to device timelines.  Host-side wall timing moved to
-:mod:`torchdistx_tpu.observe` — ``observe.span`` is the block-until-ready
-aware timer that also lands in the exported trace, and
-``observe.StepMeter`` is the training-loop successor of ``StepTimer``.
-``Timer`` and ``StepTimer`` survive as deprecation shims with their
-original semantics (and, when telemetry is enabled, their measurements
-now flow into the shared tracer too).
+``trace`` wraps ``jax.profiler`` (view in TensorBoard/XProf).  Named
+regions on the profiler's timeline come from
+:mod:`torchdistx_tpu.observe`: while telemetry is enabled every
+``observe.span`` also opens a ``jax.profiler.TraceAnnotation`` of its
+name, so a capture made with ``trace`` holds the program's spans on
+``/host:CPU`` beside the device's operations.  ``observe.span`` is also
+the block-until-ready aware host timer, and ``observe.StepMeter`` is the
+training-loop successor of ``StepTimer``.  ``Timer`` and ``StepTimer``
+survive as deprecation shims with their original semantics (and, when
+telemetry is enabled, their measurements now flow into the shared tracer
+too).
 """
 
 from __future__ import annotations
@@ -25,17 +27,21 @@ from .. import observe
 
 @contextlib.contextmanager
 def trace(logdir: str) -> Iterator[None]:
-    """Capture an XLA profile for the enclosed region."""
-    jax.profiler.start_trace(logdir)
+    """Capture an XLA profile for the enclosed region.
+
+    The Python call tracer is off and the host tracer at level 2, as in
+    the benchmark's traced runs: an event for every Python call makes a
+    few seconds of trace tens of MB and slows the host whose gaps the
+    capture is there to explain, while ``TraceAnnotation``s (the
+    program's spans among them) are kept."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region on the device timeline (TraceAnnotation)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 class Timer:
