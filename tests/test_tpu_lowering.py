@@ -132,12 +132,15 @@ def test_paged_attention_lowers_for_tpu(H, KV, D, page, dtype):
     assert _export(fn, q, pool, pool, lens, table).mlir_module()
 
 
-@pytest.mark.parametrize("program", ["decode", "verify-2"])
+@pytest.mark.parametrize("program",
+                         ["decode", "verify-2", "prefill-16", "chunk-16"])
 def test_serving_program_lowers_for_tpu(program, monkeypatch):
     # The whole program as the replica compiles it (2-layer Llama-width
-    # config, bf16 params): scatter into the pool, kernel, head.  The
-    # kernel resolves its own interpret mode inside the program, so the
-    # backend it consults is pinned to "tpu" for the trace.
+    # config, bf16 params): scatter into the flat pool at layer*P + page,
+    # kernel, head -- every program kind, since each addresses the pool
+    # its own way.  The kernel resolves its own interpret mode inside the
+    # program, so the backend it consults is pinned to "tpu" for the
+    # trace.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = LLAMA3_8B.replace(n_layers=2)
     scfg = ServeConfig(max_batch=4, page_size=16, n_pages=16,
@@ -146,6 +149,6 @@ def test_serving_program_lowers_for_tpu(program, monkeypatch):
         "llama", cfg, scfg, param_dtype=jnp.bfloat16, include_init=False)}
     spec = specs[program]
     module = _export(spec.fn, *spec.args).mlir_module()
-    # decode carries the compiled kernel; verify attends through the
+    # decode carries the compiled kernel; the others attend through the
     # gather-based jnp path and must lower without one.
     assert ("tpu_custom_call" in module) == (program == "decode")

@@ -281,60 +281,92 @@ def _decode_attention(mesh, kv_heads: int) -> Callable:
     if sh is None or not any(sh.spec):
         return paged_attention
     heads = P(None, sh.spec[2], None)        # q / out [B, H, D]
-    pool = P(None, sh.spec[2], None, None)   # layer slice [P, KV, page, D]
+    pool = P(None, sh.spec[2], None, None)   # flat pool [L*P, KV, page, D]
     return shard_map(
         paged_attention, mesh=mesh, in_specs=(heads, pool, pool, P(), P()),
         out_specs=heads, check_vma=False,
     )
 
 
-def _decode_block(cfg, blk, x, kp, vp, *, angles, positions, lengths,
-                  page_table, attend):
+def _write_kv(kp, vp, base, table, k, v, start, end):
+    """Write ``k`` and ``v`` [B, S, KV, D] — the rows of positions
+    ``[start, start + S)`` of each sequence, valid below ``end`` — into
+    the flat pools [L*P, KV, page, D], in which this layer's page ``p``
+    is row ``base + p``, through the page table [B, maxp], a whole page
+    at a time: the pages the positions fall into are read, their valid
+    rows replaced, and the pages written back.  The update then indexes
+    the pool's major dim alone and its window is a page, so XLA keeps
+    the pool in the layout the decode kernel reads and updates the
+    scan's carry in place.  A scatter that indexes the slot too
+    (``pool.at[page, :, slot].set``) makes XLA lay the pool out with the
+    kv heads under the token rows, and a carried pool then changes
+    layout, whole, twice a layer; one that indexes every kv head costs a
+    2,048-token chunk what the carry saves (PERF.md, PR 27).  A page
+    with no valid position (padding) is routed to the layer's null page,
+    which gets back what it held."""
+    B, S, KV, D = k.shape
+    page_size = kp.shape[2]
+    n = (S + page_size - 2) // page_size + 1  # pages S positions can span
+    ords = (start // page_size)[:, None] + jnp.arange(n, dtype=jnp.int32)
+    pos = ords[:, :, None] * page_size + jnp.arange(page_size,
+                                                    dtype=jnp.int32)
+    src = pos - start[:, None, None]  # [B, n, page]: the slot's row of k/v
+    valid = (src >= 0) & (src < S) & (pos < end[:, None, None])
+    rows = base + jnp.where(
+        valid.any(-1),
+        jnp.take_along_axis(
+            table, jnp.minimum(ords, table.shape[1] - 1), axis=1),
+        0)
+    src = jnp.clip(src, 0, S - 1).reshape(B, -1, 1, 1)
+    valid = valid[:, :, None, :, None]
+
+    def write(pool, x):
+        new = jnp.take_along_axis(x, src, axis=1)
+        new = new.reshape(B, n, page_size, KV, D).transpose(0, 1, 3, 2, 4)
+        return pool.at[rows].set(jnp.where(valid, new, pool[rows]))
+
+    return write(kp, k), write(vp, v)
+
+
+def _decode_block(cfg, blk, x, kp, vp, base, *, angles, positions,
+                  lengths, page_table, attend):
     """One layer of the decode step: x [B, 1, d]; writes this token's
     K/V at (page, slot) and attends the whole context through the page
-    table."""
+    table.  ``kp`` / ``vp`` are the flat pools, in which this layer's
+    page ``p`` is row ``base + p`` (:func:`_scan_blocks`), so the kernel
+    gets the table offset by ``base`` and the layer's null page is row
+    ``base``."""
     n0, n1 = _norm_keys(cfg)
-    page_size = kp.shape[2]
-    B = x.shape[0]
     h = make_norm(cfg).apply({"params": blk[n0]}, x)
     q, k, v = _qkv(cfg, blk["attn"], h)
     if angles is not None:
         q = apply_rope(q, angles)
         k = apply_rope(k, angles)
-    page = page_table[jnp.arange(B), positions // page_size]
-    slot = positions % page_size
-    # Pool layer slice is [P, KV, page, D]: (page, slot) address a
-    # token row across every kv head.
-    kp = kp.at[page, :, slot].set(k[:, 0])
-    vp = vp.at[page, :, slot].set(v[:, 0])
-    attn = attend(q[:, 0], kp, vp, lengths, page_table)
+    kp, vp = _write_kv(kp, vp, base, page_table, k, v, positions,
+                       positions + 1)
+    attn = attend(q[:, 0], kp, vp, lengths, page_table + base)
     x = x + _attn_out(cfg, blk["attn"], attn[:, None])
     h2 = make_norm(cfg).apply({"params": blk[n1]}, x)
     x = x + _mlp(cfg, blk, h2)
     return x, kp, vp
 
 
-def _prefill_block(cfg, blk, x, kp, vp, *, angles, positions, length,
+def _prefill_block(cfg, blk, x, kp, vp, base, *, angles, positions, length,
                    page_table):
     """One layer of prefill: x [B, S, d]; causal attention over the
     in-flight K/V (a fresh prompt attends only itself), every valid
-    position's K/V scattered into its page; padded positions write the
-    null page and are segment-masked out of the valid rows."""
+    position's K/V written into its page (:func:`_write_kv`); padded
+    positions write nothing and are segment-masked out of the valid
+    rows."""
     n0, n1 = _norm_keys(cfg)
-    page_size = kp.shape[2]
-    maxp = page_table.shape[1]
-    B = x.shape[0]
     h = make_norm(cfg).apply({"params": blk[n0]}, x)
     q, k, v = _qkv(cfg, blk["attn"], h)
     if angles is not None:
         q = apply_rope(q, angles)
         k = apply_rope(k, angles)
     valid = positions < length[:, None]  # [B, S]
-    pidx = jnp.minimum(positions // page_size, maxp - 1)
-    page = jnp.where(valid, jnp.take_along_axis(page_table, pidx, axis=1), 0)
-    slot = jnp.where(valid, positions % page_size, 0)
-    kp = kp.at[page, :, slot].set(k)
-    vp = vp.at[page, :, slot].set(v)
+    kp, vp = _write_kv(kp, vp, base, page_table, k, v, positions[:, 0],
+                       length)
     attn = default_attention(q, k, v, causal=True,
                              segment_ids=valid.astype(jnp.int32))
     x = x + _attn_out(cfg, blk["attn"], attn)
@@ -343,30 +375,25 @@ def _prefill_block(cfg, blk, x, kp, vp, *, angles, positions, length,
     return x, kp, vp
 
 
-def _chunk_block(cfg, blk, x, kp, vp, *, angles, positions, end,
+def _chunk_block(cfg, blk, x, kp, vp, base, *, angles, positions, end,
                  page_table):
     """One layer of CHUNKED prefill: x [B, S, d] holds prompt positions
-    ``[start, start+S)``; valid positions' K/V scatter into their pages
-    (the caller already copy-on-wrote any shared first page), and
+    ``[start, start+S)``; valid positions' K/V are written into their
+    pages (the caller already copy-on-wrote any shared first page), and
     attention runs through the page table over the WHOLE written
     context — cached prefix pages, earlier chunks, and this chunk's
     causal self-context — which is what lets a suffix prefill skip the
     prefix's FLOPs entirely."""
     n0, n1 = _norm_keys(cfg)
-    page_size = kp.shape[2]
-    maxp = page_table.shape[1]
     h = make_norm(cfg).apply({"params": blk[n0]}, x)
     q, k, v = _qkv(cfg, blk["attn"], h)
     if angles is not None:
         q = apply_rope(q, angles)
         k = apply_rope(k, angles)
-    valid = positions < end[:, None]  # [B, S] absolute-position validity
-    pidx = jnp.minimum(positions // page_size, maxp - 1)
-    page = jnp.where(valid, jnp.take_along_axis(page_table, pidx, axis=1), 0)
-    slot = jnp.where(valid, positions % page_size, 0)
-    kp = kp.at[page, :, slot].set(k)
-    vp = vp.at[page, :, slot].set(v)
-    attn = paged_prefill_attention(q, kp, vp, positions, end, page_table)
+    kp, vp = _write_kv(kp, vp, base, page_table, k, v, positions[:, 0],
+                       end)
+    attn = paged_prefill_attention(q, kp, vp, positions, end,
+                                   page_table + base)
     x = x + _attn_out(cfg, blk["attn"], attn)
     h2 = make_norm(cfg).apply({"params": blk[n1]}, x)
     x = x + _mlp(cfg, blk, h2)
@@ -391,18 +418,29 @@ def _program_name(name: str) -> Callable:
 
 
 def _scan_blocks(decomp, p, x, k_pages, v_pages, block_step):
-    """Thread x through the scan-stacked layers; the per-layer pool
-    slices ride the scan as mapped inputs/outputs, so the whole stack's
-    cache update is one functional pass."""
+    """Thread x and both pools through the scan-stacked layers.  The
+    pools are the scan's CARRY, viewed flat as [L*P, KV, page, D] (the
+    two major dims merged: no data moves), and layer ``l`` addresses its
+    page ``p`` at row ``l*P + p``: ``block_step(blk, x, kp, vp, base)``
+    gets ``base = l*P``.  A carry is updated in place, so a layer writes
+    its token rows and reads the pages it attends and nothing else; as
+    mapped inputs and outputs of the scan the pools cost eight copies of
+    a [P, KV, page, D] slice a layer (PERF.md, PR 27)."""
     blocks = decomp.block_params(p)
+    pool_shape = k_pages.shape
+    n_layers, n_pages = pool_shape[:2]
+    flat = (n_layers * n_pages,) + pool_shape[2:]
 
     def body(carry, inp):
-        blk, kp, vp = inp
-        y, kp, vp = block_step(blk, carry, kp, vp)
-        return y, (kp, vp)
+        x, kp, vp = carry
+        blk, base = inp
+        return block_step(blk, x, kp, vp, base), None
 
-    x, (k_pages, v_pages) = jax.lax.scan(body, x, (blocks, k_pages, v_pages))
-    return x, k_pages, v_pages
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, k_pages.reshape(flat), v_pages.reshape(flat)),
+        (blocks, jnp.arange(n_layers, dtype=jnp.int32) * n_pages),
+    )
+    return x, k_pages.reshape(pool_shape), v_pages.reshape(pool_shape)
 
 
 def build_decode_fn(family: str, cfg: TransformerConfig,
@@ -427,10 +465,11 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
         # null page is written by their scatters but never READ.
         lengths = jnp.where(positions > 0, positions + 1, 0)
 
-        def step(blk, x, kp, vp):
+        def step(blk, x, kp, vp, base):
             return _decode_block(
-                cfg, blk, x, kp, vp, angles=angles, positions=positions,
-                lengths=lengths, page_table=page_table, attend=attend,
+                cfg, blk, x, kp, vp, base, angles=angles,
+                positions=positions, lengths=lengths, page_table=page_table,
+                attend=attend,
             )
 
         x, k_pages, v_pages = _scan_blocks(
@@ -458,10 +497,10 @@ def build_prefill_fn(family: str, cfg: TransformerConfig,
         x = decomp.embed(p, tokens, positions)
         angles = decomp.angles_at(positions)
 
-        def step(blk, x, kp, vp):
+        def step(blk, x, kp, vp, base):
             return _prefill_block(
-                cfg, blk, x, kp, vp, angles=angles, positions=positions,
-                length=length, page_table=page_table,
+                cfg, blk, x, kp, vp, base, angles=angles,
+                positions=positions, length=length, page_table=page_table,
             )
 
         x, k_pages, v_pages = _scan_blocks(
@@ -497,10 +536,10 @@ def build_chunk_prefill_fn(family: str, cfg: TransformerConfig,
         x = decomp.embed(p, tokens, positions)
         angles = decomp.angles_at(positions)
 
-        def step(blk, x, kp, vp):
+        def step(blk, x, kp, vp, base):
             return _chunk_block(
-                cfg, blk, x, kp, vp, angles=angles, positions=positions,
-                end=end, page_table=page_table,
+                cfg, blk, x, kp, vp, base, angles=angles,
+                positions=positions, end=end, page_table=page_table,
             )
 
         x, k_pages, v_pages = _scan_blocks(
@@ -523,8 +562,8 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
     v_pages)``.  Lane ``b`` feeds its last emitted token plus its draft,
     left-aligned in ``tokens[b]``, occupying absolute positions
     ``[start[b], end[b])`` (``end - start`` = 1 + draft length, ≤ k+1);
-    padded positions past ``end`` write the null page and are masked out
-    of attention, and idle lanes carry ``start == end == 0`` with a null
+    padded positions past ``end`` write nothing and are masked out of
+    attention, and idle lanes carry ``start == end == 0`` with a null
     table row.  Row ``i`` of the logits scores the token AFTER position
     ``start + i``, so greedy accept walks the rows left to right: accept
     while the draft token equals the row's argmax, then emit one
@@ -543,10 +582,10 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
         x = decomp.embed(p, tokens, positions)
         angles = decomp.angles_at(positions)
 
-        def step(blk, x, kp, vp):
+        def step(blk, x, kp, vp, base):
             return _chunk_block(
-                cfg, blk, x, kp, vp, angles=angles, positions=positions,
-                end=end, page_table=page_table,
+                cfg, blk, x, kp, vp, base, angles=angles,
+                positions=positions, end=end, page_table=page_table,
             )
 
         x, k_pages, v_pages = _scan_blocks(
@@ -563,7 +602,9 @@ def build_cow_fn() -> Callable:
     ``(k_pages, v_pages, src [1], dst [1]) -> (k_pages, v_pages)`` —
     clone page ``src`` into ``dst`` across every layer, K and V, so a
     grower about to write into a shared page writes into its private
-    copy instead.  Pure pool-to-pool; no params, one donated update."""
+    copy instead.  Pure pool-to-pool, no params.  The pools are NOT
+    donated (no serving program donates them: ROADMAP S1), so a call
+    copies both pools to write one page of each."""
 
     @_program_name("tdx_serve_cow")
     def cow_fn(k_pages, v_pages, src, dst):
@@ -617,9 +658,10 @@ def _fp(kind: str, family: str, cfg: TransformerConfig,
         scfg.max_batch, scfg.page_size, scfg.n_pages,
         scfg.max_pages_per_seq, scfg.prefill_buckets,
     )
-    # v2: the [L, P, KV, page, D] pool layout — same key material, other
-    # compiled bytes, so artifacts published under v1 must not be served.
-    h = hashlib.sha1(b"tdx-serve-program-fp-v2")
+    # v3: the pools are the layer scan's carry, addressed flat at
+    # layer*P + page — same key material, other compiled bytes, so
+    # artifacts published under v2 must not be served.
+    h = hashlib.sha1(b"tdx-serve-program-fp-v3")
     h.update(repr((kind, family, cfg, shape, extra)).encode())
     return h.hexdigest()
 
