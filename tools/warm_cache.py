@@ -396,11 +396,19 @@ def main(argv=None) -> None:
             buckets = tuple(
                 int(b) for b in args.prefill_buckets.split(",") if b.strip()
             )
+        from torchdistx_tpu.models import PRESETS
+
+        # A stack with recurrent layers has no verify-<k> and no cow
+        # program to warm: ServeConfig.resolve refuses speculation and
+        # the prefix cache for it, and says why.
+        hybrid = getattr(PRESETS.get(args.model), "mamba", None) is not None
         serve_cfg = ServeConfig(
             max_batch=args.serve_batch, page_size=args.page_size,
             n_pages=args.pages,
             max_pages_per_seq=args.max_pages_per_seq or None,
             prefill_buckets=buckets,
+            **({"spec_decode": False, "prefix_cache": False}
+               if hybrid else {}),
         )
         summary = warm_decode(
             args.model, args.cache_dir, registry_dir=args.registry_dir,

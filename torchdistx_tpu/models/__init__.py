@@ -9,18 +9,21 @@ from .configs import (
     T5_11B,
     TINY,
     TINY_GPT2,
+    TINY_JAMBA,
     TINY_MOE,
     TINY_T5,
     TINY_VIT,
     VIT_B16,
     VIT_L16,
     EncDecConfig,
+    MambaConfig,
     MoEConfig,
     TransformerConfig,
     VisionConfig,
 )
 from .decomposition import DecodeDecomposition, PipelineDecomposition
 from .gpt2 import GPT2Model, make_gpt2
+from .jamba import JambaModel, make_jamba
 from .llama import LlamaModel, make_llama
 from .mixtral import make_mixtral
 from .plans import decoder_lm_plan, t5_plan, vit_plan
@@ -32,6 +35,7 @@ __all__ = [
     "EncDecConfig",
     "VisionConfig",
     "MoEConfig",
+    "MambaConfig",
     "PRESETS",
     "GPT2_125M",
     "LLAMA3_8B",
@@ -40,6 +44,7 @@ __all__ = [
     "T5_11B",
     "TINY",
     "TINY_GPT2",
+    "TINY_JAMBA",
     "TINY_MOE",
     "TINY_T5",
     "TINY_VIT",
@@ -47,11 +52,13 @@ __all__ = [
     "VIT_L16",
     "DecodeDecomposition",
     "GPT2Model",
+    "JambaModel",
     "LlamaModel",
     "PipelineDecomposition",
     "T5Model",
     "ViTModel",
     "make_gpt2",
+    "make_jamba",
     "make_llama",
     "make_mixtral",
     "make_t5",

@@ -22,6 +22,20 @@ class MoEConfig:
 
 
 @dataclass(frozen=True)
+class MambaConfig:
+    """A hybrid stack's state-space side (Jamba): Mamba-1 mixers in
+    every layer but one a period.  Layer ``i`` is an attention layer iff
+    ``i % attn_period == attn_offset``; every layer keeps the dense MLP."""
+
+    d_state: int = 16
+    d_conv: int = 4
+    expand: int = 2      # d_inner = expand * d_model
+    dt_rank: int = 160
+    attn_period: int = 14
+    attn_offset: int = 7
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -36,7 +50,7 @@ class TransformerConfig:
     use_bias: bool = False
     activation: str = "silu"  # "silu" (SwiGLU) | "gelu" (plain MLP)
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
-    positions: str = "rope"  # "rope" | "learned" | "relative"
+    positions: str = "rope"  # "rope" | "learned" | "relative" | "none"
     tie_embeddings: bool = False
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
@@ -44,6 +58,7 @@ class TransformerConfig:
     relative_pos_max_distance: int = 128
 
     moe: Optional[MoEConfig] = None
+    mamba: Optional[MambaConfig] = None  # hybrid stack (models/jamba.py)
 
     dtype: jnp.dtype = jnp.bfloat16  # activation/compute dtype
     param_dtype: jnp.dtype = jnp.float32
@@ -211,6 +226,16 @@ TINY_GPT2 = GPT2_125M.replace(
 
 TINY_MOE = TINY.replace(moe=MoEConfig(n_experts=4, top_k=2))
 
+# One whole period of Jamba's pattern: 13 Mamba-1 layers round one MQA
+# attention layer, no positions, tied head.
+TINY_JAMBA = TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=14, n_heads=4, n_kv_heads=1,
+    d_ff=128, max_seq_len=128, positions="none", tie_embeddings=True,
+    norm_eps=1e-6, dtype=jnp.float32,
+    mamba=MambaConfig(d_state=16, d_conv=4, expand=2, dt_rank=8,
+                      attn_period=14, attn_offset=7),
+)
+
 TINY_T5 = EncDecConfig(
     encoder=_T5_STACK.replace(
         vocab_size=256, d_model=64, n_layers=2, n_heads=4, head_dim=16, d_ff=128,
@@ -244,6 +269,7 @@ PRESETS = {
     "tiny": TINY,
     "tiny-gpt2": TINY_GPT2,
     "tiny-moe": TINY_MOE,
+    "tiny-jamba": TINY_JAMBA,
     "tiny-t5": TINY_T5,
     "tiny-vit": TINY_VIT,
 }

@@ -36,18 +36,44 @@ def _block_rules(fsdp: Optional[str], tp: Optional[str]):
     ]
 
 
+def _jamba_rules(fsdp: Optional[str], tp: Optional[str]):
+    """Rules for the hybrid stack's three parameter stacks
+    (models/jamba.py; flat names ``mamba_*`` / ``attn_*`` / ``ffn_*``,
+    leading layer axis unsharded).  The mixer is split over ``tp`` along
+    its channels (``d_inner``), as the recurrent state is
+    (``serve.kv_cache.state_sharding``): ``in_proj``'s columns and
+    everything channel-wise on that axis, ``out_proj``'s rows; the
+    small ``x_proj`` / ``dt_proj`` along the channels too.  Attention and
+    the MLP follow :func:`_block_rules` (a single KV head does not divide
+    and falls back to replication)."""
+    return [
+        (r".*mamba_in_proj", P(None, fsdp, tp)),        # [L, d, 2*Di]
+        (r".*mamba_out_proj", P(None, tp, fsdp)),       # [L, Di, d]
+        (r".*mamba_x_proj", P(None, tp, None)),         # [L, Di, R+2N]
+        (r".*mamba_dt_proj", P(None, None, tp)),        # [L, R, Di]
+        (r".*mamba_(conv_w|A_log)", P(None, None, tp)),  # [L, K|N, Di]
+        (r".*mamba_(conv_b|dt_bias|D)", P(None, tp)),   # [L, Di]
+        (r".*attn_w[qkv]", P(None, fsdp, tp, None)),    # [L, d, H, hd]
+        (r".*attn_wo", P(None, tp, None, fsdp)),        # [L, H, hd, d]
+        (r".*ffn_w_(gate|up)", P(None, fsdp, tp)),      # [L, d, ff]
+        (r".*ffn_w_down", P(None, tp, fsdp)),           # [L, ff, d]
+        (r".*params\.embedding", P(tp, fsdp)),          # [V, d], tied head
+    ]
+
+
 def decoder_lm_plan(
     *,
     fsdp: Optional[str] = "fsdp",
     tp: Optional[str] = "tp",
     ep: Optional[str] = "ep",
 ) -> ShardingPlan:
-    """Plan for LlamaModel / GPT2Model / Mixtral param trees.
+    """Plan for LlamaModel / GPT2Model / Mixtral / JambaModel param trees.
 
     Pass ``tp=None`` (etc.) to drop an axis entirely when building a plan
     for a mesh that intentionally lacks it — no absent-axis warnings."""
     return ShardingPlan(
         _block_rules(fsdp, tp)
+        + _jamba_rules(fsdp, tp)
         + [
             # MoE experts [L, E, d, ff] / [L, E, ff, d]
             (r".*moe\.w_(gate|up)", P(None, ep, fsdp, tp)),

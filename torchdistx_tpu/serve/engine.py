@@ -98,7 +98,8 @@ from .. import chaos, observe
 from ..observe import reqledger
 from ..models import PRESETS, TransformerConfig
 from ..utils.logging import get_logger
-from .kv_cache import OutOfPages, PagedKVCache, init_pools, pool_sharding
+from .kv_cache import (OutOfPages, PagedKVCache, init_pools, init_state,
+                       pool_sharding, state_sharding)
 from .prefix import NgramDrafter, PrefixCache
 from .programs import (
     ResolvedServeConfig,
@@ -193,6 +194,12 @@ class ServeEngine:
         self.k_pages, self.v_pages = init_pools(
             self.scfg.kv_config(cfg), cfg.dtype,
             pool_sharding(mesh, cfg.kv_heads))
+        # The recurrent layer group's arrays (a hybrid stack: ssm, conv;
+        # else none), threaded through every model program behind the
+        # pools.  A lane's slot is the lane's index.
+        st = self.kv.cfg.state
+        self.state: tuple = () if st is None else init_state(
+            st, cfg.dtype, state_sharding(mesh, st.d_inner))
         # Chunk-boundary chaos faults (``serve@N=raise:chunk``) are
         # deferred here by step() and fired BETWEEN prefill chunks —
         # the mid-chunked-prefill fault the failure matrix pins.
@@ -507,6 +514,7 @@ class ServeEngine:
                 f"drain first"
             )
         self.k_pages = self.v_pages = None
+        self.state = ()
         self.kv = PagedKVCache(self.scfg.kv_config(self.cfg))
         self.prefix = PrefixCache(self.kv)
         self._gauges()
@@ -606,23 +614,34 @@ class ServeEngine:
     def _run_program(self, name: str, *args, lanes: int, attended: int,
                      fetch: bool = True) -> Optional[np.ndarray]:
         """Call the compiled model program ``name`` on the params, the
-        pools and ``args`` under ``serve.program`` and bring its logits to
-        the host under ``serve.tick.d2h`` (``fetch`` False: a chunk that
-        is not a prompt's last, whose logits nobody reads).  While
-        telemetry is on ``serve.program`` ends when the logits are ready,
-        so the two spans split device time from the copy; off, nothing
-        waits before the fetch.  ``attended`` is the context the program's
-        ``lanes`` attend over, counted before anything retires."""
+        pools, the recurrent state (a hybrid stack) and ``args`` under
+        ``serve.program`` (``state_lanes``: the lanes whose recurrent
+        state the call advances) and bring its logits to the host under
+        ``serve.tick.d2h`` (``fetch`` False: a chunk that is not a
+        prompt's last, whose logits nobody reads).  While telemetry is on
+        ``serve.program`` ends when the logits are ready, so the two spans
+        split device time from the copy; off, nothing waits before the
+        fetch.  ``attended`` is the context the program's ``lanes`` attend
+        over, counted before anything retires."""
         with observe.span("serve.program", category="serve", program=name,
-                          lanes=lanes, attended_tokens=attended) as sp:
-            logits, self.k_pages, self.v_pages = self._program(name)(
-                self.params, self.k_pages, self.v_pages, *args)
+                          lanes=lanes, attended_tokens=attended,
+                          state_lanes=lanes if self.state else 0) as sp:
+            logits, self.k_pages, self.v_pages, *state = self._program(name)(
+                self.params, self.k_pages, self.v_pages, *self.state, *args)
+            self.state = tuple(state)
             sp.block_on(logits)
         if not fetch:
             return None
         with observe.span("serve.tick.d2h", category="serve", program=name,
                           bytes=logits.nbytes):
             return np.asarray(logits)
+
+    def _slot_arg(self, lane: _Lane) -> tuple:
+        """The last operand of a one-sequence program of a hybrid stack:
+        the lane's recurrent-state slot (nothing for the other families)."""
+        if self.kv.cfg.state is None:
+            return ()
+        return (jnp.asarray([self.kv.state_slot(lane.seq_id)], jnp.int32),)
 
     def _free_slot(self) -> Optional[int]:
         for s in range(self.scfg.max_batch):
@@ -682,7 +701,7 @@ class ServeEngine:
         if shared:
             self.kv.alloc_shared(sid, shared, L)
         else:
-            self.kv.alloc(sid, L)
+            self.kv.alloc(sid, L, slot=slot)
         # Reused tokens never re-prefill — but the LAST prompt position
         # must run (its logits are the first generated token), so a
         # fully-cached prompt recomputes exactly one token (and that
@@ -692,6 +711,12 @@ class ServeEngine:
         if start > 0:
             observe.counter("tdx.serve.prefix_hits").inc()
             observe.counter("tdx.serve.prefix_tokens_reused").inc(start)
+        if getattr(req, "_prefilled", False):
+            # Preempted (or faulted) and admitted again: neither its pages
+            # nor its recurrent state were kept, so the prompt is prefilled
+            # a second time.
+            observe.counter("tdx.serve.recomputed_tokens").inc(L - start)
+        req._prefilled = True
         reqledger.on_admit(req.rid, replica=self.slo.name,
                            prefix_tokens=start)
         lane = _Lane(req=req, seq_id=sid, slot=slot, length=start,
@@ -723,7 +748,7 @@ class ServeEngine:
                         )
                         args = (jnp.asarray(toks),
                                 jnp.asarray([L], jnp.int32),
-                                jnp.asarray(row))
+                                jnp.asarray(row), *self._slot_arg(lane))
                     logits = self._run_program(name, *args, lanes=1,
                                                attended=L)
                     lane.length = L
@@ -780,7 +805,8 @@ class ServeEngine:
                 np.int32,
             )
             args = (jnp.asarray(toks), jnp.asarray([s], jnp.int32),
-                    jnp.asarray([s + n], jnp.int32), jnp.asarray(row))
+                    jnp.asarray([s + n], jnp.int32), jnp.asarray(row),
+                    *self._slot_arg(lane))
         logits = self._run_program(name, *args, lanes=1, attended=s + n,
                                    fetch=s + n >= L)
         lane.length = s + n
@@ -1357,7 +1383,9 @@ def spin_up_replica(
                 on_token=on_token, on_complete=on_complete,
                 on_cancel=on_cancel, slo_name=slo_name,
             )
-            psp.block_on((engine.k_pages, engine.v_pages))
+            psp.block_on((engine.k_pages, engine.v_pages, engine.state))
+            psp.set(pool_bytes=engine.k_pages.nbytes + engine.v_pages.nbytes,
+                    state_bytes=sum(a.nbytes for a in engine.state))
         # The spec list above already paid the model's deferred-init
         # trace; hand it to the engine so warmup/lazy compiles reuse it.
         engine._spec_cache = {s.name: s for s in specs if s.name != "init"}

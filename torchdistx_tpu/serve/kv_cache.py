@@ -40,12 +40,30 @@ is the copy-on-write step — a sequence about to WRITE into a page it
 shares swaps in a fresh page first (the engine device-copies the
 contents), so no reader of a shared page ever observes a mutation.
 
+**Two kinds of state** (the start of ROADMAP D7).  A cache is described
+by its layer groups: the attention layers' pages above, and, for a
+stack with recurrent layers (``models/jamba.py``), a
+:class:`StateCacheConfig`: one **slot** a batch lane holding that lane's
+recurrent state in every Mamba layer, of a fixed size whatever the
+context.  A slot is not paged and not shared: it is bound to a sequence
+when the sequence is allocated (``alloc(..., slot=lane)``) and dropped
+with it (:meth:`PagedKVCache.free`) — there is nothing to return to a
+free list, and nothing of it survives a preemption, so a preempted
+request prefills again from its tokens.  The device arrays
+(:func:`init_state`) are the engine's, like the pools; the FIRST
+program that writes a slot for a new sequence starts from zero whatever
+the slot held (``serve/programs.py``), which is what "zeroed on
+admission" means here, and each binding counts one
+``tdx.serve.state_resets``.
+
 Telemetry (docs/observability.md): ``tdx.serve.kv_pages_in_use``,
 ``tdx.serve.kv_occupancy`` (used token slots / allocated slots in live
 pages — the internal-fragmentation complement),
 ``tdx.serve.kv_pool_pages``, ``tdx.serve.kv_pages_free``, and
 ``tdx.serve.kv_pages_shared`` (refcount > 1 — the live copy-on-write
-exposure) gauges, refreshed on every mutation.
+exposure) gauges, refreshed on every mutation; with a state group also
+``tdx.serve.state_slots_in_use`` and ``tdx.serve.state_slots_peak``
+(gauges) and ``tdx.serve.state_resets`` (counter, always on).
 """
 
 from __future__ import annotations
@@ -57,8 +75,8 @@ import numpy as np
 
 from .. import observe
 
-__all__ = ["KVCacheConfig", "OutOfPages", "PagedKVCache", "init_pools",
-           "pool_sharding"]
+__all__ = ["KVCacheConfig", "OutOfPages", "PagedKVCache", "StateCacheConfig",
+           "init_pools", "init_state", "pool_sharding", "state_sharding"]
 
 
 class OutOfPages(RuntimeError):
@@ -68,14 +86,40 @@ class OutOfPages(RuntimeError):
 
 
 @dataclass(frozen=True)
+class StateCacheConfig:
+    """The recurrent layer group of a cache: ``lanes`` slots, each the
+    state of one sequence in all ``n_layers`` Mamba layers.  The SSM
+    state is float32 ``[L, lanes, d_state, d_inner]`` — channels minor:
+    a minor dim of ``d_state`` = 16 would pad to the chip's 128 lanes,
+    eight times the bytes — and the conv tail (the last ``d_conv - 1``
+    inputs of the depthwise conv) ``[L, d_conv-1, lanes, d_inner]`` in
+    the activation dtype, lanes second-minor for the same reason."""
+
+    n_layers: int
+    d_inner: int
+    d_state: int
+    d_conv: int
+    lanes: int
+
+    def ssm_shape(self) -> Tuple[int, int, int, int]:
+        return (self.n_layers, self.lanes, self.d_state, self.d_inner)
+
+    def conv_shape(self) -> Tuple[int, int, int, int]:
+        return (self.n_layers, self.d_conv - 1, self.lanes, self.d_inner)
+
+
+@dataclass(frozen=True)
 class KVCacheConfig:
-    """Shape of the device pool (one K and one V pool, all layers)."""
+    """Shape of the cache, by layer group: the attention layers' pool
+    (one K and one V pool over ``n_layers`` layers) and, where the stack
+    has recurrent layers, their ``state`` group."""
 
     n_layers: int
     kv_heads: int
     head_dim: int
     page_size: int = 16
     n_pages: int = 64  # includes the reserved null page 0
+    state: Optional[StateCacheConfig] = None
 
     @property
     def usable_pages(self) -> int:
@@ -130,6 +174,11 @@ class PagedKVCache:
         # the page, plus one per prefix-cache node holding it.  A page
         # returns to the free list only at refcount zero.
         self._ref: Dict[int, int] = {}
+        # The recurrent group's slots: sequence -> the lane whose slot
+        # holds its state (empty for a cache of pages only).
+        self._slot_of: Dict[int, int] = {}
+        self.state_slots_peak = 0
+        self._resets = observe.counter("tdx.serve.state_resets")
         self._update_gauges()
 
     # -- queries ------------------------------------------------------------
@@ -147,6 +196,14 @@ class PagedKVCache:
         """Pages with more than one reference (prefix-shared right
         now) — the live copy-on-write exposure."""
         return sum(1 for v in self._ref.values() if v > 1)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return len(self._slot_of)
+
+    def state_slot(self, seq_id: int) -> int:
+        """The slot that holds the sequence's recurrent state."""
+        return self._slot_of[seq_id]
 
     def length(self, seq_id: int) -> int:
         return self._seqs[seq_id].length
@@ -181,8 +238,28 @@ class PagedKVCache:
 
     # -- mutations ----------------------------------------------------------
 
-    def alloc(self, seq_id: int, n_tokens: int) -> List[int]:
-        """Allocate pages for a new sequence holding ``n_tokens``;
+    def _bind_slot(self, seq_id: int, slot: Optional[int]) -> None:
+        """Give the new sequence its recurrent-state slot (a cache with
+        a state group only; else ``slot`` is not looked at).  The slot's
+        old contents are dead from here on: the sequence's first program
+        call starts from zero."""
+        if self.cfg.state is None:
+            return
+        if slot is None or not (0 <= slot < self.cfg.state.lanes):
+            raise ValueError(
+                f"sequence {seq_id} needs a state slot in "
+                f"[0, {self.cfg.state.lanes}), got {slot!r}")
+        if slot in self._slot_of.values():
+            raise ValueError(f"state slot {slot} is held by a live sequence")
+        self._slot_of[seq_id] = slot
+        self.state_slots_peak = max(self.state_slots_peak,
+                                    len(self._slot_of))
+        self._resets.inc()
+
+    def alloc(self, seq_id: int, n_tokens: int,
+              slot: Optional[int] = None) -> List[int]:
+        """Allocate pages for a new sequence holding ``n_tokens`` (and,
+        with a state group, bind recurrent-state slot ``slot`` to it);
         returns its page ids.  Raises :class:`OutOfPages` (allocating
         nothing) when the free list cannot cover it."""
         if seq_id in self._seqs:
@@ -193,6 +270,7 @@ class PagedKVCache:
                 f"need {need} pages for {n_tokens} tokens, "
                 f"{len(self._free)} free"
             )
+        self._bind_slot(seq_id, slot)
         pages = [self._free.pop() for _ in range(need)]
         for p in pages:
             self._ref[p] = 1
@@ -210,6 +288,10 @@ class PagedKVCache:
         cannot cover the suffix."""
         if seq_id in self._seqs:
             raise ValueError(f"sequence {seq_id} already allocated")
+        if self.cfg.state is not None:
+            raise ValueError(
+                "a cache with a recurrent state group shares no pages: a "
+                "prefix's pages hold no state to resume from")
         shared = list(shared_pages)
         need = self.cfg.pages_for(n_tokens) - len(shared)
         if need < 0:
@@ -347,6 +429,7 @@ class PagedKVCache:
         seq = self._seqs.pop(seq_id, None)
         if seq is None:
             return 0
+        self._slot_of.pop(seq_id, None)  # the state is dropped, not saved
         freed = []
         for p in seq.pages:
             if self._ref[p] == 1:
@@ -364,6 +447,7 @@ class PagedKVCache:
         :meth:`free` calls."""
         self._seqs.clear()
         self._ref.clear()
+        self._slot_of.clear()
         self._free = list(range(self.cfg.n_pages - 1, 0, -1))
         self._update_gauges()
 
@@ -406,6 +490,11 @@ class PagedKVCache:
         observe.gauge("tdx.serve.kv_occupancy").set(round(self.occupancy(), 4))
         observe.gauge("tdx.serve.kv_pages_free").set(len(self._free))
         observe.gauge("tdx.serve.kv_pages_shared").set(self.shared_pages)
+        if self.cfg.state is not None:
+            observe.gauge("tdx.serve.state_slots_in_use").set(
+                len(self._slot_of))
+            observe.gauge("tdx.serve.state_slots_peak").set(
+                self.state_slots_peak)
 
 
 def pool_sharding(mesh, kv_heads: int, tp_axis: str = "tp"):
@@ -422,6 +511,33 @@ def pool_sharding(mesh, kv_heads: int, tp_axis: str = "tp"):
     if tp > 1 and kv_heads % tp == 0:
         return NamedSharding(mesh, P(None, None, tp_axis))
     return NamedSharding(mesh, P())
+
+
+def state_sharding(mesh, d_inner: int, tp_axis: str = "tp"):
+    """Where the recurrent state lives on a replica mesh: channels split
+    over the tensor-parallel axis when it divides them (the mixer's
+    ``in_proj`` columns are split the same way, ``models/plans.py``),
+    replicated over every other axis.  None without a mesh.  Both arrays
+    (:func:`init_state`) have the channels as their last dim."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    tp = mesh.shape.get(tp_axis, 1)
+    if tp > 1 and d_inner % tp == 0:
+        return NamedSharding(mesh, P(None, None, None, tp_axis))
+    return NamedSharding(mesh, P())
+
+
+def init_state(cfg: StateCacheConfig, dtype,
+               sharding=None) -> Tuple["jax.Array", "jax.Array"]:
+    """The zeroed recurrent state ``(ssm, conv)``: float32
+    ``[L, lanes, d_state, d_inner]`` and ``dtype``
+    ``[L, d_conv-1, lanes, d_inner]``."""
+    import jax.numpy as jnp
+
+    return (jnp.zeros(cfg.ssm_shape(), jnp.float32, device=sharding),
+            jnp.zeros(cfg.conv_shape(), dtype, device=sharding))
 
 
 def init_pools(cfg: KVCacheConfig, dtype,

@@ -67,11 +67,13 @@ from jax.sharding import PartitionSpec as P
 
 from .. import abstract, chaos, observe
 from .. import config as tdx_config
-from ..models import TransformerConfig, make_gpt2, make_llama
+from ..models import TransformerConfig, make_gpt2, make_jamba, make_llama
+from ..models import jamba
 from ..models.layers import MLP, apply_rope, default_attention, make_norm
 from ..ops import paged_attention, paged_prefill_attention
 from ..utils.logging import get_logger
-from .kv_cache import KVCacheConfig, pool_sharding
+from .kv_cache import (KVCacheConfig, StateCacheConfig, pool_sharding,
+                       state_sharding)
 
 __all__ = [
     "ServeConfig",
@@ -156,6 +158,16 @@ class ServeConfig:
         if spec_k is None:
             spec_k = tdx_config.get().spec_k
         spec_k = max(1, min(spec_k, spec_buckets[-1]))
+        if cfg.mamba is not None and (spec_on or self.prefix_cache):
+            on = [n for n, v in (("spec_decode", spec_on),
+                                 ("prefix_cache", self.prefix_cache)) if v]
+            raise ValueError(
+                f"{' and '.join(on)} cannot be on for a stack with recurrent "
+                f"(Mamba) layers: a recurrent state is one value a lane, not "
+                f"a row a token, so a rejected draft cannot be rolled back "
+                f"out of it (verify-<k>) and a shared prefix's pages hold no "
+                f"state to resume from (prefix cache); pass "
+                f"ServeConfig(spec_decode=False, prefix_cache=False)")
         return ResolvedServeConfig(
             max_batch=self.max_batch, page_size=page, n_pages=self.n_pages,
             max_pages_per_seq=maxp, prefill_buckets=buckets,
@@ -185,10 +197,21 @@ class ResolvedServeConfig:
     spec_k: int = 4             # max draft length (host-side knob)
 
     def kv_config(self, cfg: TransformerConfig) -> KVCacheConfig:
+        """The cache's layer groups: pages for the attention layers (all
+        layers of a gpt2 / llama stack) and, for a hybrid stack, one
+        state slot a lane for its Mamba layers."""
+        if cfg.mamba is None:
+            n_attn, state = cfg.n_layers, None
+        else:
+            n_attn = jamba.n_attn_layers(cfg)
+            state = StateCacheConfig(
+                n_layers=jamba.n_mamba_layers(cfg),
+                d_inner=jamba.d_inner(cfg), d_state=cfg.mamba.d_state,
+                d_conv=cfg.mamba.d_conv, lanes=self.max_batch)
         return KVCacheConfig(
-            n_layers=cfg.n_layers, kv_heads=cfg.kv_heads,
+            n_layers=n_attn, kv_heads=cfg.kv_heads,
             head_dim=cfg.head_size, page_size=self.page_size,
-            n_pages=self.n_pages,
+            n_pages=self.n_pages, state=state,
         )
 
     def bucket_for(self, n_tokens: int) -> int:
@@ -211,23 +234,38 @@ class ResolvedServeConfig:
         )
 
 
+FAMILIES = ("gpt2", "llama", "jamba")
+
+
 def model_family(name: str) -> str:
-    """The decode family of a zoo preset name: gpt2 presets by name, any
-    other dense decoder serves through the llama path."""
-    return "gpt2" if "gpt2" in name else "llama"
+    """The decode family of a zoo preset name: gpt2 and jamba presets by
+    name, any other dense decoder serves through the llama path."""
+    for family in ("gpt2", "jamba"):
+        if family in name:
+            return family
+    return "llama"
 
 
 def make_model(family: str, cfg: TransformerConfig):
     if cfg.moe is not None:
         raise NotImplementedError(
-            "the serving runtime covers the dense decoder families "
-            "(gpt2, llama); MoE decode is future work"
+            f"the serving runtime covers the dense decoder families "
+            f"({', '.join(FAMILIES)}); MoE decode is future work"
         )
+    if (family == "jamba") != (cfg.mamba is not None):
+        raise ValueError(
+            f"decode family {family!r} with cfg.mamba="
+            f"{'set' if cfg.mamba is not None else 'None'}: the jamba "
+            f"family, and no other, takes a config with Mamba layers")
     if family == "gpt2":
         return make_gpt2(cfg)
     if family == "llama":
         return make_llama(cfg)
-    raise ValueError(f"unknown decode family {family!r} (gpt2 | llama)")
+    if family == "jamba":
+        return make_jamba(cfg)
+    raise ValueError(
+        f"unknown decode family {family!r}; the families that exist: "
+        f"{' | '.join(FAMILIES)}")
 
 
 # ---------------------------------------------------------------------------
@@ -443,6 +481,187 @@ def _scan_blocks(decomp, p, x, k_pages, v_pages, block_step):
     return x, k_pages.reshape(pool_shape), v_pages.reshape(pool_shape)
 
 
+# ---------------------------------------------------------------------------
+# hybrid stacks (models/jamba.py): recurrent layers beside attention layers
+# ---------------------------------------------------------------------------
+#
+# The programs of a hybrid stack take and return two more arrays than the
+# others, between the pools and the per-call operands: the recurrent
+# layer group's state (serve/kv_cache.py StateCacheConfig),
+#
+#   decode       (params, k_pages, v_pages, ssm, conv, tokens [B],
+#                 positions [B], page_table [B, maxp])
+#   prefill-<b>  (params, k_pages, v_pages, ssm, conv, tokens [1, b],
+#                 length [1], page_table [1, maxp], slot [1])
+#   chunk-<b>    (params, k_pages, v_pages, ssm, conv, tokens [1, b],
+#                 start [1], end [1], page_table [1, maxp], slot [1])
+#   -> (logits, k_pages, v_pages, ssm, conv)
+#
+# and all four ride the layer loops as their carry, as PR 27 made the
+# pools ride: a Mamba layer reads and writes row ``g`` of the state (all
+# lanes in decode, lane ``slot`` in a prefill), an attention layer its
+# pages at ``j * P + page``.  There is no verify-<k> and no cow program:
+# a recurrent state cannot be rolled back and shares nothing.
+
+
+def _hybrid_layers(cfg, mixer_state, attention):
+    """The two layer bodies of :func:`models.jamba.scan_layers` over the
+    carry ``(kp, vp, ssm, conv)``.  ``mixer_state(ssm, conv, g)`` ->
+    ``(s, tail, n_valid, put)``: the state rows the call works on and
+    how to put the new ones back; ``attention(a, h, kp, vp, j)`` ->
+    ``(attn [B, S, H, D], kp, vp)``."""
+    eps = cfg.norm_eps
+
+    def ffn(f, x):
+        return x + jamba.mlp(cfg, f, jamba.rms_norm(x, f["norm1"], eps))
+
+    def mamba_layer(m, f, x, carry, g):
+        kp, vp, ssm, conv = carry
+        # Reading the layer's rows and putting them back is part of the
+        # recurrence it belongs to: the write fuses with the update, and
+        # the fusion takes its root's name.
+        scope = (jamba.DECODE_UPDATE if x.shape[1] == 1 else jamba.CHUNK_SCAN)
+        with jax.named_scope(scope):
+            s, tail, n_valid, put = mixer_state(ssm, conv, g)
+        out, s, tail = jamba.mamba_mixer(
+            cfg, m, jamba.rms_norm(x, f["norm0"], eps), s, tail, n_valid)
+        with jax.named_scope(scope):
+            ssm, conv = put(ssm, conv, g, s, tail)
+        return ffn(f, x + out), (kp, vp, ssm, conv)
+
+    def attn_layer(a, f, x, carry, j):
+        kp, vp, ssm, conv = carry
+        attn, kp, vp = attention(
+            a, jamba.rms_norm(x, f["norm0"], eps), kp, vp, j)
+        return ffn(f, x + jamba.attn_out(cfg, a, attn)), (kp, vp, ssm, conv)
+
+    return mamba_layer, attn_layer
+
+
+def _run_hybrid(cfg, p, x, k_pages, v_pages, ssm, conv, mixer_state,
+                attention):
+    """x through the stack with the pools (viewed flat, as
+    :func:`_scan_blocks` views them) and the states as the loops' carry."""
+    pool_shape = k_pages.shape
+    flat = (pool_shape[0] * pool_shape[1],) + pool_shape[2:]
+    x, (kp, vp, ssm, conv) = jamba.scan_layers(
+        cfg, p, x,
+        (k_pages.reshape(flat), v_pages.reshape(flat), ssm, conv),
+        *_hybrid_layers(cfg, mixer_state, attention))
+    return x, kp.reshape(pool_shape), vp.reshape(pool_shape), ssm, conv
+
+
+def _lane_state(slot, fresh, n_valid):
+    """``mixer_state`` of a one-sequence program: lane ``slot``'s rows of
+    layer ``g``, ``n_valid`` positions of the call real.  ``fresh`` (the
+    call holds the sequence's first position) starts from zero whatever
+    the slot held: a reused lane needs no clearing pass, and a stale
+    state cannot leak."""
+
+    def put(ssm, conv, g, s, tail):
+        return (jax.lax.dynamic_update_slice(ssm, s[None], (g, slot, 0, 0)),
+                jax.lax.dynamic_update_slice(
+                    conv, tail[None], (g, 0, slot, 0)))
+
+    def mixer_state(ssm, conv, g):
+        _, _, N, Di = ssm.shape
+        s = jax.lax.dynamic_slice(ssm, (g, slot, 0, 0), (1, 1, N, Di))[0]
+        tail = jax.lax.dynamic_slice(
+            conv, (g, 0, slot, 0), (1, conv.shape[1], 1, Di))[0]
+        return (jnp.where(fresh, 0.0, s),
+                jnp.where(fresh, jnp.zeros_like(tail), tail), n_valid, put)
+
+    return mixer_state
+
+
+def _build_hybrid_decode_fn(cfg, scfg, mesh) -> Callable:
+    attend = _decode_attention(mesh, cfg.kv_heads)
+    n_pages = scfg.n_pages
+
+    @_program_name("tdx_serve_decode")
+    def decode_fn(params, k_pages, v_pages, ssm, conv, tokens, positions,
+                  page_table):
+        p = jamba.param_tree(params["params"])
+        x = jamba.embed_tokens(cfg, p, tokens[:, None])
+        live = positions > 0  # idle and mid-prefill lanes sit the tick out
+        lengths = jnp.where(live, positions + 1, 0)
+        n_valid = live.astype(jnp.int32)
+
+        def mixer_state(ssm, conv, g):
+            def put(ssm, conv, g, s, tail):
+                return (jax.lax.dynamic_update_index_in_dim(ssm, s, g, 0),
+                        jax.lax.dynamic_update_index_in_dim(conv, tail, g, 0))
+
+            return (jax.lax.dynamic_index_in_dim(ssm, g, 0, keepdims=False),
+                    jax.lax.dynamic_index_in_dim(conv, g, 0, keepdims=False),
+                    n_valid, put)
+
+        def attention(a, h, kp, vp, j):
+            q, k, v = jamba.qkv(cfg, a, h)
+            base = j * n_pages
+            kp, vp = _write_kv(kp, vp, base, page_table, k, v, positions,
+                               positions + 1)
+            o = attend(q[:, 0], kp, vp, lengths, page_table + base)
+            return o[:, None], kp, vp
+
+        x, k_pages, v_pages, ssm, conv = _run_hybrid(
+            cfg, p, x, k_pages, v_pages, ssm, conv, mixer_state, attention)
+        logits = jamba.head_logits(cfg, p, x)[:, 0]
+        return logits, k_pages, v_pages, ssm, conv
+
+    return decode_fn
+
+
+def _build_hybrid_prefill_fn(cfg, scfg, bucket, *, chunked: bool) -> Callable:
+    """``prefill-<b>`` (``chunked`` False: a fresh prompt, dense causal
+    attention over the bucket, state from zero) and ``chunk-<b>`` (a
+    prompt's positions ``[start, end)``: attention through the page
+    table over what earlier chunks wrote, the scan resumed from the
+    state and the conv tail they left)."""
+    n_pages = scfg.n_pages
+    kind = "chunk" if chunked else "prefill"
+
+    def body(params, k_pages, v_pages, ssm, conv, tokens, start, end,
+             page_table, slot):
+        p = jamba.param_tree(params["params"])
+        S = tokens.shape[1]
+        positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        x = jamba.embed_tokens(cfg, p, tokens)
+        mixer_state = _lane_state(slot[0], start[0] == 0, end - start)
+
+        def attention(a, h, kp, vp, j):
+            q, k, v = jamba.qkv(cfg, a, h)
+            base = j * n_pages
+            kp, vp = _write_kv(kp, vp, base, page_table, k, v,
+                               positions[:, 0], end)
+            if chunked:
+                o = paged_prefill_attention(q, kp, vp, positions, end,
+                                            page_table + base)
+            else:
+                valid = positions < end[:, None]
+                o = default_attention(q, k, v, causal=True,
+                                      segment_ids=valid.astype(jnp.int32))
+            return o, kp, vp
+
+        x, k_pages, v_pages, ssm, conv = _run_hybrid(
+            cfg, p, x, k_pages, v_pages, ssm, conv, mixer_state, attention)
+        last = jnp.clip(end - 1 - start, 0, S - 1)[:, None, None]
+        x_last = jnp.take_along_axis(x, jnp.broadcast_to(
+            last, (x.shape[0], 1, x.shape[2])), axis=1)
+        return (jamba.head_logits(cfg, p, x_last)[0, 0], k_pages, v_pages,
+                ssm, conv)
+
+    if chunked:
+        fn = body
+    else:
+        def fn(params, k_pages, v_pages, ssm, conv, tokens, length,
+               page_table, slot):
+            return body(params, k_pages, v_pages, ssm, conv, tokens,
+                        jnp.zeros_like(length), length, page_table, slot)
+
+    return _program_name(f"tdx_serve_{kind}_{bucket}")(fn)
+
+
 def build_decode_fn(family: str, cfg: TransformerConfig,
                     scfg: ResolvedServeConfig, mesh=None) -> Callable:
     """The batched decode-step program:
@@ -450,8 +669,12 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
     page_table [B, maxp]) -> (logits [B, vocab], k_pages, v_pages)``.
     ``positions[b]`` is the index the incoming token occupies; idle
     lanes carry position 0 and a null page table (their writes land in
-    the null page, their logits are ignored)."""
-    decomp = make_model(family, cfg).decode_decomposition()
+    the null page, their logits are ignored).  A hybrid stack's programs
+    carry the recurrent state too (the section above)."""
+    model = make_model(family, cfg)
+    if cfg.mamba is not None:
+        return _build_hybrid_decode_fn(cfg, scfg, mesh)
+    decomp = model.decode_decomposition()
     attend = _decode_attention(mesh, cfg.kv_heads)
 
     @_program_name("tdx_serve_decode")
@@ -487,7 +710,10 @@ def build_prefill_fn(family: str, cfg: TransformerConfig,
     ``(params, k_pages, v_pages, tokens [1, bucket], length [1],
     page_table [1, maxp]) -> (logits [vocab], k_pages, v_pages)`` —
     logits are the LAST VALID position's (the first generated token)."""
-    decomp = make_model(family, cfg).decode_decomposition()
+    model = make_model(family, cfg)
+    if cfg.mamba is not None:
+        return _build_hybrid_prefill_fn(cfg, scfg, bucket, chunked=False)
+    decomp = model.decode_decomposition()
 
     @_program_name(f"tdx_serve_prefill_{bucket}")
     def prefill_fn(params, k_pages, v_pages, tokens, length, page_table):
@@ -526,7 +752,10 @@ def build_chunk_prefill_fn(family: str, cfg: TransformerConfig,
     table, so a suffix behind a shared prefix costs only its own FLOPs.
     Logits are the last valid position's: meaningful (the first
     generated token) only on the final chunk, ignored otherwise."""
-    decomp = make_model(family, cfg).decode_decomposition()
+    model = make_model(family, cfg)
+    if cfg.mamba is not None:
+        return _build_hybrid_prefill_fn(cfg, scfg, bucket, chunked=True)
+    decomp = model.decode_decomposition()
 
     @_program_name(f"tdx_serve_chunk_{bucket}")
     def chunk_fn(params, k_pages, v_pages, tokens, start, end, page_table):
@@ -572,6 +801,11 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
     batched sibling of :func:`build_chunk_prefill_fn`: same
     ``_chunk_block`` scatter-and-ragged-attend per layer, but every lane
     at once and the head applied to every position instead of the last."""
+    if cfg.mamba is not None:
+        raise NotImplementedError(
+            "no verify-<k> program for a stack with recurrent layers: the "
+            "tick would advance every lane's state over its whole draft, "
+            "and a rejected position cannot be rolled back out of a state")
     decomp = make_model(family, cfg).decode_decomposition()
 
     @_program_name(f"tdx_serve_verify_{k}")
@@ -757,6 +991,20 @@ def serve_program_specs(
     step_out = None if pool_sh is None else (None, pool_sh, pool_sh)
     i32 = jnp.int32
     B, maxp = scfg.max_batch, scfg.max_pages_per_seq
+    # A hybrid stack's programs carry the recurrent layer group's state
+    # behind the pools, in and out, and take the lane's slot where they
+    # run one sequence; it has no cow and no verify programs.
+    state_sds, slot_sds = (), ()
+    if kv.state is not None:
+        st_sh = state_sharding(mesh, kv.state.d_inner)
+        state_sds = (
+            jax.ShapeDtypeStruct(kv.state.ssm_shape(), jnp.float32,
+                                 sharding=st_sh),
+            jax.ShapeDtypeStruct(kv.state.conv_shape(), cfg.dtype,
+                                 sharding=st_sh))
+        slot_sds = (jax.ShapeDtypeStruct((1,), i32),)
+        if step_out is not None:
+            step_out = step_out + (st_sh, st_sh)
     # The OUTPUT CONTRACT is part of every fingerprint, exactly as the
     # torch path's _registry_program_fp hashes str(NamedSharding) per
     # slot: two plans with the same class name but different rules must
@@ -791,10 +1039,10 @@ def serve_program_specs(
         specs.append(ServeProgramSpec(
             name=f"prefill-{b}",
             fn=build_prefill_fn(family, cfg, scfg, b),
-            args=(params_abs, pool_sds, pool_sds,
+            args=(params_abs, pool_sds, pool_sds, *state_sds,
                   jax.ShapeDtypeStruct((1, b), i32),
                   jax.ShapeDtypeStruct((1,), i32),
-                  jax.ShapeDtypeStruct((1, maxp), i32)),
+                  jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds),
             out_shardings=step_out,
             program_fp=_fp(f"prefill-{b}", family, cfg, scfg, extra),
             init_options=False,
@@ -803,29 +1051,30 @@ def serve_program_specs(
         specs.append(ServeProgramSpec(
             name=f"chunk-{b}",
             fn=build_chunk_prefill_fn(family, cfg, scfg, b),
-            args=(params_abs, pool_sds, pool_sds,
+            args=(params_abs, pool_sds, pool_sds, *state_sds,
                   jax.ShapeDtypeStruct((1, b), i32),
                   jax.ShapeDtypeStruct((1,), i32),
                   jax.ShapeDtypeStruct((1,), i32),
-                  jax.ShapeDtypeStruct((1, maxp), i32)),
+                  jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds),
             out_shardings=step_out,
             program_fp=_fp(f"chunk-{b}", family, cfg, scfg, extra),
             init_options=False,
         ))
-    specs.append(ServeProgramSpec(
-        name="cow",
-        fn=build_cow_fn(),
-        args=(pool_sds, pool_sds,
-              jax.ShapeDtypeStruct((1,), i32),
-              jax.ShapeDtypeStruct((1,), i32)),
-        out_shardings=None if pool_sh is None else (pool_sh, pool_sh),
-        program_fp=_fp("cow", family, cfg, scfg, extra),
-        init_options=False,
-    ))
+    if kv.state is None:
+        specs.append(ServeProgramSpec(
+            name="cow",
+            fn=build_cow_fn(),
+            args=(pool_sds, pool_sds,
+                  jax.ShapeDtypeStruct((1,), i32),
+                  jax.ShapeDtypeStruct((1,), i32)),
+            out_shardings=None if pool_sh is None else (pool_sh, pool_sh),
+            program_fp=_fp("cow", family, cfg, scfg, extra),
+            init_options=False,
+        ))
     specs.append(ServeProgramSpec(
         name="decode",
         fn=build_decode_fn(family, cfg, scfg, mesh),
-        args=(params_abs, pool_sds, pool_sds,
+        args=(params_abs, pool_sds, pool_sds, *state_sds,
               jax.ShapeDtypeStruct((B,), i32),
               jax.ShapeDtypeStruct((B,), i32),
               jax.ShapeDtypeStruct((B, maxp), i32)),
@@ -837,7 +1086,7 @@ def serve_program_specs(
     # REGARDLESS of the spec_decode host knob: warm once, then flip
     # speculation on or off without invalidating a byte of the registry
     # (the fingerprint-host-knob invariance test pins this).
-    for k in scfg.spec_buckets:
+    for k in (scfg.spec_buckets if kv.state is None else ()):
         specs.append(ServeProgramSpec(
             name=f"verify-{k}",
             fn=build_verify_fn(family, cfg, scfg, k),
