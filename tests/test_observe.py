@@ -623,6 +623,38 @@ class TestServeTickSpans:
                 "tdx.serve.decode_lane_ticks"] == 2
         eng.run()
 
+    def test_decode_tick_counts_the_blocks_the_kernel_walks(
+            self, telemetry, tick_engine):
+        """``kv_blocks`` of a decode tick's ``serve.program`` and the
+        always-on ``tdx.serve.attn_kv_blocks`` are the kernel's own
+        block arithmetic on the lanes' lengths; a program that attends
+        through jnp gathers carries 0."""
+        from torchdistx_tpu.ops import kv_blocks_walked, pages_per_block
+
+        eng = tick_engine
+        self._two_lanes(eng, "kb")
+        lengths = [l.length + 1 for l in eng.active.values()]
+        pool = eng.k_pages
+        kv, page, hd = pool.shape[2:]
+        want = kv_blocks_walked(lengths, page, kv, hd, pool.dtype)
+        # TINY's head dim walks a page a block: the pages under the lanes.
+        assert pages_per_block(kv, page, hd, pool.dtype) == 1
+        assert want == sum(-(-n // page) for n in lengths) > 0
+        before = observe.counter("tdx.serve.attn_kv_blocks").value
+        n0 = len(observe.tracer().events)
+        eng.step()
+        progs = [e["args"] for e in list(observe.tracer().events)[n0:]
+                 if e["ph"] == "X" and e["name"] == "serve.program"]
+        assert [(a["program"], a["kv_blocks"]) for a in progs] == [
+            ("decode", want)]
+        assert observe.counter(
+            "tdx.serve.attn_kv_blocks").value - before == want
+        eng.run()
+        others = [e["args"] for e in observe.tracer().events
+                  if e["ph"] == "X" and e["name"] == "serve.program"
+                  and e["args"]["program"] != "decode"]
+        assert others and all(a["kv_blocks"] == 0 for a in others)
+
     def test_prefill_and_chunk_paths_open_the_same_children(
             self, telemetry, tick_engine):
         from torchdistx_tpu.serve import Request

@@ -4,24 +4,42 @@ ragged batch shapes, and match flash attention / dense attention on
 contiguous single-page layouts — the serving engine's numerical
 foundation."""
 
+import importlib
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from torchdistx_tpu.ops import (
     flash_attention,
+    kv_blocks_walked,
+    pages_per_block,
     paged_attention,
     paged_attention_reference,
 )
 from torchdistx_tpu.models.layers import default_attention
+
+# ``ops.paged_attention`` the attribute is the function; this is the module.
+pa = importlib.import_module("torchdistx_tpu.ops.paged_attention")
 
 
 def _to_pool(tok_major):
     """Token-major pages [P, page, KV, D] -> the pool layout
     [P, KV, page, D] the kernel and the serving programs use."""
     return tok_major.transpose(0, 2, 1, 3)
+
+
+def _assert_live_rows_match(out, ref, lengths, atol):
+    """Kernel == reference on every lane that attends anything (an idle
+    lane's reference row is a uniform softmax, its kernel row zero)."""
+    live = np.asarray(lengths) > 0
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.isfinite(out).all()
+    assert np.all(out[~live] == 0.0)
+    np.testing.assert_allclose(out[live], ref[live], atol=atol)
 
 
 def _rand_case(seed, *, B, H, KV, D, page, n_pages, maxp, lengths, dtype):
@@ -184,3 +202,188 @@ def test_shape_validation():
         paged_attention(jnp.zeros((2, 4, 4)), kp, kp, lens, table)
     with pytest.raises(ValueError, match="batch mismatch"):
         paged_attention(q, kp, kp, lens, jnp.zeros((3, 2), jnp.int32))
+
+
+# -- the walk (PR 29): blocks of whole pages, as far as the length ----------
+#
+# A head dim of 128 so that a block holds many pages, as on the chip (a
+# toy head dim walks a page a block, which the cases above cover), and
+# one kv head of float32 so that the pools stay small: 32 pages of 16, or
+# 128 of 4, make the 512 tokens of a block.
+
+WALK = dict(H=2, KV=1, D=128, dtype=jnp.float32)
+
+
+def _span(page, KV=1, D=128, dtype=jnp.float32):
+    return pages_per_block(KV, page, D, dtype) * page
+
+
+@pytest.mark.parametrize("blocks,off", [(1, -1), (1, 0), (1, 1), (2, -1),
+                                        (2, 0), (2, 1)])
+def test_walk_at_block_boundaries(blocks, off):
+    """A length of ``k x block + {-1, 0, 1}`` tokens beside a one-token
+    lane: the last block holds all but one of its rows, all of them, or
+    one row of one page."""
+    page = 16
+    n = blocks * _span(page) + off
+    maxp = -(-n // page) + 1
+    q, kp, vp, lens, table = _rand_case(
+        10 + blocks, B=2, page=page, n_pages=2 * maxp + 1, maxp=maxp,
+        lengths=[n, 1], **WALK)
+    ref = paged_attention_reference(q, kp, vp, lens, table)
+    out = paged_attention(q, kp, vp, lens, table)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("page", [4, 16])
+def test_walk_table_width_no_multiple_of_the_block(page):
+    """``max_pages`` = one block and five pages, every page in use: the
+    second block is five pages long and the walk stops there."""
+    maxp = pages_per_block(1, page, 128, jnp.float32) + 5
+    q, kp, vp, lens, table = _rand_case(
+        20, B=2, page=page, n_pages=2 * maxp + 1, maxp=maxp,
+        lengths=[maxp * page, maxp * page - page - 1], **WALK)
+    ref = paged_attention_reference(q, kp, vp, lens, table)
+    out = paged_attention(q, kp, vp, lens, table)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("lengths", [
+    [0, 16, "full"],           # idle, one page, the whole table
+    ["full", 0, 0, 3],         # idle lanes between live ones
+    [0, 0, 600],               # idle lanes first: nobody fetched for them
+    [5, 0],                    # an idle lane last
+], ids=["idle-page-full", "idle-between", "idle-first", "idle-last"])
+def test_walk_mixed_batch(lengths):
+    """An idle lane passes the fetch of the next lane's first block on,
+    writes a zero row and leaves the slots' order intact."""
+    page, maxp = 16, 40
+    lengths = [maxp * page if n == "full" else n for n in lengths]
+    B = len(lengths)
+    q, kp, vp, lens, table = _rand_case(
+        21, B=B, page=page, n_pages=B * maxp + 1, maxp=maxp,
+        lengths=lengths, **WALK)
+    ref = paged_attention_reference(q, kp, vp, lens, table)
+    out = paged_attention(q, kp, vp, lens, table)
+    _assert_live_rows_match(out, ref, lengths, 1e-5)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("H,KV,D", [(20, 1, 128),   # Jamba: Gp 24
+                                    (32, 8, 128),   # Mistral / Llama-3
+                                    (4, 4, 64)],    # GPT-2: MHA, 64
+                         ids=["jamba", "mistral", "gpt2"])
+def test_walk_head_layouts_of_the_served_families(H, KV, D, dtype, atol):
+    page, maxp = 16, 36
+    lengths = [maxp * page, 0, 530, 17]
+    q, kp, vp, lens, table = _rand_case(
+        22, B=4, H=H, KV=KV, D=D, page=page, n_pages=4 * maxp + 1,
+        maxp=maxp, lengths=lengths, dtype=dtype)
+    ref = paged_attention_reference(q, kp, vp, lens, table)
+    out = paged_attention(q, kp, vp, lens, table)
+    assert out.dtype == q.dtype
+    _assert_live_rows_match(out, ref, lengths, atol)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 3e-2)])
+def test_page_kernel_matches_reference(dtype, atol):
+    """The kernel a head dim of 64 gets on the chip, where Mosaic cannot
+    slice the pool (the interpreter walks every head dim, so it is asked
+    for here)."""
+    page, maxp = 16, 5
+    lengths = [maxp * page, 0, 1, 37]
+    q, kp, vp, lens, table = _rand_case(
+        23, B=4, H=4, KV=4, D=64, page=page, n_pages=4 * maxp + 1,
+        maxp=maxp, lengths=lengths, dtype=dtype)
+    ref = paged_attention_reference(q, kp, vp, lens, table)
+    out = pa._paged_attention(q, kp, vp, lens, table, 1, True, walk=False)
+    _assert_live_rows_match(out, ref, lengths, atol)
+
+
+def test_walk_flat_pool_with_a_layer_base():
+    """The serving programs' call: the layers' pools flat, ``[L x P, KV,
+    page, D]``, and the table offset by the layer's base."""
+    page, maxp, L = 16, 34, 3
+    B, P = 2, 2 * maxp + 1
+    lengths = [maxp * page - 3, 20]
+    q, kp, vp, lens, table = _rand_case(
+        24, B=B, page=page, n_pages=L * P, maxp=maxp, lengths=lengths,
+        **WALK)
+    table = table % P  # page ids of one layer
+    for layer in range(L):
+        base = layer * P
+        ref = paged_attention_reference(
+            q, kp[base:base + P], vp[base:base + P], lens, table)
+        out = paged_attention(q, kp, vp, lens, table + base)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("walk", [True, False], ids=["walk", "page-kernel"])
+def test_nothing_past_the_length_is_touched(walk):
+    """Table entries past ``ceil(length / page)`` point outside the pool,
+    every page no sequence maps holds NaN, and VMEM starts as NaN: the
+    call raises on no out-of-bounds read and returns finite rows that
+    match the reference (which gets a table it can gather)."""
+    page, maxp, D = 16, 40, (128 if walk else 64)
+    lengths = [0, 1, 16, 17, 600, 5]
+    B = len(lengths)
+    q, kp, vp, lens, table = _rand_case(
+        25, B=B, H=2, KV=1, D=D, page=page, n_pages=B * maxp + 1, maxp=maxp,
+        lengths=lengths, dtype=jnp.float32)
+    used = np.arange(maxp)[None, :] < -(-np.asarray(lengths) // page)[:, None]
+    table = np.asarray(table)
+    mapped = np.zeros(kp.shape[0], bool)
+    mapped[table[used]] = True
+    kp = jnp.where(mapped[:, None, None, None], kp, jnp.nan)
+    vp = jnp.where(mapped[:, None, None, None], vp, jnp.nan)
+    wild = jnp.asarray(np.where(used, table, 10 ** 6), jnp.int32)
+    out = pa._paged_attention(
+        q, kp, vp, lens, wild, pages_per_block(1, page, D, jnp.float32),
+        pltpu.InterpretParams(out_of_bounds_reads="raise",
+                              uninitialized_memory="nan"), walk=walk)
+    ref = paged_attention_reference(
+        q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), lens, jnp.asarray(table))
+    _assert_live_rows_match(out, ref, lengths, 1e-5)
+
+
+def test_block_arithmetic_against_a_hand_count():
+    """``pages_per_block`` for the pools the repo serves, and the blocks
+    a batch walks, counted by hand."""
+    # 8 kv heads x 16 rows x 128 x 2 B = 32 KB a page and pool; four
+    # buffers of 32 pages are 4 MiB, and 32 pages are the 512 tokens.
+    assert pages_per_block(8, 16, 128, jnp.bfloat16) == 32
+    # One kv head: 4 KB a page, the 512 tokens bind.
+    assert pages_per_block(1, 16, 128, jnp.bfloat16) == 32
+    # float32 doubles the page: the bytes bind at 16 pages.
+    assert pages_per_block(8, 16, 128, jnp.float32) == 16
+    # 32 kv heads (an MHA pool of 128): 128 KB a page, 8 pages.
+    assert pages_per_block(32, 16, 128, jnp.bfloat16) == 8
+    # A bfloat16 page of 8 rows fills a 16-row tile all the same.
+    assert pages_per_block(16, 8, 128, jnp.bfloat16) == 16
+    # A head dim Mosaic cannot slice goes a page at a time.
+    assert pages_per_block(25, 16, 64, jnp.bfloat16) == 1
+    assert pages_per_block(2, 8, 16, jnp.float32) == 1
+    # 512 tokens a block: 0, 1 and 512 tokens are 0, 1 and 1 blocks, 513
+    # are 2, 1,025 are 3.
+    assert kv_blocks_walked([0, 1, 512, 513, 1025], 16, 8, 128,
+                            jnp.bfloat16) == 7
+    assert kv_blocks_walked(np.array([245] * 32), 16, 8, 128,
+                            jnp.bfloat16) == 32
+    # A page a block: the pages under the lengths.
+    assert kv_blocks_walked([0, 1, 16, 17], 16, 25, 64, jnp.bfloat16) == 4
+    assert kv_blocks_walked([], 16, 8, 128, jnp.bfloat16) == 0
+
+
+def test_length_past_the_table_is_held_to_the_table():
+    """A length the table cannot hold walks the table and no further, as
+    the reference's mask does."""
+    page, maxp = 16, 3
+    q, kp, vp, _, table = _rand_case(
+        26, B=2, page=page, n_pages=8, maxp=maxp, lengths=[0, 0], **WALK)
+    lens = jnp.asarray([maxp * page + 40, 9], jnp.int32)
+    ref = paged_attention_reference(q, kp, vp, lens, table)
+    out = paged_attention(q, kp, vp, lens, table)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)

@@ -283,3 +283,70 @@ def test_decode_on_tp2_mesh_equals_one_device():
     # another order, so later layers' K/V differ in the last place.
     for a, b in zip(got[1:], want[1:]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+# -- the walking kernel under the programs (PR 29) ----------------------------
+#
+# The configurations above have a head dim of 8, which the decode kernel
+# walks a page a block.  Here the head dim is 128, as every configuration
+# the benchmark serves has it, so a block is 64 pages of 8 tokens, and
+# lane 0 attends 530 tokens: two blocks, the second one three pages long,
+# at each layer's own base of the flat pool.
+
+WIDE = TransformerConfig(
+    vocab_size=128, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+    d_ff=64, max_seq_len=640, dtype=jnp.float32,
+)
+WIDE_SCFG = ServeConfig(max_batch=3, page_size=8, n_pages=150,
+                        max_pages_per_seq=70, prefill_buckets=(16,),
+                        spec_decode=False)
+
+
+def _wide_decode_spec(mesh=None, plan=None):
+    return {s.name: s for s in serve_program_specs(
+        "llama", WIDE, WIDE_SCFG, include_init=False, mesh=mesh,
+        plan=plan)}["decode"]
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_decode_walks_blocks_of_pages_at_each_layers_base(tp, monkeypatch):
+    from torchdistx_tpu.models import decoder_lm_plan
+    from torchdistx_tpu.ops import (kv_blocks_walked, pages_per_block,
+                                    paged_attention_reference)
+    from torchdistx_tpu.parallel import make_mesh
+
+    one = _wide_decode_spec()
+    params = _random_like(one.args[0], 3)
+    k_pages, v_pages = _random_like((one.args[1], one.args[2]), 4)
+    assert pages_per_block(2 // tp, 8, 128, jnp.float32) == 64
+    positions = [529, 0, 7]  # lane 1 idle, lane 2 inside its first page
+    assert kv_blocks_walked([530, 0, 8], 8, 2 // tp, 128, jnp.float32) == 3
+    table = np.zeros((3, 70), np.int32)
+    table[0, :67] = 1 + np.random.RandomState(0).permutation(140)[:67]
+    table[2, 0] = 149
+    rest = (jnp.asarray([5, 0, 9], jnp.int32),
+            jnp.asarray(positions, jnp.int32), jnp.asarray(table))
+
+    if tp == 1:
+        got = jax.jit(one.fn)(params, k_pages, v_pages, *rest)
+    else:
+        mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
+        spec = _wide_decode_spec(mesh, decoder_lm_plan(fsdp=None, ep=None))
+        placed = jax.tree.map(lambda a, s: jax.device_put(a, s.sharding),
+                              (params, k_pages, v_pages),
+                              tuple(spec.args[:3]))
+        got = jax.jit(spec.fn, out_shardings=spec.out_shardings)(
+            *placed, *rest)
+
+    monkeypatch.setattr(programs, "paged_attention",
+                        paged_attention_reference)
+    want = jax.jit(_wide_decode_spec().fn)(params, k_pages, v_pages, *rest)
+    live = np.asarray([0, 2])
+    np.testing.assert_allclose(np.asarray(got[0])[live],
+                               np.asarray(want[0])[live], atol=2e-5)
+    # Pages a sequence can own; the null page takes the idle lane's rows,
+    # which follow its attention output (zeros from the kernel, a uniform
+    # softmax from the reference) from the second layer on.
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(a)[:, 1:],
+                                   np.asarray(b)[:, 1:], atol=2e-5)

@@ -152,3 +152,51 @@ def test_serving_program_lowers_for_tpu(program, monkeypatch):
     # decode carries the compiled kernel; the others attend through the
     # gather-based jnp path and must lower without one.
     assert ("tpu_custom_call" in module) == (program == "decode")
+
+
+# -- the whole compile, for a chip that is described and not attached ---------
+#
+# ``jax.export`` above stops at the Mosaic module; what Mosaic itself
+# refuses (a slice not aligned to the tiling, more VMEM than a kernel
+# may have) shows only when the module is compiled.  The TPU compiler is
+# installed beside jax and compiles for a described v5e: the decode
+# kernel at the widths the benchmark's cells and the kept configuration
+# files have.  The topology is described inside a fixture (only one
+# process may load the TPU library, and under several workers only the
+# one that is given this file may try).
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,H,KV,D,page,maxp,dtype", [
+    (32, 32, 8, 128, 16, 48, jnp.bfloat16),    # mistral7b-chat-backlog
+    (32, 32, 8, 128, 16, 260, jnp.bfloat16),   # mistral7b-doc-prefill
+    (128, 20, 1, 128, 16, 48, jnp.bfloat16),   # jamba2-3b-chat-backlog
+    (32, 16, 4, 128, 16, 48, jnp.bfloat16),    # one shard of a tp=2 mesh
+    (4, 32, 8, 128, 16, 8, jnp.float32),       # float32 pools
+    (4, 32, 8, 128, 8, 8, jnp.bfloat16),       # a page of half a tile
+    (8, 25, 25, 64, 16, 48, jnp.bfloat16),     # gpt2-xl: ``_page_kernel``
+], ids=["mistral-48", "mistral-260", "jamba", "tp2-shard", "float32",
+        "page8-bf16", "gpt2-xl"])
+def test_paged_attention_compiles_for_v5e(one_chip, B, H, KV, D, page, maxp,
+                                          dtype):
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fn = jax.jit(functools.partial(paged_attention, interpret=False))
+    compiled = fn.lower(
+        arg((B, H, D), dtype), arg((512, KV, page, D), dtype),
+        arg((512, KV, page, D), dtype), arg((B,), jnp.int32),
+        arg((B, maxp), jnp.int32)).compile()
+    assert "tdx_paged_attention_decode" in compiled.as_text()

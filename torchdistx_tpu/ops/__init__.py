@@ -8,6 +8,8 @@ an interpret-mode path so the full test suite runs on CPU.
 from .autotune import tune_flash_blocks
 from .flash_attention import flash_attention, make_flash_attention
 from .paged_attention import (
+    kv_blocks_walked,
+    pages_per_block,
     paged_attention,
     paged_attention_reference,
     paged_prefill_attention,
@@ -16,8 +18,10 @@ from .segments import normalize_segment_ids
 
 __all__ = [
     "flash_attention",
+    "kv_blocks_walked",
     "make_flash_attention",
     "normalize_segment_ids",
+    "pages_per_block",
     "paged_attention",
     "paged_attention_reference",
     "paged_prefill_attention",

@@ -97,6 +97,7 @@ import numpy as np
 from .. import chaos, observe
 from ..observe import reqledger
 from ..models import PRESETS, TransformerConfig
+from ..ops.paged_attention import kv_blocks_walked
 from ..utils.logging import get_logger
 from .kv_cache import (OutOfPages, PagedKVCache, init_pools, init_state,
                        pool_sharding, state_sharding)
@@ -257,6 +258,13 @@ class ServeEngine:
         # over and the lanes it decodes (the work a tick's time buys).
         self._attended = observe.counter("tdx.serve.attended_tokens")
         self._lane_ticks = observe.counter("tdx.serve.decode_lane_ticks")
+        # Per decode tick: the blocks one call of the decode kernel walks
+        # (each attention layer's call walks as many), by the kernel's
+        # own arithmetic on the pool as one tp shard of it sees it.
+        self._kv_blocks = observe.counter("tdx.serve.attn_kv_blocks")
+        _, _, kv_local, page, head_dim = self.k_pages.sharding.shard_shape(
+            self.k_pages.shape)
+        self._kernel_pool = (page, kv_local, head_dim, self.k_pages.dtype)
         self._n_admitted = 0  # lifetime; serve.admit reports a tick's share
 
     # -- program cache ------------------------------------------------------
@@ -612,6 +620,7 @@ class ServeEngine:
                 chaos.execute(fault)
 
     def _run_program(self, name: str, *args, lanes: int, attended: int,
+                     kv_blocks: int = 0,
                      fetch: bool = True) -> Optional[np.ndarray]:
         """Call the compiled model program ``name`` on the params, the
         pools, the recurrent state (a hybrid stack) and ``args`` under
@@ -622,9 +631,12 @@ class ServeEngine:
         ``serve.program`` ends when the logits are ready, so the two spans
         split device time from the copy; off, nothing waits before the
         fetch.  ``attended`` is the context the program's ``lanes`` attend
-        over, counted before anything retires."""
+        over, counted before anything retires; ``kv_blocks`` the blocks
+        the decode kernel walks for it (a program that attends through
+        jnp gathers walks none)."""
         with observe.span("serve.program", category="serve", program=name,
                           lanes=lanes, attended_tokens=attended,
+                          kv_blocks=kv_blocks,
                           state_lanes=lanes if self.state else 0) as sp:
             logits, self.k_pages, self.v_pages, *state = self._program(name)(
                 self.params, self.k_pages, self.v_pages, *self.state, *args)
@@ -838,7 +850,7 @@ class ServeEngine:
             src, dst = moved
             with observe.span("serve.program", category="serve",
                               program="cow", lanes=1,
-                              attended_tokens=0) as sp:
+                              attended_tokens=0, kv_blocks=0) as sp:
                 self.k_pages, self.v_pages = self._program("cow")(
                     self.k_pages, self.v_pages,
                     jnp.asarray([src], jnp.int32),
@@ -969,8 +981,10 @@ class ServeEngine:
         # A lane at position p attends over p + 1 tokens, its new one
         # included (idle lanes sit at 0 and attend over nothing).
         attended = int(positions.sum()) + n_lanes
+        kv_blocks = kv_blocks_walked(positions[slots] + 1,
+                                     *self._kernel_pool)
         logits = self._run_program("decode", *args, lanes=n_lanes,
-                                   attended=attended)
+                                   attended=attended, kv_blocks=kv_blocks)
         with observe.span("serve.tick.emit", category="serve",
                           program="decode", tokens=n_lanes):
             # Per-token latency: every lane's next token took this step's
@@ -997,6 +1011,7 @@ class ServeEngine:
                 self._emit(lane, int(np.argmax(logits[slot])), logits[slot])
             self._decode_steps.inc()
             self._attended.inc(attended)
+            self._kv_blocks.inc(kv_blocks)
             self._lane_ticks.inc(n_lanes)
 
     # -- speculative decode (docs/serving.md §Speculative decoding) ---------
