@@ -88,8 +88,12 @@ def run(env) -> dict:
     outcomes.update(eng.warmup())
     clk.lap("programs")
 
-    # One execution of every compiled shape, on zeros, results dropped (the
-    # pools are not donated, so the engine's state is untouched).
+    # One execution of every compiled shape, on zeros.  A call is given the
+    # engine's pools and the engine takes them back from its outputs, as its
+    # own call path does: they are the last two outputs of every program
+    # ((logits, k_pages, v_pages); ``cow``: (k_pages, v_pages)).  So the loop
+    # is right whether or not a program donates them.  On zero tables a
+    # program writes the null page 0 only, which nothing reads.
     for name, spec in eng._all_specs().items():
         args = [eng.params if i == 0 and name != "cow" else None
                 for i in range(len(spec.args))]
@@ -100,8 +104,11 @@ def run(env) -> dict:
                 args[i] = eng.k_pages if i == pools[0] else eng.v_pages
             elif args[i] is None:
                 args[i] = jnp.zeros(a.shape, a.dtype)
-        jax.block_until_ready(eng._programs[name](*args))
+        out = eng._programs[name](*args)
         del args
+        eng.k_pages, eng.v_pages = out[-2:]
+        jax.block_until_ready(out)
+        del out
     clk.lap("warmup", "every compiled shape once, on zeros")
     # ... and the engine's own host path once for every prefill bucket (and
     # a chunked prompt where the mix chunks), so that no small program of

@@ -108,8 +108,15 @@ def run(env) -> dict:
     outcomes.update(eng.warmup())
     clk.lap("programs")
 
-    # One execution of every compiled shape, on zeros, results dropped (the
-    # pools are not donated, so the engine's state is untouched).
+    # One execution of every compiled shape, on zeros.  A call is given the
+    # engine's pools and recurrent state and the engine takes them back from
+    # its outputs, as its own call path does: every program of the family
+    # returns (logits, k_pages, v_pages, *state).  So the loop is right
+    # whether or not a program donates them.  On zero tables a program writes
+    # the null page 0, which nothing reads, and the state of slot 0, which
+    # the first program of a new sequence starts from zero whatever it held.
+    # No local may outlive the loop with a pool or a state in it: it would
+    # keep a dead copy on the device for the whole run (``held`` did, 1.19 GB).
     for name, spec in eng._all_specs().items():
         args = [eng.params if i == 0 and name != "cow" else None
                 for i in range(len(spec.args))]
@@ -123,8 +130,12 @@ def run(env) -> dict:
                 args[i] = held[a.shape]
             elif args[i] is None:
                 args[i] = jnp.zeros(a.shape, a.dtype)
-        jax.block_until_ready(eng._programs[name](*args))
-        del args
+        out = eng._programs[name](*args)
+        del args, held
+        _, eng.k_pages, eng.v_pages, *state = out
+        eng.state = tuple(state)
+        jax.block_until_ready(out)
+        del out, state
     clk.lap("warmup", "every compiled shape once, on zeros")
     # ... and the engine's own host path once for every prefill bucket (and
     # a chunked prompt where the mix chunks), so that no small program of

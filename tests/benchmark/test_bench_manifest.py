@@ -4,11 +4,10 @@ plus entries, editing nothing that is there."""
 
 import json
 import os
-import shutil
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from bench_util import ROOT, add_dummies, copy_benchmark, roots
 
 
 def _manifest_mod():
@@ -21,8 +20,8 @@ def _manifest_mod():
     return mod
 
 
-def _load():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def _load(root=ROOT):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
@@ -52,11 +51,15 @@ def test_every_moves_names_a_metric_that_each_of_its_cells_reports():
                 assert cell in target["workloads"], (p["name"], cell)
 
 
-def test_names_the_issue_fixed():
-    m = _load()
+@pytest.mark.parametrize("grown", [False, True],
+                         ids=["as-committed", "with-a-later-cell"])
+def test_names_the_issue_fixed(tmp_path, grown):
+    m = _load(roots(tmp_path, grown))
     assert {e["name"] for e in m["end_to_end"]} >= {"setup_s"}
-    assert {c["name"] for c in m["configs"]} <= {
-        "mistral-7b-v0.3-d12", "gpt2-xl", "gpt2-medium"}
+    # PR 25's configurations that a cell uses are among the manifest's;
+    # later PRs append theirs.
+    assert {c["name"] for c in m["configs"]} >= {
+        "mistral-7b-v0.3-d12", "gpt2-medium"}
     assert all(w["chips"] == 1 for w in m["workloads"])
     phases = {p["name"] for p in m["per_layer"] if p["moves"] == "setup_s"}
     assert phases >= {f"bringup.{x}_s" for x in (
@@ -65,47 +68,13 @@ def test_names_the_issue_fixed():
 
 @pytest.fixture
 def copy(tmp_path):
-    dst = tmp_path / "repo"
-    dst.mkdir()
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst / "BENCHMARK.json")
-    shutil.copytree(os.path.join(ROOT, "benchmark"), dst / "benchmark",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
-    return dst
-
-
-def _add_dummies(dst):
-    """What a later PR would add: four new files and three entries."""
-    m = json.loads((dst / "BENCHMARK.json").read_text())
-    src = json.loads((dst / "benchmark/configs/gpt2-medium.json").read_text())
-    src["source"] = "https://example.org/dummy/config.json"
-    (dst / "benchmark/configs/dummy.json").write_text(json.dumps(src))
-    (dst / "benchmark/traffic/dummy-mix.json").write_text(
-        json.dumps({"mode": "train", "batch": 2, "seq_len": 512}))
-    (dst / "benchmark/metrics/dummy.counter.py").write_text(
-        "def read(ctx):\n    return len(ctx['steps']) or None\n")
-    (dst / "benchmark/limits/dummy-cell.json").write_text(
-        (dst / "benchmark/limits/gpt2m-train-1chip.json").read_text())
-    m["configs"].append({
-        "name": "dummy", "source": src["source"],
-        "file": "benchmark/configs/dummy.json", "reduced": [], "why": "a test"})
-    m["workloads"].append({
-        "name": "dummy-cell", "config": "dummy", "traffic": "dummy-mix",
-        "chips": 1, "why": "a test"})
-    for e in m["end_to_end"]:
-        if e["name"] == "train_tok_s":
-            e["workloads"].append("dummy-cell")
-    m["per_layer"].append({
-        "name": "dummy.counter", "unit": "count", "better": "higher",
-        "source": "program_counter", "layer": "train step",
-        "moves": "train_tok_s", "workloads": ["dummy-cell"]})
-    (dst / "BENCHMARK.json").write_text(json.dumps(m))
-    return m
+    return copy_benchmark(tmp_path)
 
 
 def test_a_cell_is_added_with_new_files_and_entries_only(copy):
     before = {p: p.read_bytes() for p in (copy / "benchmark").rglob("*")
               if p.is_file()}
-    _add_dummies(copy)
+    add_dummies(copy)
     assert _manifest_mod().check(str(copy)) == []
     for p, body in before.items():
         assert p.read_bytes() == body, f"{p} had to be edited"
@@ -122,7 +91,7 @@ def test_a_cell_is_added_with_new_files_and_entries_only(copy):
     (lambda m: m["per_layer"][-1].update(name="nowhere.metric"), "no reader"),
 ])
 def test_the_check_finds_a_broken_manifest(copy, breakage, word):
-    m = _add_dummies(copy)
+    m = add_dummies(copy)
     breakage(m)
     (copy / "BENCHMARK.json").write_text(json.dumps(m))
     faults = _manifest_mod().check(str(copy))

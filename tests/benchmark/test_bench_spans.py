@@ -3,7 +3,6 @@
 tracer content and a hand-made compile log, cut to the window; nothing to
 read gives None; and the manifest is sound with their entries."""
 
-import json
 import os
 import sys
 import time
@@ -13,6 +12,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
+from bench_util import roots  # noqa: E402
 from benchmark import harness, manifest, spanlog  # noqa: E402
 from torchdistx_tpu import observe  # noqa: E402
 from torchdistx_tpu.observe import compilelog, spans  # noqa: E402
@@ -151,36 +151,102 @@ def test_span_reader_without_the_clock_conversion_gives_none(ctx, monkeypatch):
     assert _reader("engine.tick_host_share").read(ctx) is None
 
 
-def test_manifest_is_sound_with_the_new_entries():
-    assert manifest.check(ROOT) == []
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        m = json.load(f)
+PR26_ORDER = [
+    "bringup.compile_s", "bringup.lower_s", "engine.tick_host_share",
+    "engine.tick_tables_p50_s", "engine.tick_emit_p50_s",
+    "programs.logits_d2h_p50_s", "programs.decode_device_p50_s",
+    "kv.attended_tokens_per_decode_tick"]
+MISTRAL_SERVING = ["mistral7b-chat-backlog", "mistral7b-doc-prefill-busy"]
+
+
+@pytest.mark.parametrize("grown", [False, True],
+                         ids=["as-committed", "with-a-later-cell"])
+def test_manifest_is_sound_with_the_new_entries(tmp_path, grown):
+    root = roots(tmp_path, grown)
+    assert manifest.check(root) == []
+    m = harness.load_manifest(root)
     by = {p["name"]: p for p in m["per_layer"]}
-    serving = ["mistral7b-chat-backlog", "mistral7b-doc-prefill"]
     for name in SPAN_METRICS:
         assert by[name]["source"] == "program_span"
-        assert by[name]["workloads"] == serving
+        # at least the two Mistral serving cells; later PRs append theirs
+        assert set(by[name]["workloads"]) >= set(MISTRAL_SERVING)
         assert by[name]["moves"] == "tpot_p50_s"
     for name in LOG_METRICS:
         assert by[name]["source"] == "program_counter"
         assert "workloads" not in by[name] and by[name]["moves"] == "setup_s"
-    # Appended, in the issue's order, after everything that was there.
-    assert [p["name"] for p in m["per_layer"]][-8:] == [
-        "bringup.compile_s", "bringup.lower_s", "engine.tick_host_share",
-        "engine.tick_tables_p50_s", "engine.tick_emit_p50_s",
-        "programs.logits_d2h_p50_s", "programs.decode_device_p50_s",
-        "kv.attended_tokens_per_decode_tick"]
+    # In the issue's order relative to each other, wherever later PRs'
+    # entries have come to lie.
+    assert [p["name"] for p in m["per_layer"]
+            if p["name"] in PR26_ORDER] == PR26_ORDER
     for name in list(SPAN_METRICS) + list(LOG_METRICS):
         assert os.path.exists(
-            os.path.join(ROOT, "benchmark", "metrics", f"{name}.py"))
+            os.path.join(root, "benchmark", "metrics", f"{name}.py"))
 
 
 @pytest.mark.parametrize("cell,expect", [
     ("mistral7b-chat-backlog", set(SPAN_METRICS) | set(LOG_METRICS)),
-    ("mistral7b-doc-prefill", set(SPAN_METRICS) | set(LOG_METRICS)),
+    ("mistral7b-doc-prefill-busy", set(SPAN_METRICS) | set(LOG_METRICS)),
     ("gpt2m-train-1chip", set(LOG_METRICS)),
 ])
 def test_each_cell_is_asked_for_its_new_metrics(cell, expect):
     m = harness.load_manifest(ROOT)
     asked = {p["name"] for p in harness.metric_names(m, cell, "per_layer")}
     assert asked & (set(SPAN_METRICS) | set(LOG_METRICS)) == expect
+
+
+# What the traced run of mistral7b-doc-prefill was asked for before the cell
+# was renamed (BENCHMARK.json at PR 29): the busy cell is asked for the same.
+DOC_PREFILL_PER_LAYER = {
+    "bringup.import_s", "bringup.backend_s", "bringup.materialize_s",
+    "bringup.programs_s", "bringup.warmup_s", "bringup.other_s",
+    "bringup.program_misses", "bringup.compile_s", "bringup.lower_s",
+    "engine.queue_wait_p90_s", "engine.verify_tick_share",
+    "engine.tick_host_share", "engine.tick_tables_p50_s",
+    "engine.tick_emit_p50_s", "kv.pages_in_use_peak_share", "kv.preemptions",
+    "kv.attended_tokens_per_decode_tick", "programs.decode_tick_p50_s",
+    "programs.prefill_s_per_ktok", "programs.logits_d2h_p50_s",
+    "programs.decode_device_p50_s", "serve.mfu", "paged_attention_roofline",
+    "device.idle_share", "device.peak_hbm_share", "latency.tpot_p90_s",
+    "latency.ttft_p50_s", "latency.ttft_p90_s", "loadgen.lateness_p99_s"}
+
+
+@pytest.mark.parametrize("group,expect", [
+    ("per_layer", DOC_PREFILL_PER_LAYER),
+    ("end_to_end", {"tpot_p50_s", "setup_s"}),
+])
+def test_the_busy_cell_is_asked_for_what_doc_prefill_was(group, expect):
+    m = harness.load_manifest(ROOT)
+    assert {p["name"] for p in harness.metric_names(
+        m, "mistral7b-doc-prefill-busy", group)} == expect
+
+
+def test_no_metric_names_the_cell_that_is_gone():
+    m = harness.load_manifest(ROOT)
+    assert [p["name"] for p in m["per_layer"] + m["end_to_end"]
+            if "mistral7b-doc-prefill" in p.get("workloads", [])] == []
+    assert "mistral7b-doc-prefill" not in {w["name"] for w in m["workloads"]}
+
+
+def test_logits_d2h_is_the_decode_ticks_fetch_and_no_prefill_s():
+    """150 of 207 program calls of the jamba cell's traced window were
+    one-row prefill fetches (PERF.md 5, PR 28): they no longer enter."""
+    observe.reset()
+    t = time.perf_counter()
+    events = []
+    for k in range(5):  # five prefills' one-row fetches, 0.6 ms
+        events.append(_span("serve.tick.d2h", t + 0.01 * k, 0.0006,
+                            program="prefill-256", bytes=262144))
+    events += [_span("serve.tick.d2h", t + 0.10, 0.011, program="decode",
+                     bytes=33554432),
+               _span("serve.tick.d2h", t + 0.15, 0.012, program="verify-2",
+                     bytes=33554432)]
+    observe.tracer().events.extend(events)
+    clk = harness.Clock(t - 1.0)
+    clk.setup_s = 1.0
+    ctx = {"clock": clk, "steps": [{"t0": t, "t1": t + 0.2}]}
+    read = _reader("programs.logits_d2h_p50_s").read
+    assert read(ctx) == pytest.approx(0.011)  # nearest rank of the two ticks
+    observe.reset()
+    observe.tracer().events.extend(events[:5])
+    assert read(ctx) is None  # a window of prefills only has none to read
+    observe.reset()

@@ -12,7 +12,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark import traffic  # noqa: E402
 
-MIXES = ["chat-backlog", "doc-prefill", "chat-interactive"]
+MIXES = ["chat-backlog", "doc-prefill-busy", "chat-interactive"]
 
 
 def mix(name):
@@ -61,9 +61,44 @@ def test_lengths_and_arrivals_keep_to_the_mix(name):
 
 
 def test_median_prompt_is_the_mix_s_median():
-    m = mix("doc-prefill")
+    m = mix("doc-prefill-busy")
     lens = sorted(traffic.lognormal_grid(401, m["prompt"]))
     assert abs(lens[200] - m["prompt"]["median"]) <= 2
+
+
+def test_the_busy_mix_at_the_window_s_length():
+    """At the benchmark's own 40 s: the rate over the grid, due times in
+    order, the median prompt the mix's, and the same schedule for two
+    ``--seed``s as large as the driver's."""
+    m = mix("doc-prefill-busy")
+    a = traffic.serving(m, 2**31 + 101, 40.0, 32768)
+    b = traffic.serving(m, 2**31 + 102, 40.0, 32768)
+    assert 0.7 * m["rate_per_s"] <= len(a) / 40.0 <= 1.1 * m["rate_per_s"]
+    assert len(a) >= 90  # a hundred requests due, where 1.25/s gave 50
+    due = [r["due_s"] for r in a]
+    assert due == sorted(due) and 0.0 <= due[0] and due[-1] < 40.0
+    lens = sorted(len(r["tokens"]) for r in a)
+    assert abs(lens[len(lens) // 2] - m["prompt"]["median"]) <= 64
+    shape = lambda rs: [(r["rid"], r["due_s"], len(r["tokens"]),
+                         r["max_new_tokens"]) for r in rs]
+    assert shape(a) == shape(b)
+    assert [r["tokens"] for r in a] != [r["tokens"] for r in b]
+
+
+def test_the_busy_mix_is_doc_prefill_s_but_for_its_rate():
+    """Every key but ``rate_per_s`` as PR 25 set it (``doc-prefill.json``,
+    gone with the cell it served)."""
+    m = mix("doc-prefill-busy")
+    assert m["mode"] == "open_loop" and m["schedule_seed"] == 1
+    assert m["prompt"] == {"median": 2048, "sigma": 0.45, "min": 1024,
+                           "max": 4096}
+    assert m["output"] == {"median": 32, "sigma": 0.4, "min": 16, "max": 64}
+    assert m["max_total"] == 4160 and m["shared_prefix"] == 0
+    assert m["engine"] == {"prefill_buckets": [1024, 2048],
+                           "prefill_chunk": 2048, "max_pages_per_seq": 260}
+    assert m["rate_per_s"] > 1.25
+    assert not os.path.exists(
+        os.path.join(ROOT, "benchmark", "traffic", "doc-prefill.json"))
 
 
 def test_training_rows_differ_by_step_and_repeat_by_seed():
