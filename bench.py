@@ -107,9 +107,9 @@ def _init_jax(cache: bool = False):
     if plat:
         jax.config.update("jax_platforms", plat)
     if cache:
-        from torchdistx_tpu.jax_bridge import materialize as mat
+        from torchdistx_tpu import compile_service
 
-        mat._maybe_enable_cache()
+        compile_service.bind_cache()
     return jax
 
 
@@ -973,6 +973,7 @@ def phase_materialize_pipeline() -> dict:
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu.deferred_init import deferred_init
     from torchdistx_tpu.jax_bridge import materialize_module_jax
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.jax_bridge import materialize as mat
 
     K = int(os.environ.get("TDX_PIPE_BENCH_LAYERS", "128"))
@@ -1003,7 +1004,7 @@ def phase_materialize_pipeline() -> dict:
             for mode in ("off", "auto"):
                 cache = tempfile.mkdtemp(prefix=f"tdx_pipe_{mode}_")
                 caches.append(cache)
-                mat._reset_cache_binding()  # variants: no shared latch
+                compile_service.reset_cache_binding()  # variants: no shared latch
                 with tdx_config.override(
                     materialize_pipeline=mode, cache_dir=cache
                 ):
@@ -1022,7 +1023,7 @@ def phase_materialize_pipeline() -> dict:
         _publish_pipeline_phase(out, times, rep_stats)
         # Warm pass: rerun over the last auto cache — per-group entries
         # hit.
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(
             materialize_pipeline="auto", cache_dir=last_auto_cache
         ):
@@ -1035,7 +1036,7 @@ def phase_materialize_pipeline() -> dict:
     finally:
         # A mid-phase failure must not orphan tmpdirs of compiled XLA
         # binaries or leave the process latched onto one of them.
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         for cache in caches:
             shutil.rmtree(cache, ignore_errors=True)
     bitwise = set(values["off"]) == set(values["auto"]) and all(
@@ -1103,6 +1104,7 @@ def phase_materialize_bandwidth() -> dict:
 
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu.deferred_init import deferred_init
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.jax_bridge import materialize as mat
     from torchdistx_tpu.jax_bridge import materialize_module_jax
     from torchdistx_tpu.observe import costmodel
@@ -1145,7 +1147,7 @@ def phase_materialize_bandwidth() -> dict:
     values = {}
     stats = {}
     try:
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         best = {}
         for name, kw in variants.items():
             # resume/registry pinned OFF: an ambient
@@ -1177,7 +1179,7 @@ def phase_materialize_bandwidth() -> dict:
             best[name] = min(times)  # unrounded: the math below uses it
             out[f"warm_{name}_s"] = round(best[name], 3)
     finally:
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(cache, ignore_errors=True)
 
     bitwise = all(
@@ -1322,7 +1324,7 @@ def phase_serving() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         Request, ServeConfig, oracle_generate, spin_up_replica,
@@ -1365,7 +1367,7 @@ def phase_serving() -> dict:
 
     try:
         # COLD: empty cache, no registry — bring-up pays every compile.
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(cache_dir=fresh_cache("cold")):
             t0 = time.perf_counter()
             eng = spin_up_replica(cfg, family="llama", serve_cfg=scfg,
@@ -1403,10 +1405,10 @@ def phase_serving() -> dict:
 
         # WARM: publish the program set, then bring up from a FRESH
         # local cache through the registry.
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         warm_serving("llama", cfg, fresh_cache("pub"), registry_dir=reg,
                      serve_cfg=scfg)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         base = {r["name"]: r["value"] for r in observe.counters().snapshot()
                 if r["type"] == "counter"}
@@ -1435,7 +1437,7 @@ def phase_serving() -> dict:
         )
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(reg, ignore_errors=True)
         for d in caches:
             shutil.rmtree(d, ignore_errors=True)
@@ -1470,7 +1472,7 @@ def phase_serving_fleet() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import chaos, observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         FleetConfig, Request, ServeConfig, ServeFleet, oracle_generate,
@@ -1524,7 +1526,7 @@ def phase_serving_fleet() -> dict:
     try:
         # COLD: empty cache, no registry — the scale-up latency a fleet
         # without artifact sharing pays for every new replica.
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(cache_dir=fresh_cache("cold")):
             t0 = time.perf_counter()
             spin_up_replica(cfg, family="llama", serve_cfg=scfg)
@@ -1539,10 +1541,10 @@ def phase_serving_fleet() -> dict:
         # Rebuilds stay off the compiler — they re-load from the local
         # disk cache, so the zero-local-compile gate is unaffected.
         jax.clear_caches()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         warm_serving("llama", cfg, fresh_cache("pub"), registry_dir=reg,
                      serve_cfg=scfg)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         base = {r["name"]: r["value"] for r in observe.counters().snapshot()
                 if r["type"] == "counter"}
@@ -1638,7 +1640,7 @@ def phase_serving_fleet() -> dict:
         out["oracle_equal"] = True
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(reg, ignore_errors=True)
         for d in caches:
             shutil.rmtree(d, ignore_errors=True)
@@ -1680,7 +1682,7 @@ def phase_guardrails() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import chaos, observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         FleetConfig, GuardrailConfig, Request, ServeConfig, ServeFleet,
@@ -1788,7 +1790,7 @@ def phase_guardrails() -> dict:
     try:
         # COLD bring-up: what a breaker respawn would cost WITHOUT the
         # artifact registry (every program XLA-compiled from scratch).
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(cache_dir=fresh_cache("cold")):
             t0 = time.perf_counter()
             spin_up_replica(cfg, family="llama", serve_cfg=scfg)
@@ -1800,10 +1802,10 @@ def phase_guardrails() -> dict:
         # serving_fleet phase: retained JIT code regions pile up mmap
         # mappings until vm.max_map_count says ENOMEM.
         jax.clear_caches()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         warm_serving("llama", cfg, fresh_cache("pub"), registry_dir=reg,
                      serve_cfg=scfg)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         base = csnap()
         fleet_cache = fresh_cache("fleet")
@@ -1876,7 +1878,7 @@ def phase_guardrails() -> dict:
         out["oracle_equal"] = True
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(reg, ignore_errors=True)
         for d in caches:
             shutil.rmtree(d, ignore_errors=True)
@@ -1920,7 +1922,7 @@ def phase_serving_prefix() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         Request, ServeConfig, oracle_generate, spin_up_replica,
@@ -2007,7 +2009,7 @@ def phase_serving_prefix() -> dict:
            "host_cpu_count": os.cpu_count()}
     cache = tempfile.mkdtemp(prefix="tdx_prefix_bench_")
     try:
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         with tdx_config.override(cache_dir=cache):
             # OFF: every prompt pays its full (bucketed) prefill.  The
@@ -2083,7 +2085,7 @@ def phase_serving_prefix() -> dict:
             out["prefill_chunks"] = chunks
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(cache, ignore_errors=True)
 
     out["prefix_off_tokens_per_s"] = round(tps_off, 2)
@@ -2150,7 +2152,7 @@ def phase_serving_spec() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         Request, ServeConfig, oracle_generate, spin_up_replica,
@@ -2218,7 +2220,7 @@ def phase_serving_spec() -> dict:
     cache = tempfile.mkdtemp(prefix="tdx_spec_bench_")
     spec_drafted = spec_accepted = spec_ticks = 0
     try:
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         with tdx_config.override(cache_dir=cache):
             # Best-of-3 per arm: the structural gap (program calls per
@@ -2249,7 +2251,7 @@ def phase_serving_spec() -> dict:
                 )
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(cache, ignore_errors=True)
 
     out["spec_off_tokens_per_s"] = round(tps_off, 2)
@@ -2308,7 +2310,7 @@ def phase_serving_ledger() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.observe import reqledger
     from torchdistx_tpu.serve import (
@@ -2380,7 +2382,7 @@ def phase_serving_ledger() -> dict:
            "host_cpu_count": os.cpu_count()}
     cache = tempfile.mkdtemp(prefix="tdx_ledger_bench_")
     try:
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         with tdx_config.override(cache_dir=cache):
             # Warm-up arm: compiles the program set into the local cache
@@ -2415,7 +2417,7 @@ def phase_serving_ledger() -> dict:
             tail = reqledger.tail_report()
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(cache, ignore_errors=True)
 
     out["ledger_off_tokens_per_s"] = round(max(tps_off), 2)
@@ -2479,7 +2481,7 @@ def phase_serving_rollover() -> dict:
     import jax.numpy as jnp
     import torchdistx_tpu.config as tdx_config
     from torchdistx_tpu import observe
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         FleetConfig, Request, RolloverConfig, ServeConfig, ServeFleet,
@@ -2602,9 +2604,9 @@ def phase_serving_rollover() -> dict:
     cache = tempfile.mkdtemp(prefix="tdx_roll_bench_cache_")
     ckpt_dir = tempfile.mkdtemp(prefix="tdx_roll_bench_ckpt_")
     try:
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         warm_serving("llama", cfg, cache, registry_dir=reg, serve_cfg=scfg)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         observe.enable(True)
         base = {r["name"]: r["value"] for r in observe.counters().snapshot()
                 if r["type"] == "counter"}
@@ -2683,7 +2685,7 @@ def phase_serving_rollover() -> dict:
         out["oracle_equal"] = True
     finally:
         observe.enable(None)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(reg, ignore_errors=True)
         shutil.rmtree(cache, ignore_errors=True)
         shutil.rmtree(ckpt_dir, ignore_errors=True)

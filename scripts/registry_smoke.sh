@@ -83,8 +83,8 @@ assert n_hit > 0 and r_hit == n_hit, (n_hit, r_hit)
 
 # Bitwise parity vs the no-registry path.
 import torchdistx_tpu.config as tdx_config
-from torchdistx_tpu.jax_bridge import materialize as mat
-mat._reset_cache_binding()
+from torchdistx_tpu import compile_service
+compile_service.reset_cache_binding()
 with tdx_config.override(cache_dir=None, registry_dir=None,
                          materialize_pipeline="off"):
     base = materialize_module_jax(deferred_init(Demo), seed=0)

@@ -1,6 +1,6 @@
 """The compile service against the INSTALLED jax, with cache errors raised.
 
-``_compile_program`` reaches into ``jax._src.compilation_cache`` (the
+``compile_service.compile_program`` reaches into ``jax._src.compilation_cache`` (the
 quarantine guard, cache-key recording, registry direct-serve).  jax
 itself catches whatever those wrappers raise and carries on as a cache
 miss with a warning — which is how a jax upgrade that added an argument
@@ -22,8 +22,7 @@ import numpy as np
 import pytest
 
 import torchdistx_tpu.config as tdx_config
-from torchdistx_tpu import observe
-from torchdistx_tpu.jax_bridge import materialize as mat
+from torchdistx_tpu import compile_service, observe
 from torchdistx_tpu.registry import ArtifactRegistry
 
 
@@ -34,7 +33,7 @@ def _strict_cache(monkeypatch):
     jax.config.update("jax_raise_persistent_cache_errors", True)
     yield
     jax.config.update("jax_raise_persistent_cache_errors", prev)
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
 
 
 def _program(x):
@@ -48,10 +47,10 @@ def _compile(cache_dir, registry_dir=None, program_fp=None):
     """One cold-process-like compile: in-memory caches dropped, binding
     re-resolved, then the service's own entry point."""
     jax.clear_caches()
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     with tdx_config.override(cache_dir=cache_dir, registry_dir=registry_dir):
-        mat._maybe_enable_cache()
-        compiled, _, _, outcome, _ = mat._compile_program(
+        compile_service.bind_cache()
+        compiled, _, _, outcome, _ = compile_service.compile_program(
             _program, _ARGS, None, program_fp=program_fp,
             init_compiler_options=False,
         )
@@ -97,12 +96,12 @@ def test_external_cache_dir_wins_and_is_never_rebound(tmp_path, monkeypatch):
     assert outcome == "miss"
     assert jax.config.jax_compilation_cache_dir == placed
     assert not os.path.exists(tmp_path / "ignored")
-    mat._reset_cache_binding()  # un-latches, but must not unbind
+    compile_service.reset_cache_binding()  # un-latches, but must not unbind
     assert jax.config.jax_compilation_cache_dir == placed
     _, outcome = _compile(str(tmp_path / "ignored"))
     assert outcome == "hit"
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     assert jax.config.jax_compilation_cache_dir is None
 
 
@@ -120,7 +119,7 @@ def test_bypass_reads_and_writes_nothing(tmp_path):
     before = sorted(os.listdir(a))
     jax.clear_caches()
     with tdx_config.override(cache_dir=a):
-        compiled, _, _, outcome, _ = mat._compile_program(
+        compiled, _, _, outcome, _ = compile_service.compile_program(
             _program, _ARGS, None, bypass_cache=True,
             init_compiler_options=False,
         )

@@ -123,10 +123,10 @@ class TestMaterializeAccounting:
 
         from torchdistx_tpu.deferred_init import deferred_init
         from torchdistx_tpu.jax_bridge import materialize_module_jax
-        from torchdistx_tpu.jax_bridge import materialize as mat
+        from torchdistx_tpu import compile_service
 
         monkeypatch.setenv("TDX_CACHE_MIN_COMPILE_S", "0")
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         cache = tmp_path / "cache"
         reg = tmp_path / "registry"
         try:
@@ -134,7 +134,7 @@ class TestMaterializeAccounting:
                                      registry_dir=str(reg)):
                 materialize_module_jax(deferred_init(torch.nn.Linear, 16, 8))
         finally:
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
         metas = glob.glob(str(reg / "*" / "meta.json"))
         assert metas, list(reg.iterdir())
         doc = json.load(open(metas[0]))

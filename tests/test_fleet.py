@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 import torchdistx_tpu.config as tdx_config
 from torchdistx_tpu import chaos, observe
-from torchdistx_tpu.jax_bridge import materialize as mat
+from torchdistx_tpu import compile_service
 from torchdistx_tpu.models import TransformerConfig
 from torchdistx_tpu.serve import (
     AdmissionQueue,
@@ -412,7 +412,7 @@ def test_scale_up_is_registry_warm_zero_local_compiles(shared_cache):
         summary = warm_serving("llama", LLAMA, warm_cache,
                                registry_dir=reg, serve_cfg=SCFG)
         assert not summary["unwarmed"], summary
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         base = {r["name"]: r["value"]
                 for r in observe.counters().snapshot()
                 if r["type"] == "counter"}
@@ -440,6 +440,6 @@ def test_scale_up_is_registry_warm_zero_local_compiles(shared_cache):
     finally:
         observe.enable(None)
         observe.health.reset()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         for d in (reg, warm_cache, fresh_cache):
             shutil.rmtree(d, ignore_errors=True)

@@ -114,10 +114,10 @@ class TestTriggers:
         from torchdistx_tpu.jax_bridge import (
             MaterializationError, materialize_module_jax,
         )
-        from torchdistx_tpu.jax_bridge import materialize as mat
+        from torchdistx_tpu import compile_service
 
         chaos.clear()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         try:
             with tdx_config.override(
                 flight_dir=flight, fault_plan="compile@1=raise x9",
@@ -129,7 +129,7 @@ class TestTriggers:
                     )
         finally:
             chaos.clear()
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
         (path,) = _dumps(flight, "materialization_error")
         doc = json.load(open(path))
         assert not flightrec.validate(doc)
@@ -141,10 +141,10 @@ class TestTriggers:
         from torchdistx_tpu import chaos
         from torchdistx_tpu.deferred_init import deferred_init
         from torchdistx_tpu.jax_bridge import materialize_module_jax
-        from torchdistx_tpu.jax_bridge import materialize as mat
+        from torchdistx_tpu import compile_service
 
         chaos.clear()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         try:
             with tdx_config.override(
                 flight_dir=flight, fault_plan="compile@1=hang:30",
@@ -155,7 +155,7 @@ class TestTriggers:
                 )
         finally:
             chaos.clear()
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
         assert set(params) == {"weight", "bias"}
         (path,) = _dumps(flight, "compile_watchdog_kill")
         doc = json.load(open(path))

@@ -23,6 +23,7 @@ from torchdistx_tpu.jax_bridge import (
     MaterializationError,
     materialize_module_jax,
 )
+from torchdistx_tpu import compile_service
 from torchdistx_tpu.jax_bridge import materialize as mat
 
 SITES = ("lower", "compile", "execute", "cache")
@@ -46,10 +47,10 @@ class Hetero(torch.nn.Module):
 @pytest.fixture(autouse=True)
 def _no_plan_or_cache_leaks():
     chaos.clear()
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     yield
     chaos.clear()
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
 
 
 @pytest.fixture(scope="module")
@@ -117,7 +118,7 @@ class TestEverySiteEveryKind:
         if kind == "corrupt":
             # Cache corruption needs committed entries: warm first.
             _materialize(mode, cache_dir=fresh_cache)
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
             before_q = _counter("tdx.jax.cache_quarantined")
         # The deadline must beat the injected 30 s hang while clearing a
         # LEGITIMATE monolith compile on a slow 1-core CI box.
@@ -134,7 +135,7 @@ class TestEverySiteEveryKind:
                 # The monolith's only cache load precedes the execute
                 # site: the damage lands on disk unread.  The NEXT cold
                 # start must quarantine it and still heal.
-                mat._reset_cache_binding()
+                compile_service.reset_cache_binding()
                 params2, _ = _materialize(mode, cache_dir=fresh_cache)
                 _assert_bitwise(params2, baseline)
             assert _counter("tdx.jax.cache_quarantined") > before_q
@@ -180,7 +181,7 @@ class TestCacheQuarantine:
         entries = [f for f in os.listdir(fresh_cache)
                    if f.endswith("-cache")]
         assert entries
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
 
         # Damage every entry on disk (the poisoned-cache model), no
         # chaos plan involved: the quarantine guard alone must recover.
@@ -194,7 +195,7 @@ class TestCacheQuarantine:
                    if f.endswith(".corrupt")]
         assert len(corrupt) >= len(entries)  # forensics kept
         _assert_bitwise(params, baseline)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
 
         # The recompiles re-persisted clean entries: the next cold start
         # is all-hit again — the cache healed, not just survived.

@@ -21,6 +21,7 @@ import torchdistx_tpu.config as tdx_config
 from torchdistx_tpu import observe
 from torchdistx_tpu.deferred_init import deferred_init
 from torchdistx_tpu.jax_bridge import materialize_module_jax
+from torchdistx_tpu import compile_service
 from torchdistx_tpu.jax_bridge import materialize as mat
 from torchdistx_tpu.jax_bridge.compile import split_init_groups
 from torchdistx_tpu.jax_bridge.materialize import named_fake_tensors
@@ -167,13 +168,13 @@ def fresh_cache(tmp_path, monkeypatch, telemetry):
     import jax
 
     monkeypatch.setenv("TDX_CACHE_MIN_COMPILE_S", "0")
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     prev_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
     cache = tmp_path / "xla_cache"
     cache.mkdir()
     yield str(cache)
     jax.config.update("jax_compilation_cache_dir", prev_dir)
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
 
 
 def _counter_snapshot():
@@ -304,7 +305,7 @@ class TestWarmCacheTool:
         assert summary["cache_entries"] > 0
 
         for mode, want_programs in (("auto", None), ("off", 1)):
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
             with tdx_config.override(cache_dir=fresh_cache):
                 _, st = _materialize(wc._demo_model, mode, workers=4)
             outcomes = st["cache"]
@@ -358,7 +359,7 @@ class TestWarmCacheTool:
 
         # The partial cache serves what it has: off-mode (the program the
         # interrupted warm DID commit) all-hits...
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(cache_dir=fresh_cache):
             _, st = _materialize(wc._demo_model, "off", workers=2)
         assert st["cache"] == {"hit": 1}
@@ -368,7 +369,7 @@ class TestWarmCacheTool:
         summary = wc.warm(wc._demo_model, fresh_cache)
         assert summary["programs"] >= 3
         for mode in ("auto", "off"):
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
             with tdx_config.override(cache_dir=fresh_cache):
                 _, st = _materialize(wc._demo_model, mode, workers=2)
             assert list(st["cache"]) == ["hit"], (mode, st["cache"])

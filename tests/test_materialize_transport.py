@@ -25,10 +25,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 import torchdistx_tpu.config as tdx_config
-from torchdistx_tpu import observe
+from torchdistx_tpu import compile_service, observe, transport
 from torchdistx_tpu.deferred_init import deferred_init
 from torchdistx_tpu.jax_bridge import materialize as mat
-from torchdistx_tpu.jax_bridge import materialize_module_jax, transport
+from torchdistx_tpu.jax_bridge import materialize_module_jax
 
 K = 10  # layers; distinct widths defeat batching → a real multi-group split
 
@@ -49,9 +49,9 @@ class Pyramid(torch.nn.Module):
 @pytest.fixture(scope="module")
 def cache_dir(tmp_path_factory):
     d = tmp_path_factory.mktemp("transport_cache")
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     yield str(d)
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
 
 
 def _run(mode, cache_dir, *, seed=0, param_dtype=None, **kw):

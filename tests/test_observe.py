@@ -290,10 +290,10 @@ def jax_cache(tmp_path, monkeypatch, telemetry):
     hit/miss telemetry needs real cache traffic)."""
     import jax
 
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
 
     monkeypatch.setenv("TDX_CACHE_MIN_COMPILE_S", "0")
-    monkeypatch.setattr(mat, "_cache_enabled", False)
+    monkeypatch.setattr(compile_service, "_cache_enabled", False)
     prev_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
     cache = tmp_path / "xla_cache"
     cache.mkdir()
@@ -305,7 +305,7 @@ def jax_cache(tmp_path, monkeypatch, telemetry):
         cc.reset_cache()
     except Exception:
         pass
-    mat._cache_enabled = False
+    compile_service._cache_enabled = False
 
 
 class TestMaterializeTelemetry:

@@ -26,6 +26,7 @@ import torchdistx_tpu.config as tdx_config
 from torchdistx_tpu import chaos, observe
 from torchdistx_tpu.deferred_init import deferred_init
 from torchdistx_tpu.jax_bridge import materialize_module_jax
+from torchdistx_tpu import compile_service
 from torchdistx_tpu.jax_bridge import materialize as mat
 from torchdistx_tpu.registry import (
     ArtifactRegistry,
@@ -58,7 +59,7 @@ def _cache_hygiene():
     os.environ["TDX_CACHE_MIN_COMPILE_S"] = "0"
     yield
     chaos.clear()
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     os.environ.pop("TDX_CACHE_MIN_COMPILE_S", None)
 
 
@@ -77,7 +78,7 @@ def _snap():
 
 
 def _materialize(reg_dir, cache_dir, *, mode="auto", seed=0):
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     with tdx_config.override(
         cache_dir=cache_dir, registry_dir=reg_dir,
         materialize_pipeline=mode, compile_workers=2,
@@ -89,7 +90,7 @@ def _materialize(reg_dir, cache_dir, *, mode="auto", seed=0):
 
 
 def _baseline(seed=0):
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     with tdx_config.override(cache_dir=None, registry_dir=None,
                              materialize_pipeline="off"):
         m = deferred_init(Hetero)
@@ -495,7 +496,7 @@ class TestMaterializeIntegration:
     def test_registry_without_local_cache_is_inert(self, tmp_path,
                                                    counters):
         base = _baseline(seed=1)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(cache_dir=None,
                                  registry_dir=str(tmp_path / "reg")):
             m = deferred_init(Hetero)
@@ -544,7 +545,7 @@ class TestWarmCacheCLI:
         def boom(*a, **k):
             raise RuntimeError("injected compile failure")
 
-        monkeypatch.setattr(mat, "_compile_program", boom)
+        monkeypatch.setattr(compile_service, "compile_program", boom)
         with pytest.raises(SystemExit) as exc:
             wc.main(["--model", "demo",
                      "--cache-dir", str(tmp_path / "c")])
@@ -612,8 +613,7 @@ class TestTwoProcessShardedWarm:
             "from torchdistx_tpu.deferred_init import deferred_init;"
             "from torchdistx_tpu.jax_bridge import materialize_module_jax;"
             "import torchdistx_tpu.config as tdx_config;"
-            "from torchdistx_tpu.jax_bridge import materialize as mat;"
-            "from torchdistx_tpu import observe;"
+            "from torchdistx_tpu import compile_service, observe;"
             "w=[32+8*i for i in range(12)];\n"
             "class Demo(torch.nn.Module):\n"
             "    def __init__(self):\n"
@@ -627,7 +627,7 @@ class TestTwoProcessShardedWarm:
             "assert s.get('tdx.jax.compile_cache_miss', 0)==0, s;"
             "assert s.get('tdx.registry.fetch_hit', 0)=="
             "s.get('tdx.jax.compile_cache_hit', 0)>0, s;"
-            "mat._reset_cache_binding();\n"
+            "compile_service.reset_cache_binding();\n"
             "with tdx_config.override(cache_dir=None, registry_dir=None,"
             " materialize_pipeline='off'):\n"
             "    b=materialize_module_jax(deferred_init(Demo), seed=0)\n"

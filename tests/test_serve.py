@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 import torchdistx_tpu.config as tdx_config
 from torchdistx_tpu import chaos, observe
-from torchdistx_tpu.jax_bridge import materialize as mat
+from torchdistx_tpu import compile_service
 from torchdistx_tpu.models import TransformerConfig
 from torchdistx_tpu.serve import (
     Request,
@@ -285,7 +285,7 @@ def test_registry_warmed_bring_up_zero_local_compiles():
     # warmth, not the contract under test.
     old_min = os.environ.get("TDX_CACHE_MIN_COMPILE_S")
     os.environ["TDX_CACHE_MIN_COMPILE_S"] = "0"
-    mat._reset_cache_binding()
+    compile_service.reset_cache_binding()
     try:
         summary = warm_serving("llama", LLAMA, warm_cache,
                                registry_dir=reg, serve_cfg=SCFG)
@@ -296,7 +296,7 @@ def test_registry_warmed_bring_up_zero_local_compiles():
                          "chunk-8", "chunk-16", "cow", "decode",
                          "verify-2", "verify-4"}
 
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         base = {r["name"]: r["value"]
                 for r in observe.counters().snapshot()
                 if r["type"] == "counter"}
@@ -322,7 +322,7 @@ def test_registry_warmed_bring_up_zero_local_compiles():
             os.environ.pop("TDX_CACHE_MIN_COMPILE_S", None)
         else:
             os.environ["TDX_CACHE_MIN_COMPILE_S"] = old_min
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         for d in (reg, warm_cache, fresh_cache):
             shutil.rmtree(d, ignore_errors=True)
 

@@ -245,7 +245,7 @@ def _materialize_oracle(seed: int, plan_text: "str | None"):
         MaterializationError,
         materialize_module_jax,
     )
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
 
     rng = random.Random(seed)
     k = rng.randrange(9, 13)
@@ -282,7 +282,7 @@ def _materialize_oracle(seed: int, plan_text: "str | None"):
             }
         # Warm pass (also validates the fault-free pipelined run) so
         # cache-corruption faults have real entries to damage.
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(
             materialize_pipeline="auto", cache_dir=cache_dir,
             compile_workers=2,
@@ -296,7 +296,7 @@ def _materialize_oracle(seed: int, plan_text: "str | None"):
             compile_workers=2, compile_deadline_s=5.0,
             materialize_retries=2, materialize_resume_dir=resume_dir,
         ):
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
             for _attempt in range(4):  # drain / resume contract
                 try:
                     params = materialize_module_jax(module, seed=seed)
@@ -312,7 +312,7 @@ def _materialize_oracle(seed: int, plan_text: "str | None"):
                 return ("mismatch", f"{name} differs plan={plan!r}")
     finally:
         chaos.clear()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(cache_dir, ignore_errors=True)
         shutil.rmtree(resume_dir, ignore_errors=True)
     return None
@@ -442,7 +442,7 @@ def _registry_oracle(seed: int, plan_text: "str | None"):
     from torchdistx_tpu import chaos
     from torchdistx_tpu.deferred_init import deferred_init
     from torchdistx_tpu.jax_bridge import materialize_module_jax
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
 
     rng = random.Random(seed)
     k = rng.randrange(9, 13)
@@ -478,7 +478,7 @@ def _registry_oracle(seed: int, plan_text: "str | None"):
             }
         # Publish pass: fault-free, fills the registry (corrupt faults
         # need real artifacts to damage).
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(
             materialize_pipeline="auto", cache_dir=cache_a,
             registry_dir=reg_dir, compile_workers=2,
@@ -486,7 +486,7 @@ def _registry_oracle(seed: int, plan_text: "str | None"):
             materialize_module_jax(module, seed=seed)
 
         chaos.install(plan)
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         with tdx_config.override(
             materialize_pipeline="auto", cache_dir=cache_b,
             registry_dir=reg_dir, compile_workers=2,
@@ -499,7 +499,7 @@ def _registry_oracle(seed: int, plan_text: "str | None"):
                 return ("mismatch", f"{name} differs plan={plan!r}")
     finally:
         chaos.clear()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         shutil.rmtree(reg_dir, ignore_errors=True)
         shutil.rmtree(cache_a, ignore_errors=True)
         shutil.rmtree(cache_b, ignore_errors=True)
@@ -627,7 +627,7 @@ def _fleet_oracle(seed: int, plan_text: "str | None"):
 
     from torchdistx_tpu import chaos
     from torchdistx_tpu import config as tdx_config
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         FleetConfig,
@@ -740,7 +740,7 @@ def _fleet_oracle(seed: int, plan_text: "str | None"):
                             f"plan={plan!r}")
     finally:
         chaos.clear()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         if old_min is None:
             os.environ.pop("TDX_CACHE_MIN_COMPILE_S", None)
         else:
@@ -771,7 +771,7 @@ def _guardrails_oracle(seed: int, plan_text: "str | None"):
 
     from torchdistx_tpu import chaos
     from torchdistx_tpu import config as tdx_config
-    from torchdistx_tpu.jax_bridge import materialize as mat
+    from torchdistx_tpu import compile_service
     from torchdistx_tpu.models import TransformerConfig
     from torchdistx_tpu.serve import (
         FleetConfig,
@@ -864,7 +864,7 @@ def _guardrails_oracle(seed: int, plan_text: "str | None"):
                 unsettled = bool(fl.partial) or bool(fl._hedges)
     finally:
         chaos.clear()
-        mat._reset_cache_binding()
+        compile_service.reset_cache_binding()
         if old_min is None:
             os.environ.pop("TDX_CACHE_MIN_COMPILE_S", None)
         else:

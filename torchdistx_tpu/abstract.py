@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from . import transport
 from .observe import compilelog
 from .parallel.sharding import ShardingPlan
 
@@ -231,7 +232,7 @@ def materialize_parts(
     ``(run_fn, out_shardings, treedef)`` where ``run_fn()`` computes the
     selected leaves.  Callers that need to own the compile — the serving
     runtime routes replica param-init through
-    ``jax_bridge.materialize._compile_program`` so the artifact registry
+    ``compile_service.compile_program`` so the artifact registry
     and the compile-cache telemetry cover it — build on this;
     :func:`build_materialize_fn` is the plain-jit convenience on top.
 
@@ -241,7 +242,7 @@ def materialize_parts(
     ``init_dtype`` are computed/stored by the program in ``init_dtype``
     (halving the bytes moved).  The returned ``run_fn`` then delivers
     those leaves in ``init_dtype`` — the CALLER owns the on-device
-    upcast (``jax_bridge.transport.commit_outputs``; the serving
+    upcast (``transport.commit_outputs``; the serving
     bring-up in ``serve.engine.spin_up_replica`` does exactly this)."""
     fakes, treedef = jax.tree.flatten(tree, is_leaf=is_fake)
     for f in fakes:
@@ -265,8 +266,6 @@ def materialize_parts(
         )
 
     if init_dtype is not None:
-        from .jax_bridge import transport
-
         finals = [
             jnp.dtype(param_dtype) if c else jnp.dtype(f.dtype)
             for f, c in zip(fakes, cast)

@@ -50,7 +50,9 @@ Fault kinds and what they model:
 ===========  ==========================================================
 
 The materialization sites fire inside the record→compile→materialize
-pipeline (:mod:`torchdistx_tpu.jax_bridge.materialize`), keyed by the
+pipeline (``lower`` / ``cache`` / ``compile`` / ``execute`` in
+:mod:`torchdistx_tpu.compile_service`, under the engines of
+:mod:`torchdistx_tpu.jax_bridge.materialize`), keyed by the
 1-based program-group number instead of the training step (the
 monolithic engine is group 1); see docs/robustness.md.  The
 ``registry`` site fires inside the shared compile-artifact registry's

@@ -769,35 +769,6 @@ def split_init_groups(
     return out
 
 
-def cast_program_outputs(
-    init_fn: Callable[..., Tuple[jax.Array, ...]],
-    dtypes: Sequence[Optional[Any]],
-) -> Callable[..., Tuple[jax.Array, ...]]:
-    """Wrap an init program so output slot *i* is cast to ``dtypes[i]``
-    INSIDE the compiled program (None keeps the slot's traced dtype;
-    non-floating slots are never cast).  The torch-bridge cast policies
-    — ``param_dtype`` storage (``materialize._cast_outputs``) and the
-    transport layer's low-precision init fast path
-    (docs/performance.md §transport) — both build on this one
-    primitive, so the cast point, and therefore what XLA fuses it into,
-    is identical across the monolithic engine, the pipelined engine,
-    and the export path."""
-    if not any(d is not None for d in dtypes):
-        return init_fn
-    dts = tuple(dtypes)
-
-    def fn(*args):
-        outs = init_fn(*args)
-        return tuple(
-            o.astype(d)
-            if d is not None and jnp.issubdtype(o.dtype, jnp.floating)
-            else o
-            for o, d in zip(outs, dts)
-        )
-
-    return fn
-
-
 def build_init_fn(
     fakes: Sequence[FakeTensor], *, dedup: bool = True
 ) -> Callable[..., Tuple[jax.Array, ...]]:

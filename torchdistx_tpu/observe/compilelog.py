@@ -12,7 +12,7 @@ while a function is traced or lowered reports a trace of its own, some
 thousand in a serving bring-up, and only the outermost trace or lowering
 of a thread is logged: its seconds cover the rest).
 The compile service registers both beside its cache-outcome listener
-(``jax_bridge/materialize._install_cache_listener``), and so does
+(``compile_service._install_cache_listener``), and so does
 ``abstract.deferred_init``, the first step of the jax-native path, which
 never loads the compile service.  The listener is process-wide: a caller's own
 ``jax.jit`` is seen like the program's.  It costs nothing outside a

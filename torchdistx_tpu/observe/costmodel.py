@@ -13,7 +13,7 @@ host→device bandwidth so ``materialize_gbps`` can be reported as a
 utilization fraction (``tdx.jax.link_utilization``) instead of a number
 with no denominator.
 
-Consumers: ``jax_bridge.materialize._compile_program`` attaches
+Consumers: ``compile_service.compile_program`` attaches
 :func:`program_costs` to every ``jax.compile`` span and to the artifact
 registry manifest; ``parallel.train._instrument_step`` feeds
 :class:`~.step.StepMeter` compiler FLOPs so the training loop publishes

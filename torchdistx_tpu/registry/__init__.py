@@ -5,9 +5,10 @@ across a fleet (:mod:`.store`), plus the sharded multi-host warm
 scheduler that partitions compile work across a pod and fills every
 host's local cache from the registry (:mod:`.scheduler`).
 
-Activated by ``TDX_REGISTRY_DIR`` (:mod:`torchdistx_tpu.config`); both
-materialization engines then consult the registry before compiling and
-publish after (:mod:`..jax_bridge.materialize`).  All registry trouble —
+Activated by ``TDX_REGISTRY_DIR`` (:mod:`torchdistx_tpu.config`); every
+program compiled through :func:`..compile_service.compile_program` —
+both materialization engines, the serving runtime — then consults the
+registry before compiling and publishes after.  All registry trouble —
 flaky shared filesystems, corrupt entries, injected ``registry`` chaos
 faults — degrades to a local compile, never an error.
 """

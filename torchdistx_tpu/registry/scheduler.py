@@ -119,7 +119,7 @@ def _active_init_dtype():
     under the same config will request (docs/performance.md
     §transport)."""
     from .. import config as tdx_config
-    from ..jax_bridge import transport
+    from .. import transport
 
     return transport.resolve_init_dtype(
         tdx_config.get().materialize_init_dtype
@@ -181,6 +181,7 @@ def warm_sharded(factory, cache_dir: str, *,
     import jax
     import torch
 
+    from .. import compile_service, transport
     from .. import config as tdx_config
     from ..deferred_init import deferred_init
     from ..jax_bridge import materialize as mat
@@ -225,8 +226,6 @@ def warm_sharded(factory, cache_dir: str, *,
             fn = mat._cast_outputs(
                 fn, param_dtype, [mask[i] for i in spec.idxs]
             )
-        from ..jax_bridge import transport
-
         fn = transport.wrap_storage(
             fn,
             mat._transport_plan(fake_list, spec.idxs, out_shardings,
@@ -236,11 +235,11 @@ def warm_sharded(factory, cache_dir: str, *,
             tuple(out_shardings[i] for i in spec.idxs)
             if out_shardings is not None else None
         )
-        # _compile_program does the whole registry dance when program_fp
+        # compile_program does the whole registry dance when program_fp
         # is set: fetch→verify→install before the compile, publish after
         # — the same path the materialization engines run, including the
         # TDX_COMPILE_DEADLINE_S watchdog over compiles AND registry IO.
-        _, _tl, _tc, cache_outcome, _costs = mat._compile_program(
+        _, _tl, _tc, cache_outcome, _costs = compile_service.compile_program(
             fn, key, osh, label=spec.label,
             program_fp=spec.program_fp if reg is not None else None,
             deadline=tdx_config.get().compile_deadline_s or None,
@@ -282,8 +281,8 @@ def warm_sharded(factory, cache_dir: str, *,
     with tdx_config.override(
         cache_dir=cache_dir, registry_dir=registry_dir or None
     ):
-        mat._reset_cache_binding()  # bind THIS cache dir even mid-process
-        mat._maybe_enable_cache()
+        compile_service.reset_cache_binding()  # bind THIS cache dir even mid-process
+        compile_service.bind_cache()
         try:
             # The whole-model program first (export-path parity; also the
             # interrupted-warm contract: the monolith commits before any
@@ -354,7 +353,7 @@ def warm_sharded(factory, cache_dir: str, *,
                 if not progressed:
                     time.sleep(min(poll_s, max(0.0, steal_at - now)))
         finally:
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
 
     outcomes: Dict[str, int] = {}
     for r in reports:

@@ -106,14 +106,11 @@ def env_fingerprint() -> Dict[str, str]:
     # The accepted init compiler options are part of the executable's
     # identity: an artifact compiled WITH xla_allow_excess_precision=False
     # must not serve a host whose backend rejected the knob.
-    try:
-        from ..jax_bridge.materialize import _compiler_options
+    from ..compile_service import compiler_options  # lazy: it imports us
 
-        info["compiler_options"] = json.dumps(
-            _compiler_options() or {}, sort_keys=True
-        )
-    except Exception:
-        info["compiler_options"] = "unknown"
+    info["compiler_options"] = json.dumps(
+        compiler_options() or {}, sort_keys=True
+    )
     return info
 
 
@@ -296,7 +293,7 @@ class ArtifactRegistry:
         for ck in cache_keys:
             # jax's LRUCache stores `<key>-cache`; other CacheInterface
             # impls store the bare key — tolerate both, exactly like the
-            # PR 5 quarantine helper (materialize._quarantine_cache_entry).
+            # PR 5 quarantine helper (compile_service._quarantine_cache_entry).
             for name in (f"{ck}-cache", ck):
                 try:
                     with open(os.path.join(cache_dir, name), "rb") as f:

@@ -427,7 +427,7 @@ def restore_resharded(
     the in-flight elastic path when a relaunch lands on a different mesh.
 
     Small leaves (≤ the chunk budget) ride
-    :func:`~..jax_bridge.transport.batched_device_put` — one dispatch per
+    :func:`~..transport.batched_device_put` — one dispatch per
     distinct target sharding; larger leaves are assembled shard-by-shard
     on device from budget-bounded slab reads, so no host ever stages a
     full unsharded leaf.  ``verify=True`` re-reads the source and
@@ -457,7 +457,7 @@ def restore_resharded(
         nonlocal small, small_bytes
         if not small:
             return
-        from ..jax_bridge import transport  # lazy: torch-free import path
+        from .. import transport  # lazy: it imports jax, as this module does
 
         values, _n = transport.batched_device_put(
             [b for _slot, b, _sh, _nb in small],

@@ -57,7 +57,7 @@ active lane the same way.
 **Replica bring-up** (:func:`spin_up_replica`) is the deferred-init
 story end-to-end: ``abstract.deferred_init`` fakes the model (zero
 storage), the init program is compiled through
-``jax_bridge._compile_program`` — so a registry-warmed replica FETCHES
+``compile_service.compile_program`` — so a registry-warmed replica FETCHES
 it rather than compiling — and executes straight into (sharded) device
 memory; the prefill/decode programs ride the same path.  With
 ``TDX_REGISTRY_DIR`` pre-warmed (``tools/warm_cache.py --decode``), a
@@ -94,7 +94,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import chaos, observe
+from .. import chaos, compile_service, observe, transport
 from ..observe import reqledger
 from ..models import PRESETS, TransformerConfig
 from ..ops.paged_attention import kv_blocks_walked
@@ -233,9 +233,7 @@ class ServeEngine:
         self._next_seq = 1
         self._t0: Optional[float] = None
         self._tokens_out = 0
-        from ..jax_bridge.materialize import _retryable_errors
-
-        self._retryable = _retryable_errors()
+        self._retryable = compile_service.retryable_errors()
         from ..observe import slo as _slo
 
         # Fleet replicas pass a per-replica ``slo_name`` so the /slo
@@ -1378,16 +1376,14 @@ def spin_up_replica(
                 # prefill/decode signatures expect (donated staging buffers,
                 # same retry contract as the materialization engines).
                 from .. import config as _tdx_config
-                from ..jax_bridge import transport as _transport
-                from ..jax_bridge.materialize import _retryable_errors
 
                 cfg_eff = _tdx_config.get()
-                values, _donated = _transport.commit_outputs(
+                values, _donated = transport.commit_outputs(
                     values, init.tplan,
                     donate=cfg_eff.materialize_donate,
                     producer=lambda: compiled(),
                     retries=max(0, cfg_eff.materialize_retries),
-                    retryable=_retryable_errors(),
+                    retryable=compile_service.retryable_errors(),
                 )
             params = jax.tree.unflatten(init.treedef, list(values))
             jax.block_until_ready(values)

@@ -34,7 +34,7 @@ byte-identical programs:
   a shared one.
 
 Every compile goes through
-:func:`..jax_bridge.materialize._compile_program`, so the pod-scale
+:func:`..compile_service.compile_program`, so the pod-scale
 artifact registry (``TDX_REGISTRY_DIR``), the persistent compile cache,
 the exact hit/miss counters, the compile watchdog, and the chaos
 ``lower``/``compile``/``cache``/``registry`` sites all cover serving
@@ -65,7 +65,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from .. import abstract, chaos, observe
+from .. import abstract, chaos, compile_service, observe, transport
 from .. import config as tdx_config
 from ..models import TransformerConfig, make_gpt2, make_jamba, make_llama
 from ..models import jamba
@@ -871,7 +871,7 @@ class ServeProgramSpec:
     # init only: the low-precision transport plan when
     # TDX_MATERIALIZE_INIT_DTYPE is armed — the compiled init program
     # then delivers eligible params in the init dtype and the bring-up
-    # upcasts them on device (jax_bridge.transport.commit_outputs).
+    # upcasts them on device (transport.commit_outputs).
     tplan: Any = None
 
 
@@ -915,7 +915,7 @@ def _abstract_params(family, cfg, *, seed, sample_len, param_dtype,
     signature matches the arrays the init program will actually
     deliver).  With ``init_dtype`` the init program stores eligible
     params in the init dtype and the returned
-    :class:`..jax_bridge.transport.TransportPlan` describes the
+    :class:`..transport.TransportPlan` describes the
     on-device upcast the bring-up must run — the ShapeDtypeStructs keep
     the POST-upcast contract dtypes, which is what the prefill/decode
     programs consume."""
@@ -944,8 +944,6 @@ def _abstract_params(family, cfg, *, seed, sample_len, param_dtype,
     params_abs = jax.tree.unflatten(treedef, sds)
     tplan = None
     if init_dtype is not None:
-        from ..jax_bridge import transport
-
         tplan = transport.plan_transport(
             [s.dtype for s in sds], elig, init_dtype, out_shardings
         )
@@ -971,8 +969,6 @@ def serve_program_specs(
     demand — same builders, same fingerprints, so a warmed registry
     makes bring-up all-hit."""
     scfg = (serve_cfg or ServeConfig()).resolve(cfg)
-    from ..jax_bridge import transport
-
     init_dtype = transport.resolve_init_dtype(
         tdx_config.get().materialize_init_dtype
     )
@@ -1103,19 +1099,18 @@ def serve_program_specs(
 
 
 def compile_serving_program(spec: ServeProgramSpec):
-    """Compile one serving program through the materialization engines'
-    `_compile_program` — persistent cache, artifact registry
-    fetch→verify→install / publish, exact cache-outcome counters, chaos
-    sites, and the ``TDX_COMPILE_DEADLINE_S`` watchdog all included.
+    """Compile one serving program through the compile service
+    (:func:`..compile_service.compile_program`) — persistent cache,
+    artifact registry fetch→verify→install / publish, exact
+    cache-outcome counters, chaos sites, and the
+    ``TDX_COMPILE_DEADLINE_S`` watchdog all included.
     Returns ``(compiled, cache_outcome)``."""
-    from ..jax_bridge import materialize as mat
-
-    mat._maybe_enable_cache()
+    compile_service.bind_cache()
     cfg = tdx_config.get()
     with observe.span(
         "serve.compile", category="serve", program=spec.name
     ) as sp:
-        compiled, t_lower, t_compile, outcome, costs = mat._compile_program(
+        compiled, t_lower, t_compile, outcome, costs = compile_service.compile_program(
             spec.fn, tuple(spec.args), spec.out_shardings,
             fault_plan=chaos.active_plan(),
             deadline=cfg.compile_deadline_s or None,
@@ -1152,7 +1147,6 @@ def warm_serving(
     same shape performs zero local compiles.  Returns the same summary
     shape as :func:`..registry.warm_sharded` (per-program outcome
     reports; ``unwarmed`` non-empty on any failure)."""
-    from ..jax_bridge import materialize as mat
     from ..registry.scheduler import ProgramReport
 
     t0 = time.perf_counter()
@@ -1161,8 +1155,8 @@ def warm_serving(
     with tdx_config.override(
         cache_dir=cache_dir, registry_dir=registry_dir or None
     ):
-        mat._reset_cache_binding()
-        mat._maybe_enable_cache()
+        compile_service.reset_cache_binding()
+        compile_service.bind_cache()
         try:
             specs = serve_program_specs(
                 family, cfg, serve_cfg, seed=seed, param_dtype=param_dtype,
@@ -1201,7 +1195,7 @@ def warm_serving(
                     seconds=time.perf_counter() - t, cache=outcome,
                 ))
         finally:
-            mat._reset_cache_binding()
+            compile_service.reset_cache_binding()
 
     outcomes: Dict[str, int] = {}
     for r in reports:

@@ -42,16 +42,17 @@ from __future__ import annotations
 
 import threading
 import warnings
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .. import observe
+from . import observe
 
 __all__ = [
     "TransportPlan",
     "batched_device_put",
+    "cast_program_outputs",
     "commit_outputs",
     "plan_transport",
     "resolve_init_dtype",
@@ -147,16 +148,43 @@ def plan_transport(final_dtypes, cast_mask, init_dtype,
     return TransportPlan(final, storage, out_shardings)
 
 
+def cast_program_outputs(
+    init_fn: Callable[..., Tuple[jax.Array, ...]],
+    dtypes: Sequence[Optional[Any]],
+) -> Callable[..., Tuple[jax.Array, ...]]:
+    """Wrap an init program so output slot *i* is cast to ``dtypes[i]``
+    INSIDE the compiled program (None keeps the slot's traced dtype;
+    non-floating slots are never cast).  The torch-bridge cast policies
+    — ``param_dtype`` storage (``jax_bridge.materialize._cast_outputs``)
+    and the low-precision init fast path (:func:`wrap_storage`,
+    docs/performance.md §transport) — both build on this one
+    primitive, so the cast point, and therefore what XLA fuses it into,
+    is identical across the monolithic engine, the pipelined engine,
+    and the export path."""
+    if not any(d is not None for d in dtypes):
+        return init_fn
+    dts = tuple(dtypes)
+
+    def fn(*args):
+        outs = init_fn(*args)
+        return tuple(
+            o.astype(d)
+            if d is not None and jnp.issubdtype(o.dtype, jnp.floating)
+            else o
+            for o, d in zip(outs, dts)
+        )
+
+    return fn
+
+
 def wrap_storage(init_fn: Callable, plan: Optional[TransportPlan]):
     """Apply the plan's storage cast to an init program (a no-op wrapper
     for a None plan) — the per-slot ``astype`` lands INSIDE the compiled
-    program via :func:`..compile.cast_program_outputs`, so XLA fuses it
+    program via :func:`cast_program_outputs`, so XLA fuses it
     into the producing ops and full-precision values never reach the
     output buffers."""
     if plan is None:
         return init_fn
-    from .compile import cast_program_outputs
-
     return cast_program_outputs(init_fn, plan.storage)
 
 

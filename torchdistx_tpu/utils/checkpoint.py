@@ -47,13 +47,6 @@ import jax
 
 from .. import observe
 
-try:
-    import orbax.checkpoint as ocp
-
-    _HAS_ORBAX = True
-except Exception:  # pragma: no cover
-    _HAS_ORBAX = False
-
 MANIFEST_NAME = "tdx_manifest.json"
 COMMIT_MARKER = "TDX_COMMITTED"
 QUARANTINE_SUFFIX = ".corrupt"
@@ -79,9 +72,16 @@ class CheckpointCorruptError(RuntimeError):
     marker).  Carries the human-readable reason in ``args[0]``."""
 
 
-def _require_orbax():
-    if not _HAS_ORBAX:
-        raise RuntimeError("orbax-checkpoint is not installed.")
+def _orbax():
+    """``orbax.checkpoint``, imported where a payload is written or read
+    and nowhere else: it pulls in tensorstore and ``google.cloud.logging``
+    (seconds of import), and the manifest, marker and verification code
+    that serving and resharding use need none of it."""
+    try:
+        import orbax.checkpoint as ocp
+    except Exception as e:  # pragma: no cover
+        raise RuntimeError("orbax-checkpoint is not installed.") from e
+    return ocp
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +334,7 @@ def save_checkpoint(
     """Save a pytree of (possibly sharded) jax.Arrays, then write the
     integrity manifest + commit marker (``manifest=False`` skips them —
     the pre-manifest layout, kept for interop)."""
-    _require_orbax()
+    ocp = _orbax()
     path = Path(path).absolute()
     with observe.span("ckpt.save", category="ckpt", path=str(path)):
         ckptr = ocp.StandardCheckpointer()
@@ -359,7 +359,7 @@ class AsyncCheckpointSaver:
     """
 
     def __init__(self, *, manifest: bool = True) -> None:
-        _require_orbax()
+        ocp = _orbax()
         self._ckptr = ocp.AsyncCheckpointer(ocp.StandardCheckpointHandler())
         self._manifest = manifest
         # (path, leaf tree, topology) saved by orbax but not yet
@@ -369,7 +369,9 @@ class AsyncCheckpointSaver:
 
     def save(self, path: "str | Path", state: Any, *, force: bool = True) -> None:
         path = Path(path).absolute()
-        self._ckptr.save(path, args=ocp.args.StandardSave(state), force=force)
+        self._ckptr.save(
+            path, args=_orbax().args.StandardSave(state), force=force
+        )
         if self._manifest:
             self._pending.append((path, _leaf_tree(state), state_topology(state)))
 
@@ -405,7 +407,7 @@ def restore_checkpoint(
     ``verify=True`` integrity-checks the manifest first and raises
     :class:`CheckpointCorruptError` instead of deserializing a damaged
     payload (``run_elastic`` does this and falls back to an older step)."""
-    _require_orbax()
+    ocp = _orbax()
     path = Path(path).absolute()
     if verify:
         ok, reason = verify_checkpoint(path)
