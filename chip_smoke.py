@@ -136,6 +136,7 @@ def serve_stage(cfg, serve_cfg, *, mesh=None, plan=None):
     ``(engine, requests_by_rid)``; the caller checks the results."""
     import jax.numpy as jnp
 
+    from torchdistx_tpu.observe.costmodel import program_costs
     from torchdistx_tpu.serve import spin_up_replica
 
     eng = spin_up_replica(
@@ -144,6 +145,15 @@ def serve_stage(cfg, serve_cfg, *, mesh=None, plan=None):
     )
     log(f"  bring_up_seconds={eng.bring_up_seconds:.1f} "
         f"bring_up_outcomes={json.dumps(eng.bring_up_outcomes)}")
+    # Every program consumes the pools and returns them in place: what
+    # the compiler aliased, beside one device's share of both pools.
+    pools = (eng.k_pages, eng.v_pages, *eng.state)
+    held = sum(a.addressable_shards[0].data.nbytes for a in pools)
+    alias = {name: int(program_costs(prog)["alias_bytes"])
+             for name, prog in sorted(eng._programs.items())}
+    log(f"  pool_bytes_a_device={held} xla_alias_bytes={json.dumps(alias)}")
+    short = sorted(n for n, b in alias.items() if b < held)
+    check(not short, f"programs that do not alias their pools: {short}")
     reqs = {}
     for wave in serve_requests(cfg.vocab_size, serve_cfg.page_size):
         eng.run(wave)

@@ -35,3 +35,11 @@ def pytest_configure(config):
         "slow: multi-second cases (long hang injection) excluded from the "
         "tier-1 run's -m 'not slow'; `make chaos-test` includes them",
     )
+
+    # A donated buffer that the lowering cannot alias to an output is an
+    # error, as it is in ``serve.programs.compile_serving_program`` itself
+    # (ROADMAP S1): a test that jits a serving program's body on its own
+    # must not pass over the warning either.  (``transport``'s cast
+    # program ignores it locally, by design.)
+    config.addinivalue_line(
+        "filterwarnings", "error:Some donated buffers were not usable")

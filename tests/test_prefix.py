@@ -548,16 +548,18 @@ def test_chaos_fault_between_chunks_requeues_without_leaks(engine):
     chaos.install(f"serve@{engine._step_no + 2}=raise:chunk")
     try:
         before = observe.counter("tdx.serve.preempted_requests").value
+        rebuilds = observe.counter("tdx.serve.pool_rebuilds").value
         out = engine.run(reqs)
         assert not chaos.active_plan().pending()
         assert (observe.counter("tdx.serve.preempted_requests").value
                 > before)
+        assert observe.counter("tdx.serve.pool_rebuilds").value == rebuilds
     finally:
         chaos.clear()
         observe.enable(None)
     _check_oracle(engine, reqs, out)
     # The shared preamble survived the fault path un-corrupted and
-    # un-freed.
+    # un-freed (the fault fired between programs: the pools were whole).
     assert tree_pages <= set(engine.prefix.pages())
     engine.drain()
     assert engine.kv.pages_in_use == 0

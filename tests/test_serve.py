@@ -161,6 +161,7 @@ def test_chaos_serve_fault_requeues_and_converges(llama_params,
     chaos.install("serve@2=raise;serve@4=slow:0.01")
     try:
         before = observe.counter("tdx.serve.preempted_requests").value
+        rebuilds = observe.counter("tdx.serve.pool_rebuilds").value
         reqs = [
             Request("x", [1, 2, 3], max_new_tokens=5),
             Request("y", [9, 8, 7, 6], max_new_tokens=4),
@@ -169,6 +170,9 @@ def test_chaos_serve_fault_requeues_and_converges(llama_params,
         assert observe.counter("tdx.serve.preempted_requests").value > before
         injected = chaos.active_plan()
         assert not injected.pending(), "both faults should have fired"
+        # The site fires between programs: the pools were whole, and a
+        # fault that finds them whole rebuilds nothing.
+        assert observe.counter("tdx.serve.pool_rebuilds").value == rebuilds
         _check_oracle(eng, reqs, out)
         # The replayed prefix of a requeued request must not stream
         # twice: on_token sees each position exactly once.
@@ -465,11 +469,13 @@ def test_chaos_raise_verify_requeues_and_converges(llama_params,
     chaos.install(f"serve@{eng._step_no + 3}=raise:verify")
     try:
         before = observe.counter("tdx.serve.preempted_requests").value
+        rebuilds = observe.counter("tdx.serve.pool_rebuilds").value
         reqs = [Request("vf-a", [7] * 8, max_new_tokens=6),
                 Request("vf-b", [9, 8, 7, 6], max_new_tokens=4)]
         out = eng.run(reqs)
         assert not chaos.active_plan().pending(), "the fault never fired"
         assert observe.counter("tdx.serve.preempted_requests").value > before
+        assert observe.counter("tdx.serve.pool_rebuilds").value == rebuilds
         _check_oracle(eng, reqs, out)
     finally:
         chaos.clear()

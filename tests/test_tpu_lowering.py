@@ -200,3 +200,108 @@ def test_paged_attention_compiles_for_v5e(one_chip, B, H, KV, D, page, maxp,
         arg((512, KV, page, D), dtype), arg((B,), jnp.int32),
         arg((B, maxp), jnp.int32)).compile()
     assert "tdx_paged_attention_decode" in compiled.as_text()
+
+
+# -- the serving programs, whole, at the benchmark's cells' widths ------------
+#
+# Every program that takes the pools consumes them and returns them in
+# the same buffers (ROADMAP S1, PR 33).  An argument that is not donated
+# and that the layer loop writes is copied once, whole, at the program's
+# entry: 2.0 GB each for the Mistral cells' pools, 5.6 ms a copy, two a
+# call.  What the chip's compiler makes of the donation shows in the
+# compiled module: an ``input_output_alias`` entry for every consumed
+# argument, and no ``copy`` of a pool's or a state's shape.
+
+_CELLS = {
+    "mistral7b-chat-backlog": ("mistral-7b-v0.3-d12", "chat-backlog"),
+    "mistral7b-doc-prefill-busy": ("mistral-7b-v0.3-d12", "doc-prefill-busy"),
+    "jamba2-3b-chat-backlog": ("jamba2-3b", "chat-backlog-wide"),
+}
+
+
+def _cell_specs(cell):
+    """The cell's serving programs as its replica compiles them
+    (``benchmark/kinds/serve*.py``), by name."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import adapters, configs
+
+    config, mix = _CELLS[cell]
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic", mix + ".json")) as f:
+        engine = json.load(f)["engine"]
+    if cfg["family"] == "jamba":
+        from benchmark.families import jamba as fam
+
+        tcfg = fam.transformer_config(cfg, fam.dims(cfg))
+    else:
+        tcfg = adapters.transformer_config(cfg, configs.dims(cfg))
+    return {s.name: s for s in serve_program_specs(
+        cfg["family"], tcfg, adapters.serve_config(cfg, engine),
+        param_dtype=jnp.bfloat16, include_init=False)}
+
+
+def _hlo_shape(sds):
+    dt = {"bfloat16": "bf16", "float32": "f32"}[str(jnp.dtype(sds.dtype))]
+    return f"{dt}[{','.join(map(str, sds.shape))}]"
+
+
+_ALIAS_CASES = [
+    ("mistral7b-chat-backlog", "decode", ()),
+    ("mistral7b-chat-backlog", "prefill-128", ()),
+    ("mistral7b-chat-backlog", "chunk-512", ()),
+    ("mistral7b-chat-backlog", "cow", ()),
+    ("mistral7b-chat-backlog", "verify-4", ()),
+    ("mistral7b-doc-prefill-busy", "chunk-2048", ()),
+    ("jamba2-3b-chat-backlog", "decode", ()),
+    # The one-sequence programs of the hybrid stack still re-lay the conv
+    # tail (0.1 GB) for their lane slice, once into the loop's layout and
+    # once back: there before donation, and no entry copy (PERF.md §7).
+    # ``layout_copies``: the positions in ``args`` this is allowed for.
+    ("jamba2-3b-chat-backlog", "prefill-128", (4,)),
+    ("jamba2-3b-chat-backlog", "chunk-256", (4,)),
+]
+
+
+@pytest.mark.parametrize("cell,program,layout_copies", _ALIAS_CASES,
+                         ids=[f"{c}-{p}" for c, p, _ in _ALIAS_CASES])
+def test_serving_program_aliases_its_pools_on_v5e(one_chip, monkeypatch, cell,
+                                                  program, layout_copies):
+    import dataclasses
+    import re
+
+    from torchdistx_tpu.observe.costmodel import program_costs
+    from torchdistx_tpu.serve.programs import compile_serving_program
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = _cell_specs(cell)[program]
+    spec = dataclasses.replace(spec, args=jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        spec.args))
+    compiled, _ = compile_serving_program(spec)
+    text = compiled.as_text()
+    if program == "decode":
+        assert "tdx_paged_attention_decode" in text
+    # Parameters are numbered over the flattened arguments: the consumed
+    # ones follow the parameter tree's leaves.
+    first = len(jax.tree.leaves(spec.args[:spec.consumes[0]]))
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}", text.split("input_output_alias={", 1)[1]
+        .split("entry_computation_layout", 1)[0])}
+    assert aliased == set(range(first, first + len(spec.consumes)))
+    carried = [spec.args[i] for i in spec.consumes]
+    assert program_costs(compiled)["alias_bytes"] == sum(
+        a.size * jnp.dtype(a.dtype).itemsize for a in carried)
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if re.search(r"= \S+ copy\(", line)]
+    for i, a in zip(spec.consumes, carried):
+        if i in layout_copies:
+            continue
+        assert not [c for c in copies if f"= {_hlo_shape(a)}" in c], (
+            program, _hlo_shape(a))

@@ -56,9 +56,13 @@ def program_costs(compiled) -> Optional[Dict[str, float]]:
     * ``bytes_accessed`` — modeled HBM traffic;
     * ``argument_bytes`` / ``output_bytes`` / ``temp_bytes`` /
       ``generated_code_bytes`` — the memory_analysis footprint split;
+    * ``alias_bytes`` — the bytes of outputs that live in the buffer of
+      a donated argument (0 for a program that donates nothing): the
+      record that a serving program returns its pools in place;
     * ``peak_bytes`` — the device high-water estimate: XLA's own
       ``peak_memory_in_bytes`` where available, else the
-      arguments+outputs+temps sum (an upper bound on live buffers).
+      arguments+outputs+temps sum less the aliased bytes, which that sum
+      counts twice (an upper bound on live buffers).
     """
     out: Dict[str, float] = {}
     try:
@@ -81,6 +85,7 @@ def program_costs(compiled) -> Optional[Dict[str, float]]:
             ("output_size_in_bytes", "output_bytes"),
             ("temp_size_in_bytes", "temp_bytes"),
             ("generated_code_size_in_bytes", "generated_code_bytes"),
+            ("alias_size_in_bytes", "alias_bytes"),
         ):
             v = getattr(ma, attr, None)
             if isinstance(v, (int, float)) and v >= 0:
@@ -89,7 +94,8 @@ def program_costs(compiled) -> Optional[Dict[str, float]]:
         if not isinstance(peak, (int, float)) or peak <= 0:
             parts = [out.get(k) for k in
                      ("argument_bytes", "output_bytes", "temp_bytes")]
-            peak = sum(p for p in parts if p) if any(parts) else None
+            peak = (sum(p for p in parts if p) - out.get("alias_bytes", 0.0)
+                    if any(parts) else None)
         if peak:
             out["peak_bytes"] = float(peak)
     return out or None

@@ -192,15 +192,7 @@ class ServeEngine:
         self._draining = False
         self.kv = PagedKVCache(self.scfg.kv_config(cfg))
         self.prefix = PrefixCache(self.kv)
-        self.k_pages, self.v_pages = init_pools(
-            self.scfg.kv_config(cfg), cfg.dtype,
-            pool_sharding(mesh, cfg.kv_heads))
-        # The recurrent layer group's arrays (a hybrid stack: ssm, conv;
-        # else none), threaded through every model program behind the
-        # pools.  A lane's slot is the lane's index.
-        st = self.kv.cfg.state
-        self.state: tuple = () if st is None else init_state(
-            st, cfg.dtype, state_sharding(mesh, st.d_inner))
+        self._init_pools()
         # Chunk-boundary chaos faults (``serve@N=raise:chunk``) are
         # deferred here by step() and fired BETWEEN prefill chunks —
         # the mid-chunked-prefill fault the failure matrix pins.
@@ -264,6 +256,34 @@ class ServeEngine:
             self.k_pages.shape)
         self._kernel_pool = (page, kv_local, head_dim, self.k_pages.dtype)
         self._n_admitted = 0  # lifetime; serve.admit reports a tick's share
+
+    # -- pools ----------------------------------------------------------------
+    #
+    # The engine OWNS the pools and the recurrent state.  Every program
+    # that takes them consumes them (they are donated) and returns them in
+    # the same buffers, so each call site rebinds ``self.k_pages``,
+    # ``self.v_pages`` and ``self.state`` from the call's outputs in the
+    # statement that makes the call, and nothing else may keep a reference
+    # to an array across a call: it would be a deleted array afterwards
+    # (its shape, dtype and sharding stay readable).
+
+    def _init_pools(self) -> None:
+        """Allocate the zeroed pools and, for a hybrid stack, the
+        recurrent layer group's arrays (``self.state``: ssm, conv; else
+        none), which every model program threads behind the pools.  A
+        lane's slot is the lane's index."""
+        cfg, kv = self.cfg, self.kv.cfg
+        self.k_pages, self.v_pages = init_pools(
+            kv, cfg.dtype, pool_sharding(self.mesh, cfg.kv_heads))
+        self.state: tuple = () if kv.state is None else init_state(
+            kv.state, cfg.dtype, state_sharding(self.mesh, kv.state.d_inner))
+
+    def _pools_lost(self) -> bool:
+        """Whether a call that consumed the pools or the state failed
+        after taking them: the arrays the engine still names are deleted."""
+        return any(a.is_deleted()
+                   for a in (self.k_pages, self.v_pages, *self.state)
+                   if a is not None)
 
     # -- program cache ------------------------------------------------------
 
@@ -547,7 +567,11 @@ class ServeEngine:
         """One engine tick: chaos site → chunked-prefill advance →
         admission (+prefill) → one batched decode step → retirement.  A
         retryable runtime fault mid-batch requeues every active lane
-        (recompute preemption)."""
+        (recompute preemption).  A fault raised by a program call that
+        had already consumed the pools leaves them deleted: they are
+        allocated anew and the prefix cache, whose pages' content went
+        with them, is dropped; the requeued lanes recompute over the new
+        pools (docs/serving.md failure matrix)."""
         self._step_no += 1
         if self._t0 is None:
             self._t0 = time.perf_counter()
@@ -581,8 +605,10 @@ class ServeEngine:
                     "requests", self._step_no, type(e).__name__,
                     str(e)[:120], len(self.active),
                 )
+                pools_lost = self._pools_lost()
                 observe.instant("serve.fault", category="serve",
-                                step=self._step_no, error=type(e).__name__)
+                                step=self._step_no, error=type(e).__name__,
+                                pools_lost=pools_lost)
                 # Survived — but the post-mortem must not depend on the
                 # survival: persist the ring before the requeue rewrites
                 # the engine state (no-op without TDX_FLIGHT_DIR).
@@ -593,6 +619,16 @@ class ServeEngine:
                 )
                 for slot in list(self.active):
                     self._preempt(slot, reason="fault")
+                if pools_lost:
+                    # Every lane is back in the queue and holds no page;
+                    # what still does is the prefix cache, and what its
+                    # pages held is gone; the allocator starts over with
+                    # the pools (a state slot's first call starts it from
+                    # zero whatever it held).
+                    self.prefix.clear()
+                    self.kv.reset()
+                    self._init_pools()
+                    observe.counter("tdx.serve.pool_rebuilds").inc()
         self._gauges()
 
     # -- admission / prefill ------------------------------------------------

@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import hashlib
 import time
+import warnings
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -836,9 +837,10 @@ def build_cow_fn() -> Callable:
     ``(k_pages, v_pages, src [1], dst [1]) -> (k_pages, v_pages)`` —
     clone page ``src`` into ``dst`` across every layer, K and V, so a
     grower about to write into a shared page writes into its private
-    copy instead.  Pure pool-to-pool, no params.  The pools are NOT
-    donated (no serving program donates them: ROADMAP S1), so a call
-    copies both pools to write one page of each."""
+    copy instead.  Pure pool-to-pool, no params.  Like every program
+    that takes the pools it consumes them (:class:`ServeProgramSpec`
+    ``consumes``) and returns them in the same buffers: a call writes
+    one page of each pool and moves nothing else."""
 
     @_program_name("tdx_serve_cow")
     def cow_fn(k_pages, v_pages, src, dst):
@@ -859,7 +861,15 @@ class ServeProgramSpec:
     """One compilable serving program: the function, its ABSTRACT
     arguments (lowerable without allocating a single real array — the
     warm tool never touches device memory), the output shardings, and
-    the registry fingerprint."""
+    the registry fingerprint.
+
+    ``consumes`` are the positions in ``args`` of the arrays the program
+    CONSUMES: the two pools and, for a hybrid stack, the two state
+    arrays.  They are donated to the compiled program
+    (:func:`compile_serving_program`), which returns each in the buffer
+    it came in: after a call the arrays passed in are deleted and the
+    caller owns the outputs instead (docs/serving.md §Who owns the
+    pools).  ``init`` consumes nothing."""
 
     name: str                      # "init" | "decode" | "prefill-<S>"
     fn: Callable
@@ -867,6 +877,7 @@ class ServeProgramSpec:
     out_shardings: Optional[tuple]
     program_fp: str
     init_options: bool             # init compiler effort vs serving default
+    consumes: Tuple[int, ...] = ()  # positions in args; donated
     treedef: Any = None            # init only: unflatten spec for params
     # init only: the low-precision transport plan when
     # TDX_MATERIALIZE_INIT_DTYPE is armed — the compiled init program
@@ -892,10 +903,11 @@ def _fp(kind: str, family: str, cfg: TransformerConfig,
         scfg.max_batch, scfg.page_size, scfg.n_pages,
         scfg.max_pages_per_seq, scfg.prefill_buckets,
     )
-    # v3: the pools are the layer scan's carry, addressed flat at
-    # layer*P + page — same key material, other compiled bytes, so
-    # artifacts published under v2 must not be served.
-    h = hashlib.sha1(b"tdx-serve-program-fp-v3")
+    # v4: the pools and the recurrent state are donated and aliased to
+    # the outputs (v3: the pools became the layer scan's carry) — same
+    # key material, other compiled bytes, so artifacts published under
+    # v3 must not be served.
+    h = hashlib.sha1(b"tdx-serve-program-fp-v4")
     h.update(repr((kind, family, cfg, shape, extra)).encode())
     return h.hexdigest()
 
@@ -984,23 +996,23 @@ def serve_program_specs(
     pool_sh = pool_sharding(mesh, cfg.kv_heads)
     pool_sds = jax.ShapeDtypeStruct(kv.pool_shape(), cfg.dtype,
                                     sharding=pool_sh)
-    step_out = None if pool_sh is None else (None, pool_sh, pool_sh)
     i32 = jnp.int32
     B, maxp = scfg.max_batch, scfg.max_pages_per_seq
-    # A hybrid stack's programs carry the recurrent layer group's state
-    # behind the pools, in and out, and take the lane's slot where they
-    # run one sequence; it has no cow and no verify programs.
-    state_sds, slot_sds = (), ()
+    # What every program but init takes, CONSUMES and returns: the two
+    # pools and, for a hybrid stack, the recurrent layer group's state
+    # behind them (such a stack's one-sequence programs also take the
+    # lane's slot; it has no cow and no verify programs).
+    carried, carried_sh = (pool_sds, pool_sds), (pool_sh, pool_sh)
+    slot_sds = ()
     if kv.state is not None:
         st_sh = state_sharding(mesh, kv.state.d_inner)
-        state_sds = (
+        carried += (
             jax.ShapeDtypeStruct(kv.state.ssm_shape(), jnp.float32,
                                  sharding=st_sh),
             jax.ShapeDtypeStruct(kv.state.conv_shape(), cfg.dtype,
                                  sharding=st_sh))
+        carried_sh += (st_sh, st_sh)
         slot_sds = (jax.ShapeDtypeStruct((1,), i32),)
-        if step_out is not None:
-            step_out = step_out + (st_sh, st_sh)
     # The OUTPUT CONTRACT is part of every fingerprint, exactly as the
     # torch path's _registry_program_fp hashes str(NamedSharding) per
     # slot: two plans with the same class name but different rules must
@@ -1031,70 +1043,46 @@ def serve_program_specs(
             program_fp=_fp("init", family, cfg, scfg, init_extra),
             init_options=True, treedef=treedef, tplan=tplan,
         ))
-    for b in (buckets if buckets is not None else scfg.prefill_buckets):
-        specs.append(ServeProgramSpec(
-            name=f"prefill-{b}",
-            fn=build_prefill_fn(family, cfg, scfg, b),
-            args=(params_abs, pool_sds, pool_sds, *state_sds,
-                  jax.ShapeDtypeStruct((1, b), i32),
-                  jax.ShapeDtypeStruct((1,), i32),
-                  jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds),
-            out_shardings=step_out,
-            program_fp=_fp(f"prefill-{b}", family, cfg, scfg, extra),
+
+    def program(name, fn, *operands, model=True):
+        """A model program: ``fn`` over ``(params,) + carried + operands``
+        → ``(logits,) + carried``; ``model`` False (cow): the pools
+        alone, in and out."""
+        head, logits_sh = ((params_abs,), (None,)) if model else ((), ())
+        return ServeProgramSpec(
+            name=name, fn=fn, args=(*head, *carried, *operands),
+            out_shardings=None if mesh is None else logits_sh + carried_sh,
+            program_fp=_fp(name, family, cfg, scfg, extra),
             init_options=False,
-        ))
+            consumes=tuple(range(len(head), len(head) + len(carried))),
+        )
+
+    one = jax.ShapeDtypeStruct((1,), i32)
+    lanes = jax.ShapeDtypeStruct((B,), i32)
     for b in (buckets if buckets is not None else scfg.prefill_buckets):
-        specs.append(ServeProgramSpec(
-            name=f"chunk-{b}",
-            fn=build_chunk_prefill_fn(family, cfg, scfg, b),
-            args=(params_abs, pool_sds, pool_sds, *state_sds,
-                  jax.ShapeDtypeStruct((1, b), i32),
-                  jax.ShapeDtypeStruct((1,), i32),
-                  jax.ShapeDtypeStruct((1,), i32),
-                  jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds),
-            out_shardings=step_out,
-            program_fp=_fp(f"chunk-{b}", family, cfg, scfg, extra),
-            init_options=False,
-        ))
+        specs.append(program(
+            f"prefill-{b}", build_prefill_fn(family, cfg, scfg, b),
+            jax.ShapeDtypeStruct((1, b), i32), one,
+            jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds))
+    for b in (buckets if buckets is not None else scfg.prefill_buckets):
+        specs.append(program(
+            f"chunk-{b}", build_chunk_prefill_fn(family, cfg, scfg, b),
+            jax.ShapeDtypeStruct((1, b), i32), one, one,
+            jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds))
     if kv.state is None:
-        specs.append(ServeProgramSpec(
-            name="cow",
-            fn=build_cow_fn(),
-            args=(pool_sds, pool_sds,
-                  jax.ShapeDtypeStruct((1,), i32),
-                  jax.ShapeDtypeStruct((1,), i32)),
-            out_shardings=None if pool_sh is None else (pool_sh, pool_sh),
-            program_fp=_fp("cow", family, cfg, scfg, extra),
-            init_options=False,
-        ))
-    specs.append(ServeProgramSpec(
-        name="decode",
-        fn=build_decode_fn(family, cfg, scfg, mesh),
-        args=(params_abs, pool_sds, pool_sds, *state_sds,
-              jax.ShapeDtypeStruct((B,), i32),
-              jax.ShapeDtypeStruct((B,), i32),
-              jax.ShapeDtypeStruct((B, maxp), i32)),
-        out_shardings=step_out,
-        program_fp=_fp("decode", family, cfg, scfg, extra),
-        init_options=False,
-    ))
+        specs.append(program("cow", build_cow_fn(), one, one, model=False))
+    specs.append(program(
+        "decode", build_decode_fn(family, cfg, scfg, mesh),
+        lanes, lanes, jax.ShapeDtypeStruct((B, maxp), i32)))
     # The verify-<k> family is part of every replica shape's program set
     # REGARDLESS of the spec_decode host knob: warm once, then flip
     # speculation on or off without invalidating a byte of the registry
     # (the fingerprint-host-knob invariance test pins this).
     for k in (scfg.spec_buckets if kv.state is None else ()):
-        specs.append(ServeProgramSpec(
-            name=f"verify-{k}",
-            fn=build_verify_fn(family, cfg, scfg, k),
-            args=(params_abs, pool_sds, pool_sds,
-                  jax.ShapeDtypeStruct((B, k + 1), i32),
-                  jax.ShapeDtypeStruct((B,), i32),
-                  jax.ShapeDtypeStruct((B,), i32),
-                  jax.ShapeDtypeStruct((B, maxp), i32)),
-            out_shardings=step_out,
-            program_fp=_fp(f"verify-{k}", family, cfg, scfg, extra),
-            init_options=False,
-        ))
+        specs.append(program(
+            f"verify-{k}", build_verify_fn(family, cfg, scfg, k),
+            jax.ShapeDtypeStruct((B, k + 1), i32), lanes, lanes,
+            jax.ShapeDtypeStruct((B, maxp), i32)))
     return specs
 
 
@@ -1103,18 +1091,26 @@ def compile_serving_program(spec: ServeProgramSpec):
     (:func:`..compile_service.compile_program`) — persistent cache,
     artifact registry fetch→verify→install / publish, exact
     cache-outcome counters, chaos sites, and the
-    ``TDX_COMPILE_DEADLINE_S`` watchdog all included.
+    ``TDX_COMPILE_DEADLINE_S`` watchdog all included.  The arguments
+    the spec ``consumes`` are donated; one that the lowering cannot
+    alias to an output (jax's "Some donated buffers were not usable"
+    warning) is an error here: the pools and the state enter and leave
+    with one shape, dtype and sharding, and a program that copies them
+    after all must not come up quietly.
     Returns ``(compiled, cache_outcome)``."""
     compile_service.bind_cache()
     cfg = tdx_config.get()
     with observe.span(
         "serve.compile", category="serve", program=spec.name
-    ) as sp:
+    ) as sp, warnings.catch_warnings():
+        warnings.filterwarnings(
+            "error", message="Some donated buffers were not usable")
         compiled, t_lower, t_compile, outcome, costs = compile_service.compile_program(
             spec.fn, tuple(spec.args), spec.out_shardings,
             fault_plan=chaos.active_plan(),
             deadline=cfg.compile_deadline_s or None,
             program_fp=spec.program_fp,
+            jit_kwargs={"donate_argnums": spec.consumes},
             init_compiler_options=spec.init_options,
         )
         sp.set(cache=outcome, lower_s=round(t_lower, 4),
