@@ -387,3 +387,109 @@ def test_length_past_the_table_is_held_to_the_table():
     ref = paged_attention_reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+# -- a first attended position, and keys and values in one pool (PR 34) -------
+
+
+@pytest.mark.parametrize("ppb", [1, 2, 4])
+@pytest.mark.parametrize("v_off", [0, 96])
+def test_walk_from_a_first_position(ppb, v_off):
+    """A window layer's call: the walk begins at the block that holds
+    ``starts[b]`` and positions below it weigh nothing; with ``v_off`` the
+    values lie that many pages behind the keys in ONE array."""
+    from torchdistx_tpu.ops.paged_attention import _paged_attention
+
+    rng = np.random.RandomState(7)
+    B, H, KV, D, page, maxp = 6, 6, 1, 16, 8, 12
+    pool = jnp.asarray(rng.randn(2 * 96, KV, page, D), jnp.float32)
+    q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
+    lengths = jnp.asarray([0, 37, 96, 5, 64, 17], jnp.int32)
+    # inside a block, at a block's edge, at 0, at the last position
+    starts = jnp.asarray([0, 21, 80, 0, 63, 16], jnp.int32)
+    table = jnp.asarray(rng.randint(1, 96, (B, maxp)), jnp.int32)
+    out = _paged_attention(q, pool, pool, lengths, table, ppb, True,
+                           starts=starts, v_page_offset=v_off)
+    ref = paged_attention_reference(q, pool, pool, lengths, table,
+                                    starts=starts, v_page_offset=v_off)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=1e-5)
+    assert np.all(np.asarray(out)[~live] == 0.0)
+    # and it is NOT the whole context: the mask is doing something
+    whole = paged_attention_reference(q, pool, pool, lengths, table,
+                                      v_page_offset=v_off)
+    assert np.abs(np.asarray(whole)[1] - np.asarray(ref)[1]).max() > 1e-3
+
+
+def test_a_first_position_of_zero_is_the_kernel_without_one():
+    """Mistral's and Jamba's calls pass no first position and trace the
+    kernel they traced before there were windows: two scalar operands."""
+    q, kp, vp, lengths, table = _rand_case(
+        3, B=3, H=4, KV=2, D=16, page=8, n_pages=32, maxp=4,
+        lengths=[9, 0, 30], dtype=jnp.float32)
+    zero = jnp.zeros((3,), jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(paged_attention(q, kp, vp, lengths, table)),
+        np.asarray(paged_attention(q, kp, vp, lengths, table, starts=zero)))
+    plain = jax.make_jaxpr(lambda *a: paged_attention(*a))(
+        q, kp, vp, lengths, table)
+    with_starts = jax.make_jaxpr(
+        lambda *a: paged_attention(*a[:5], starts=a[5]))(
+        q, kp, vp, lengths, table, zero)
+    n_prefetch = lambda j: [
+        e.params["grid_mapping"].num_index_operands for e in j.eqns
+        if e.primitive.name == "pallas_call"]
+    assert n_prefetch(plain) == [2] and n_prefetch(with_starts) == [3]
+
+
+def test_a_first_position_needs_the_walk():
+    q, kp, vp, lengths, table = _rand_case(
+        4, B=2, H=4, KV=2, D=64, page=8, n_pages=16, maxp=2,
+        lengths=[9, 12], dtype=jnp.float32)
+    from torchdistx_tpu.ops.paged_attention import _paged_attention
+
+    with pytest.raises(NotImplementedError, match="needs the walk"):
+        _paged_attention(q, kp, vp, lengths, table, 1, False, walk=False,
+                         starts=jnp.zeros((2,), jnp.int32))
+    with pytest.raises(ValueError, match="one a sequence"):
+        paged_attention(q, kp, vp, lengths, table,
+                        starts=jnp.zeros((3,), jnp.int32))
+
+
+def test_prefill_attention_masks_by_the_window_and_gathers_only_its_row():
+    """A chunk on a window layer: positions counted from the row's first
+    page; the result is the dense computation over the whole sequence with
+    the window's mask."""
+    from torchdistx_tpu.ops import paged_prefill_attention
+
+    rng = np.random.RandomState(11)
+    H, KV, D, page, W = 4, 1, 16, 4, 12
+    T, S, s0 = 40, 8, 32                       # the chunk is [32, 40)
+    k = rng.randn(T, KV, D).astype(np.float32)
+    v = rng.randn(T, KV, D).astype(np.float32)
+    q = rng.randn(1, S, H, D).astype(np.float32)
+    # the row holds pages of positions 20.. (first live page: 20 // 4 = 5)
+    first = (s0 - W + 1) // page * page        # 20
+    n_live = (T - first) // page               # 5 pages
+    pool = np.zeros((2 * 16, KV, page, D), np.float32)
+    ids = np.asarray([7, 3, 9, 12, 5])
+    for i, pid in enumerate(ids):
+        rows = slice(first + i * page, first + (i + 1) * page)
+        pool[pid] = k[rows].transpose(1, 0, 2)
+        pool[16 + pid] = v[rows].transpose(1, 0, 2)
+    table = np.zeros((1, 7), np.int32)
+    table[0, :n_live] = ids
+    pos = (s0 + np.arange(S))[None] - first
+    out = paged_prefill_attention(
+        jnp.asarray(q), jnp.asarray(pool), jnp.asarray(pool),
+        jnp.asarray(pos, jnp.int32), jnp.asarray([T - first], jnp.int32),
+        jnp.asarray(table), window=W, v_page_offset=16)
+    # dense, over the whole sequence
+    sc = np.einsum("shd,tkd->hst", q[0], k) / np.sqrt(D)
+    i, j = (s0 + np.arange(S))[:, None], np.arange(T)[None]
+    sc = np.where((j <= i) & (i - j < W), sc, -1e30)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("hst,tkd->shd", p, v)
+    np.testing.assert_allclose(np.asarray(out)[0], want, atol=1e-5)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from torchdistx_tpu import compile_service, observe
-from torchdistx_tpu.models import TINY, TINY_JAMBA
+from torchdistx_tpu.models import TINY, TINY_AFMOE, TINY_JAMBA
 from torchdistx_tpu.observe.costmodel import program_costs
 from torchdistx_tpu.serve import (Request, ServeConfig, ServeEngine,
                                   serve_program_specs)
@@ -23,10 +23,15 @@ FAMILIES = {
     "llama": (TINY, ServeConfig(**SHAPE)),
     "jamba": (TINY_JAMBA, ServeConfig(**SHAPE, prefix_cache=False,
                                       spec_decode=False)),
+    # the window group's pool and the held experts' pair counts behind
+    # the full group's two pools
+    "afmoe": (TINY_AFMOE, ServeConfig(**SHAPE, prefix_cache=False,
+                                      spec_decode=False)),
 }
 PROGRAMS = {
     "llama": ("prefill-8", "chunk-8", "cow", "decode", "verify-2"),
     "jamba": ("prefill-8", "chunk-8", "decode"),
+    "afmoe": ("prefill-8", "chunk-8", "decode"),
 }
 KINDS = [(f, p) for f, ps in PROGRAMS.items() for p in ps]
 
@@ -152,7 +157,7 @@ class _FailsOnce:
 @pytest.mark.parametrize("family,program,nth", [
     ("llama", "decode", 3), ("llama", "prefill-8", 2), ("llama", "chunk-8", 2),
     ("llama", "cow", 1), ("jamba", "decode", 3), ("jamba", "prefill-8", 2),
-    ("jamba", "chunk-8", 2)])
+    ("jamba", "chunk-8", 2), ("afmoe", "decode", 3), ("afmoe", "chunk-8", 2)])
 def test_fault_inside_a_donated_call_rebuilds_the_pools(built, family,
                                                         program, nth):
     assert issubclass(jax.errors.JaxRuntimeError,
@@ -188,7 +193,8 @@ def test_fault_inside_a_donated_call_rebuilds_the_pools(built, family,
     assert not eng._pools_lost()
     assert len(eng.prefix) == 0 and not eng.active
     assert eng.kv.pages_in_use == 0 and eng.kv.state_slots_in_use == 0
-    assert len(eng.state) == (2 if family == "jamba" else 0)
+    assert len(eng.state) == (0 if family == "llama" else 2)
+    assert eng.kv.window_pages_in_use == 0
     assert {r.rid for r in eng.waiting} == {
         r.rid for r in reqs} - set(eng.results)
     got = eng.run()

@@ -187,8 +187,10 @@ def one_chip():
     (4, 32, 8, 128, 16, 8, jnp.float32),       # float32 pools
     (4, 32, 8, 128, 8, 8, jnp.bfloat16),       # a page of half a tile
     (8, 25, 25, 64, 16, 48, jnp.bfloat16),     # gpt2-xl: ``_page_kernel``
+    (32, 32, 8, 128, 16, 800, jnp.bfloat16),   # Mistral under mixed-queue
+    (128, 6, 1, 128, 16, 800, jnp.bfloat16),   # trinity: the full layer
 ], ids=["mistral-48", "mistral-260", "jamba", "tp2-shard", "float32",
-        "page8-bf16", "gpt2-xl"])
+        "page8-bf16", "gpt2-xl", "mistral-800", "trinity-full"])
 def test_paged_attention_compiles_for_v5e(one_chip, B, H, KV, D, page, maxp,
                                           dtype):
     def arg(shape, dt):
@@ -199,6 +201,25 @@ def test_paged_attention_compiles_for_v5e(one_chip, B, H, KV, D, page, maxp,
         arg((B, H, D), dtype), arg((512, KV, page, D), dtype),
         arg((512, KV, page, D), dtype), arg((B,), jnp.int32),
         arg((B, maxp), jnp.int32)).compile()
+    assert "tdx_paged_attention_decode" in compiled.as_text()
+
+
+def test_paged_attention_from_a_first_position_compiles_for_v5e(one_chip):
+    """A window layer's call at the trinity cell's shape: 128 lanes, one KV
+    head under six query heads, a row of 386 live pages, a first position a
+    lane as a third scalar operand, and the values 4 x 33,281 pages behind
+    the keys in ONE pool that is passed twice."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows = 8 * 33281
+    fn = jax.jit(lambda q, pool, lens, table, starts: paged_attention(
+        q, pool, pool, lens, table, starts=starts, v_page_offset=4 * 33281,
+        interpret=False))
+    compiled = fn.lower(
+        arg((128, 6, 128), jnp.bfloat16),
+        arg((rows, 1, 16, 128), jnp.bfloat16), arg((128,), jnp.int32),
+        arg((128, 386), jnp.int32), arg((128,), jnp.int32)).compile()
     assert "tdx_paged_attention_decode" in compiled.as_text()
 
 
@@ -216,6 +237,11 @@ _CELLS = {
     "mistral7b-chat-backlog": ("mistral-7b-v0.3-d12", "chat-backlog"),
     "mistral7b-doc-prefill-busy": ("mistral-7b-v0.3-d12", "doc-prefill-busy"),
     "jamba2-3b-chat-backlog": ("jamba2-3b", "chat-backlog-wide"),
+    "trinity-large-mixed-queue": ("trinity-large-preview-tp8-d5",
+                                  "mixed-queue"),
+    # no cell of the manifest (PERF.md 7: measured and left out in PR 34);
+    # the dense stack at the same traffic's widths, 800 pages a sequence
+    "mistral-under-mixed-queue": ("mistral-7b-v0.3-d12", "mixed-queue"),
 }
 
 
@@ -236,9 +262,10 @@ def _cell_specs(cell):
         cfg = json.load(f)
     with open(os.path.join(root, "benchmark", "traffic", mix + ".json")) as f:
         engine = json.load(f)["engine"]
-    if cfg["family"] == "jamba":
-        from benchmark.families import jamba as fam
+    if cfg["family"] in ("jamba", "afmoe"):
+        from benchmark import harness
 
+        fam = harness.load_module(root, cfg["family_module"])
         tcfg = fam.transformer_config(cfg, fam.dims(cfg))
     else:
         tcfg = adapters.transformer_config(cfg, configs.dims(cfg))
@@ -248,7 +275,8 @@ def _cell_specs(cell):
 
 
 def _hlo_shape(sds):
-    dt = {"bfloat16": "bf16", "float32": "f32"}[str(jnp.dtype(sds.dtype))]
+    dt = {"bfloat16": "bf16", "float32": "f32", "int32": "s32"}[
+        str(jnp.dtype(sds.dtype))]
     return f"{dt}[{','.join(map(str, sds.shape))}]"
 
 
@@ -266,6 +294,13 @@ _ALIAS_CASES = [
     # ``layout_copies``: the positions in ``args`` this is allowed for.
     ("jamba2-3b-chat-backlog", "prefill-128", (4,)),
     ("jamba2-3b-chat-backlog", "chunk-256", (4,)),
+    # The afmoe family carries four arrays: both pools of the full group,
+    # the window group's one pool and the held experts' pair counts.
+    ("trinity-large-mixed-queue", "decode", ()),
+    ("trinity-large-mixed-queue", "prefill-256", ()),
+    ("trinity-large-mixed-queue", "chunk-2048", ()),
+    ("mistral-under-mixed-queue", "decode", ()),
+    ("mistral-under-mixed-queue", "chunk-2048", ()),
 ]
 
 
@@ -296,7 +331,10 @@ def test_serving_program_aliases_its_pools_on_v5e(one_chip, monkeypatch, cell,
         .split("entry_computation_layout", 1)[0])}
     assert aliased == set(range(first, first + len(spec.consumes)))
     carried = [spec.args[i] for i in spec.consumes]
-    assert program_costs(compiled)["alias_bytes"] == sum(
+    # The afmoe family's pair counts, int32 [4, 32], are laid out in a
+    # whole (8, 128) tile on the chip: 2,048 bytes for their 512.
+    tile_pad = 1536 if cell.startswith("trinity") else 0
+    assert program_costs(compiled)["alias_bytes"] == tile_pad + sum(
         a.size * jnp.dtype(a.dtype).itemsize for a in carried)
     copies = [line.strip()[:120] for line in text.splitlines()
               if re.search(r"= \S+ copy\(", line)]

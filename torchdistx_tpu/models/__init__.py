@@ -8,6 +8,7 @@ from .configs import (
     PRESETS,
     T5_11B,
     TINY,
+    TINY_AFMOE,
     TINY_GPT2,
     TINY_JAMBA,
     TINY_MOE,
@@ -15,6 +16,7 @@ from .configs import (
     TINY_VIT,
     VIT_B16,
     VIT_L16,
+    AfmoeConfig,
     EncDecConfig,
     MambaConfig,
     MoEConfig,
@@ -22,6 +24,7 @@ from .configs import (
     VisionConfig,
 )
 from .decomposition import DecodeDecomposition, PipelineDecomposition
+from .afmoe import AfmoeModel, make_afmoe
 from .gpt2 import GPT2Model, make_gpt2
 from .jamba import JambaModel, make_jamba
 from .llama import LlamaModel, make_llama
@@ -32,6 +35,7 @@ from .vit import ViTModel, make_vit
 
 __all__ = [
     "TransformerConfig",
+    "AfmoeConfig",
     "EncDecConfig",
     "VisionConfig",
     "MoEConfig",
@@ -43,6 +47,7 @@ __all__ = [
     "MIXTRAL_8X7B",
     "T5_11B",
     "TINY",
+    "TINY_AFMOE",
     "TINY_GPT2",
     "TINY_JAMBA",
     "TINY_MOE",
@@ -50,6 +55,7 @@ __all__ = [
     "TINY_VIT",
     "VIT_B16",
     "VIT_L16",
+    "AfmoeModel",
     "DecodeDecomposition",
     "GPT2Model",
     "JambaModel",
@@ -57,6 +63,7 @@ __all__ = [
     "PipelineDecomposition",
     "T5Model",
     "ViTModel",
+    "make_afmoe",
     "make_gpt2",
     "make_jamba",
     "make_llama",

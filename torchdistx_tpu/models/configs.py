@@ -8,7 +8,7 @@ by tests and multi-chip dry runs.
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -36,6 +36,27 @@ class MambaConfig:
 
 
 @dataclass(frozen=True)
+class AfmoeConfig:
+    """The AFMoE stack (arcee-ai Trinity; models/afmoe.py): sandwich-normed
+    blocks with gated, QK-normed attention that is windowed (with rotary)
+    or full (no positional term) by layer, ``n_dense_layers`` leading
+    dense MLPs and sigmoid-routed expert layers with one shared expert
+    behind them.  The router scores all ``n_experts``; THIS replica holds
+    the ``held_experts`` from ``first_expert`` on and computes their part
+    (an expert-parallel chip's share; the whole layer where it holds all)."""
+
+    n_experts: int = 256
+    top_k: int = 4
+    d_expert: int = 3072     # width of a routed expert and of the shared one
+    n_dense_layers: int = 1
+    layer_types: Tuple[str, ...] = ()  # "sliding" | "full", one a layer
+    window: int = 4096
+    route_scale: float = 2.448
+    first_expert: int = 0
+    held_experts: int = 32
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 512
@@ -59,6 +80,7 @@ class TransformerConfig:
 
     moe: Optional[MoEConfig] = None
     mamba: Optional[MambaConfig] = None  # hybrid stack (models/jamba.py)
+    afmoe: Optional[AfmoeConfig] = None  # AFMoE stack (models/afmoe.py)
 
     dtype: jnp.dtype = jnp.bfloat16  # activation/compute dtype
     param_dtype: jnp.dtype = jnp.float32
@@ -236,6 +258,16 @@ TINY_JAMBA = TransformerConfig(
                       attn_period=14, attn_offset=7),
 )
 
+TINY_AFMOE = TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=5, n_heads=4, n_kv_heads=1,
+    head_dim=16, d_ff=128, max_seq_len=256, rope_theta=10000.0,
+    tie_embeddings=False, norm_eps=1e-5, dtype=jnp.float32,
+    afmoe=AfmoeConfig(
+        n_experts=8, top_k=2, d_expert=32, n_dense_layers=1,
+        layer_types=("sliding", "sliding", "full", "sliding", "sliding"),
+        window=16, first_expert=0, held_experts=4),
+)
+
 TINY_T5 = EncDecConfig(
     encoder=_T5_STACK.replace(
         vocab_size=256, d_model=64, n_layers=2, n_heads=4, head_dim=16, d_ff=128,
@@ -270,6 +302,7 @@ PRESETS = {
     "tiny-gpt2": TINY_GPT2,
     "tiny-moe": TINY_MOE,
     "tiny-jamba": TINY_JAMBA,
+    "tiny-afmoe": TINY_AFMOE,
     "tiny-t5": TINY_T5,
     "tiny-vit": TINY_VIT,
 }

@@ -79,6 +79,18 @@ Conventions shared with the serving engine:
   A sequence with ``lengths[b] == 0`` (an idle batch slot) produces a
   zero output row in the kernel; the reference softmaxes uniform masked
   logits there instead — callers must ignore idle rows.
+* ``starts`` (optional): [B] int32 — the first attended position of
+  each sequence (a sliding-window layer: ``max(0, length - window)``);
+  the walk begins at the block that holds it and positions below it
+  weigh exactly zero.  Positions are the TABLE's: entry 0 of a row is
+  the page of positions ``[0, page_size)``, so a caller whose row holds
+  only the live pages of a window passes lengths and starts counted
+  from its row's first page (the keys carry their own rotary term;
+  attention itself knows no absolute position).  Without ``starts`` the
+  kernel is the one it was: no operand, no mask, no arithmetic more.
+* ``v_page_offset`` (static): added to a page id to find the page's
+  VALUES, for a pool that keeps keys and values in one array (the
+  window group, ``serve/kv_cache.py``); pass that array twice.
 """
 
 from __future__ import annotations
@@ -121,21 +133,26 @@ def paged_attention_reference(
     v_pages: jax.Array,  # [P, KV, page, D]
     lengths: jax.Array,  # [B] int32
     page_table: jax.Array,  # [B, max_pages] int32
+    *,
+    starts: Optional[jax.Array] = None,  # [B] int32
+    v_page_offset: int = 0,
 ) -> jax.Array:
-    """Dense jnp oracle: gather the mapped pages, mask past ``lengths``,
-    f32 softmax — numerically the same computation as
-    ``default_attention`` on the gathered layout."""
+    """Dense jnp oracle: gather the mapped pages, mask past ``lengths``
+    (and below ``starts``), f32 softmax — numerically the same
+    computation as ``default_attention`` on the gathered layout."""
     B, H, D = q.shape
     KV = k_pages.shape[1]
     groups = H // KV
 
     k = _gather_context(k_pages, page_table)  # [B, KV, T, D]
-    v = _gather_context(v_pages, page_table)
+    v = _gather_context(v_pages, page_table + v_page_offset)
     T = k.shape[2]
     qf = q.astype(jnp.float32) * (1.0 / math.sqrt(D))
     qf = qf.reshape(B, KV, groups, D)
     logits = jnp.einsum("bkgd,bktd->bkgt", qf, k)
     mask = jnp.arange(T)[None, :] < lengths[:, None]  # [B, T]
+    if starts is not None:
+        mask &= jnp.arange(T)[None, :] >= starts[:, None]
     logits = jnp.where(mask[:, None, None], logits, _NEG)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgt,bktd->bkgd", probs, v)
@@ -149,6 +166,9 @@ def paged_prefill_attention(
     q_positions: jax.Array,  # [B, S] int32 — absolute positions of q
     lengths: jax.Array,  # [B] int32 — valid context INCLUDING the chunk
     page_table: jax.Array,  # [B, max_pages] int32
+    *,
+    window: Optional[int] = None,
+    v_page_offset: int = 0,
 ) -> jax.Array:
     """Chunked-prefill attention through the page table: each query at
     absolute position ``t`` attends every cached position ``<= t`` — the
@@ -158,13 +178,18 @@ def paged_prefill_attention(
     :func:`paged_attention_reference`; positions at or past
     ``lengths[b]`` are padding — their rows are garbage and must be
     ignored by the caller (position 0 always satisfies the mask, so no
-    row softmaxes over an empty set)."""
+    row softmaxes over an empty set).  With ``window`` a query attends
+    only the ``window`` positions up to its own (``0 <= t - j < window``),
+    so a caller whose table row holds only a window's live pages passes
+    positions and lengths counted from the row's first page, as for
+    :func:`paged_attention`'s ``starts``, and gathers ``chunk + window``
+    keys, not the context; ``v_page_offset`` as there."""
     B, S, H, D = q.shape
     KV = k_pages.shape[1]
     groups = H // KV
 
     k = _gather_context(k_pages, page_table)  # [B, KV, T, D]
-    v = _gather_context(v_pages, page_table)
+    v = _gather_context(v_pages, page_table + v_page_offset)
     T = k.shape[2]
     qf = q.astype(jnp.float32) * (1.0 / math.sqrt(D))
     qf = qf.reshape(B, S, KV, groups, D)
@@ -173,6 +198,8 @@ def paged_prefill_attention(
     mask = (tpos <= q_positions[:, :, None]) & (
         tpos < lengths[:, None, None]
     )  # [B, S, T]
+    if window is not None:
+        mask &= q_positions[:, :, None] - tpos < window
     logits = jnp.where(mask[:, :, None, None, :], logits, _NEG)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bskgt,bktd->bskgd", probs, v)
@@ -210,20 +237,23 @@ def kv_blocks_walked(lengths, page_size: int, kv_heads: int, head_dim: int,
 
 
 def _attend_block(q_ref, k, v, pos0, seq_len, acc_ref, m_ref, l_ref,
-                  sm_scale):
+                  sm_scale, start=None):
     """One step of the online softmax, every kv head at once: the block's
     keys and values ``k`` / ``v`` [KV, T, D], whose first row is position
     ``pos0`` of a sequence of ``seq_len``, against the heads' query groups
     ``q_ref[0]`` [KV, Gp, D].  The heads go through each product together
     (independent matmuls, which the unit pipelines) and through one
     vectorised softmax between them.  Everything in float32; rows at or
-    past the length weigh exactly zero."""
+    past the length (and, with ``start``, below it) weigh exactly zero."""
     q = q_ref[0].astype(jnp.float32) * sm_scale
     s = jax.lax.dot_general(
         q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     )  # [KV, Gp, T]
-    mask = pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) < seq_len
+    pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    mask = pos < seq_len
+    if start is not None:
+        mask &= pos >= start
     s = jnp.where(mask, s, _NEG)
 
     m_prev = m_ref[:, :, :1]
@@ -256,6 +286,7 @@ def _write_out(o_ref, acc_ref, l_ref):
 def _decode_kernel(
     lengths_ref,  # SMEM [B] i32 (scalar prefetch)
     table_ref,  # SMEM [B, max_pages] i32 (scalar prefetch)
+    starts_ref,  # SMEM [B] i32 (scalar prefetch), or None: all from 0
     q_ref,  # [1, KV, Gp, D]
     k_hbm,  # [P, KV, page, D] — the pool, where it lives
     v_hbm,  # [P, KV, page, D]
@@ -271,12 +302,21 @@ def _decode_kernel(
     page_size: int,
     pages_per_block: int,
     sm_scale: float,
+    v_page_offset: int = 0,
 ):
     b = pl.program_id(0)
     last_b = pl.num_programs(0) - 1
     span = pages_per_block * page_size
     seq_len = lengths_ref[b]
     n_blocks = pl.cdiv(seq_len, span)
+
+    def first_block(s):
+        """The block a sequence's walk begins at: the one that holds its
+        first attended position (block 0 without ``starts``)."""
+        return 0 if starts_ref is None else starts_ref[s] // span
+
+    blk0 = first_block(b)
+    start = None if starts_ref is None else starts_ref[b]
 
     def block_copies(s, blk, slot, wait=False):
         """Start, or wait for, the copies of block ``blk`` of sequence
@@ -289,11 +329,11 @@ def _decode_kernel(
         def page(p, _):
             pid = table_ref[s, first + p]
             rows = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
-            for which, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                                (v_hbm, v_buf))):
+            for which, (hbm, buf, off) in enumerate((
+                    (k_hbm, k_buf, 0), (v_hbm, v_buf, v_page_offset))):
                 cp = pltpu.make_async_copy(
-                    hbm.at[pid], buf.at[slot, :, rows, :],
-                    sem.at[which, slot])
+                    hbm.at[pid + off] if off else hbm.at[pid],
+                    buf.at[slot, :, rows, :], sem.at[which, slot])
                 if wait:
                     cp.wait()
                 else:
@@ -301,10 +341,11 @@ def _decode_kernel(
 
         jax.lax.fori_loop(0, n, page, None)
 
-    # The sequence after this one, whose block 0 is fetched under this
-    # one's last block (an idle sequence fetches nothing).
+    # The sequence after this one, whose first block is fetched under
+    # this one's last block (an idle sequence fetches nothing).
     nxt_b = jnp.minimum(b + 1, last_b)
     nxt_starts = (b < last_b) & (lengths_ref[nxt_b] > 0)
+    nxt_blk0 = first_block(nxt_b)
 
     @pl.when(b == 0)
     def _first():
@@ -316,30 +357,30 @@ def _decode_kernel(
 
         @pl.when(seq_len > 0)
         def _():
-            block_copies(0, 0, 0)
+            block_copies(0, blk0, 0)
 
     slot0 = slot_ref[0]
     _init_state(acc_ref, m_ref, l_ref)
 
     @pl.when((n_blocks == 0) & nxt_starts)
     def _idle():
-        block_copies(nxt_b, 0, slot0)
+        block_copies(nxt_b, nxt_blk0, slot0)
 
     def block(i, _):
-        slot = (slot0 + i) % 2
+        slot = (slot0 + i - blk0) % 2
         more = i + 1 < n_blocks
 
         @pl.when(more | nxt_starts)
         def _():
-            block_copies(jnp.where(more, b, nxt_b), jnp.where(more, i + 1, 0),
-                         1 - slot)
+            block_copies(jnp.where(more, b, nxt_b),
+                         jnp.where(more, i + 1, nxt_blk0), 1 - slot)
 
         block_copies(b, i, slot, wait=True)
         _attend_block(q_ref, k_buf[slot], v_buf[slot], i * span, seq_len,
-                      acc_ref, m_ref, l_ref, sm_scale)
+                      acc_ref, m_ref, l_ref, sm_scale, start)
 
-    jax.lax.fori_loop(0, n_blocks, block, None)
-    slot_ref[0] = (slot0 + n_blocks) % 2
+    jax.lax.fori_loop(blk0, n_blocks, block, None)
+    slot_ref[0] = (slot0 + jnp.maximum(n_blocks - blk0, 0)) % 2
     _write_out(o_ref, acc_ref, l_ref)
 
 
@@ -387,11 +428,14 @@ def paged_attention(
     lengths: jax.Array,  # [B] int32
     page_table: jax.Array,  # [B, max_pages] int32
     *,
+    starts: Optional[jax.Array] = None,  # [B] int32
+    v_page_offset: int = 0,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Ragged paged-attention decode: one query token per sequence
-    against its page-table-mapped context.  See the module docstring for
-    the layout contract; output is [B, H, D] in ``q``'s dtype."""
+    against its page-table-mapped context, from position ``starts[b]``
+    on where given.  See the module docstring for the layout contract;
+    output is [B, H, D] in ``q``'s dtype."""
     B, H, D = q.shape
     P, KV, page_size, Dk = k_pages.shape
     if Dk != D:
@@ -409,14 +453,18 @@ def paged_attention(
             f"batch mismatch: q {B}, page_table {page_table.shape}, "
             f"lengths {lengths.shape}"
         )
+    if starts is not None and starts.shape != (B,):
+        raise ValueError(f"starts {starts.shape} is not one a sequence, "
+                         f"({B},)")
     return _paged_attention(
         q, k_pages, v_pages, lengths, page_table,
         pages_per_block(KV, page_size, D, k_pages.dtype),
-        resolve_interpret(interpret))
+        resolve_interpret(interpret), starts=starts,
+        v_page_offset=v_page_offset)
 
 
 def _paged_attention(q, k_pages, v_pages, lengths, page_table, ppb,
-                     interpret, walk=None):
+                     interpret, walk=None, starts=None, v_page_offset=0):
     """The kernel call at ``ppb`` pages a block (the on-chip sweep,
     ``tools/paged_attention_chip.py``, times other sizes beside the
     derived one).  ``walk``: the walk or ``_page_kernel``; by default
@@ -443,6 +491,13 @@ def _paged_attention(q, k_pages, v_pages, lengths, page_table, ppb,
                 pltpu.VMEM((heads, gp, _LANES), jnp.float32),
                 pltpu.VMEM((heads, gp, _LANES), jnp.float32)]
 
+    if not walk and (starts is not None or v_page_offset):
+        raise NotImplementedError(
+            f"a first position or a value offset needs the walk, which a "
+            f"head dim of {D} cannot take on the chip (no multiple of "
+            f"{_LANES}); the page-a-step fallback attends from 0")
+    lengths = jnp.minimum(lengths.astype(jnp.int32), maxp * page_size)
+    prefetch = (lengths, page_table.astype(jnp.int32))
     if not walk:
         # Index maps see the scalar-prefetch refs after the grid indices.
         # The pipeline fetches a block every step: past the length it
@@ -465,14 +520,25 @@ def _paged_attention(q, k_pages, v_pages, lengths, page_table, ppb,
             scratch_shapes=state(1),
         )
     else:
-        q_spec = pl.BlockSpec((1, KV, gp, D),
-                              lambda b, lens, table: (b, 0, 0, 0))
+        q_spec = pl.BlockSpec((1, KV, gp, D), lambda b, *_: (b, 0, 0, 0))
         pool_spec = pl.BlockSpec(memory_space=pl.ANY)
         block = (2, KV, ppb * page_size, D)
         kernel = functools.partial(_decode_kernel, page_size=page_size,
-                                   pages_per_block=ppb)
+                                   pages_per_block=ppb,
+                                   v_page_offset=v_page_offset)
+        if starts is None:
+            # The kernel every caller had before there were windows: two
+            # scalar operands, no first position anywhere in its body.
+            walk_from_0 = kernel
+            kernel = lambda lens, table, *refs, **kw: walk_from_0(
+                lens, table, None, *refs, **kw)
+        else:
+            # Below the length, so that a live sequence's walk has a block
+            # (the sequence after it is fetched under that block).
+            prefetch += (jnp.clip(starts.astype(jnp.int32), 0,
+                                  jnp.maximum(lengths - 1, 0)),)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=(B,),
             in_specs=[q_spec, pool_spec, pool_spec],
             out_specs=q_spec,
@@ -496,8 +562,7 @@ def _paged_attention(q, k_pages, v_pages, lengths, page_table, ppb,
         name="tdx_paged_attention_decode",
     )(
         # The walk's trip counts come from the lengths, so a length the
-        # table cannot hold is held to the table, as the reference's
-        # mask holds it.
-        jnp.minimum(lengths.astype(jnp.int32), maxp * page_size),
-        page_table.astype(jnp.int32), qh, k_pages, v_pages)
+        # table cannot hold is held to the table (above), as the
+        # reference's mask holds it.
+        *prefetch, qh, k_pages, v_pages)
     return out[:, :, :groups].reshape(B, H, D)

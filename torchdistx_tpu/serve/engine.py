@@ -100,6 +100,7 @@ from ..models import PRESETS, TransformerConfig
 from ..ops.paged_attention import kv_blocks_walked
 from ..utils.logging import get_logger
 from .kv_cache import (OutOfPages, PagedKVCache, init_pools, init_state,
+                       init_window_pool,
                        pool_sharding, state_sharding)
 from .prefix import NgramDrafter, PrefixCache
 from .programs import (
@@ -112,6 +113,17 @@ from .programs import (
 )
 
 __all__ = ["Request", "ServeEngine", "oracle_generate", "spin_up_replica"]
+
+
+@jax.jit
+def _greedy(logits):
+    """The greedy choice of every lane, made on the device beside the
+    logits.  ``np.argmax`` of one row of 25,024 floats took about 70 us on
+    the chip's host (numpy's float argmax is a scalar loop where the CPU
+    lacks AVX-512; 10 us where it has it): 8.7 of the 14 ms of host time
+    in a decode tick of 128 lanes, all of it with the device idle
+    (PERF.md section 6, PR 34).  First index of the maximum, as numpy's."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 @dataclass
@@ -256,6 +268,11 @@ class ServeEngine:
             self.k_pages.shape)
         self._kernel_pool = (page, kv_local, head_dim, self.k_pages.dtype)
         self._n_admitted = 0  # lifetime; serve.admit reports a tick's share
+        # The afmoe family: what its expert layers routed to the experts
+        # this replica holds, from the counts every call brings back.
+        self._moe_pairs = observe.counter("tdx.serve.moe_routed_pairs")
+        self._moe_hit = observe.counter("tdx.serve.moe_experts_hit")
+        self._moe_max = observe.counter("tdx.serve.moe_pairs_max_expert")
 
     # -- pools ----------------------------------------------------------------
     #
@@ -268,15 +285,27 @@ class ServeEngine:
     # (its shape, dtype and sharding stay readable).
 
     def _init_pools(self) -> None:
-        """Allocate the zeroed pools and, for a hybrid stack, the
-        recurrent layer group's arrays (``self.state``: ssm, conv; else
-        none), which every model program threads behind the pools.  A
-        lane's slot is the lane's index."""
+        """Allocate the zeroed pools and what else every model program
+        of the family threads behind them (``self.state``): a hybrid
+        stack's recurrent layer group (ssm, conv; a lane's slot is the
+        lane's index), the afmoe family's window layer group and the
+        held experts' running pair counts, else nothing."""
         cfg, kv = self.cfg, self.kv.cfg
-        self.k_pages, self.v_pages = init_pools(
-            kv, cfg.dtype, pool_sharding(self.mesh, cfg.kv_heads))
-        self.state: tuple = () if kv.state is None else init_state(
-            kv.state, cfg.dtype, state_sharding(self.mesh, kv.state.d_inner))
+        sharding = pool_sharding(self.mesh, cfg.kv_heads)
+        self.k_pages, self.v_pages = init_pools(kv, cfg.dtype, sharding)
+        self.state: tuple = ()
+        if kv.state is not None:
+            self.state = init_state(
+                kv.state, cfg.dtype,
+                state_sharding(self.mesh, kv.state.d_inner))
+        elif kv.window is not None:
+            from ..models import afmoe
+
+            self.state = (
+                init_window_pool(kv, cfg.dtype, sharding),
+                jnp.zeros((afmoe.n_expert_layers(cfg),
+                           cfg.afmoe.held_experts), jnp.int32))
+            self._pairs_seen = np.zeros(self.state[1].shape, np.int32)
 
     def _pools_lost(self) -> bool:
         """Whether a call that consumed the pools or the state failed
@@ -306,6 +335,7 @@ class ServeEngine:
                     spec_buckets=self.scfg.spec_buckets,
                     spec_decode=self.scfg.spec_decode,
                     spec_k=self.scfg.spec_k,
+                    n_window_pages=self.scfg.n_window_pages or None,
                 ),
                 seed=self._seed, param_dtype=self._param_dtype,
                 mesh=self.mesh, plan=self.plan,
@@ -654,40 +684,89 @@ class ServeEngine:
                 chaos.execute(fault)
 
     def _run_program(self, name: str, *args, lanes: int, attended: int,
-                     kv_blocks: int = 0,
-                     fetch: bool = True) -> Optional[np.ndarray]:
+                     kv_blocks: int = 0, window_tokens: int = 0,
+                     fetch: bool = True, greedy: bool = False):
         """Call the compiled model program ``name`` on the params, the
         pools, the recurrent state (a hybrid stack) and ``args`` under
         ``serve.program`` (``state_lanes``: the lanes whose recurrent
         state the call advances) and bring its logits to the host under
         ``serve.tick.d2h`` (``fetch`` False: a chunk that is not a
-        prompt's last, whose logits nobody reads).  While telemetry is on
+        prompt's last, whose logits nobody reads; ``greedy``: also every
+        row's greedy choice, made on the device: ``(logits, tokens)``).
+        While telemetry is on
         ``serve.program`` ends when the logits are ready, so the two spans
         split device time from the copy; off, nothing waits before the
         fetch.  ``attended`` is the context the program's ``lanes`` attend
         over, counted before anything retires; ``kv_blocks`` the blocks
         the decode kernel walks for it (a program that attends through
-        jnp gathers walks none)."""
+        jnp gathers walks none).  With a window group ``window_tokens`` is
+        what the lanes attend over in a window layer (``min(context,
+        window)`` each), and the call's pair counts come to the host with
+        the logits: ``routed_pairs`` (pairs that landed on a held expert,
+        over the expert layers) and ``experts_hit`` (held experts with at
+        least one) on the span, and the ``tdx.serve.moe_*`` counters."""
         with observe.span("serve.program", category="serve", program=name,
                           lanes=lanes, attended_tokens=attended,
                           kv_blocks=kv_blocks,
-                          state_lanes=lanes if self.state else 0) as sp:
+                          state_lanes=(lanes if self.kv.cfg.state is not None
+                                       else 0)) as sp:
             logits, self.k_pages, self.v_pages, *state = self._program(name)(
                 self.params, self.k_pages, self.v_pages, *self.state, *args)
             self.state = tuple(state)
             sp.block_on(logits)
+            # The pair counts ride to the host with the logits: a chunk
+            # whose logits nobody reads waits for nothing, so the host
+            # prepares the next call while the device runs this one, and
+            # its pairs are counted with the next call that is fetched
+            # (the device keeps a running sum).  With telemetry on every
+            # call is waited for anyway and the span gets its own counts.
+            if self.kv.cfg.window is not None and (fetch or observe.enabled()):
+                seen = np.asarray(self.state[1])
+                pairs, self._pairs_seen = seen - self._pairs_seen, seen
+                routed, hit = int(pairs.sum()), int((pairs > 0).sum())
+                sp.set(routed_pairs=routed, experts_hit=hit,
+                       window_tokens=window_tokens)
+                self._moe_pairs.inc(routed)
+                self._moe_hit.inc(hit)
+                self._moe_max.inc(int(pairs.max()))
         if not fetch:
             return None
         with observe.span("serve.tick.d2h", category="serve", program=name,
                           bytes=logits.nbytes):
+            if greedy:
+                tokens = _greedy(logits)
+                return np.asarray(logits), np.asarray(tokens)
             return np.asarray(logits)
 
     def _slot_arg(self, lane: _Lane) -> tuple:
-        """The last operand of a one-sequence program of a hybrid stack:
-        the lane's recurrent-state slot (nothing for the other families)."""
+        """The last operands of a one-sequence program: a hybrid stack's
+        lane slot, the afmoe family's window-group row and its first
+        position (nothing for the other families)."""
+        if self.kv.cfg.window is not None:
+            rows, first = self.kv.window_rows([lane.seq_id])
+            return jnp.asarray(rows), jnp.asarray(first)
         if self.kv.cfg.state is None:
             return ()
         return (jnp.asarray([self.kv.state_slot(lane.seq_id)], jnp.int32),)
+
+    def _window_room(self, lane: _Lane, start: int, end: int) -> None:
+        """Before a program writes positions ``[start, end)`` of ``lane``:
+        move its window group's pages along (a no-op without the group),
+        preempting the youngest other lane while the group's pool is
+        short."""
+        while True:
+            try:
+                self.kv.window_advance(lane.seq_id, start, end)
+                return
+            except OutOfPages:
+                victim = self._youngest_other(lane)
+                if victim is None:
+                    raise
+                self._preempt(victim, reason="window_pages")
+
+    def _window_tokens(self, context: int) -> int:
+        w = self.kv.cfg.window
+        return 0 if w is None else min(context, w.window)
 
     def _free_slot(self) -> Optional[int]:
         for s in range(self.scfg.max_batch):
@@ -716,6 +795,10 @@ class ServeEngine:
                 pass
             if need > self.kv.free_pages:
                 break  # retirement will free pages; keep FIFO order
+            if (self.kv.cfg.window is not None and self.kv.cfg.pages_for(
+                    min(len(req.tokens), self._chunk_cap()))
+                    > self.kv.window_free_pages):
+                break  # the window group is short of the first chunk's pages
             self.waiting.popleft()
             self._prefill(req, slot, shared)
 
@@ -785,6 +868,7 @@ class ServeEngine:
                     name = f"prefill-{bucket}"
                     with observe.span("serve.tick.tables", category="serve",
                                       program=name):
+                        self._window_room(lane, 0, L)
                         toks = np.zeros((1, bucket), np.int32)
                         toks[0, :L] = req.tokens
                         row = np.asarray(
@@ -795,8 +879,9 @@ class ServeEngine:
                         args = (jnp.asarray(toks),
                                 jnp.asarray([L], jnp.int32),
                                 jnp.asarray(row), *self._slot_arg(lane))
-                    logits = self._run_program(name, *args, lanes=1,
-                                               attended=L)
+                    logits = self._run_program(
+                        name, *args, lanes=1, attended=L,
+                        window_tokens=self._window_tokens(L))
                     lane.length = L
                     reqledger.on_event(req.rid, "prefill", bucket=bucket,
                                        n=L, replica=self.slo.name)
@@ -844,6 +929,7 @@ class ServeEngine:
             # written by this very sequence's earlier chunks); cow_page
             # no-ops at refcount 1, so this is unconditional.
             self._cow_for(lane, s // self.scfg.page_size)
+            self._window_room(lane, s, s + n)
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :n] = req.tokens[s:s + n]
             row = np.asarray(
@@ -853,8 +939,9 @@ class ServeEngine:
             args = (jnp.asarray(toks), jnp.asarray([s], jnp.int32),
                     jnp.asarray([s + n], jnp.int32), jnp.asarray(row),
                     *self._slot_arg(lane))
-        logits = self._run_program(name, *args, lanes=1, attended=s + n,
-                                   fetch=s + n >= L)
+        logits = self._run_program(
+            name, *args, lanes=1, attended=s + n,
+            window_tokens=self._window_tokens(s + n), fetch=s + n >= L)
         lane.length = s + n
         observe.counter("tdx.serve.prefill_chunks").inc()
         reqledger.on_chunk(req.rid, bucket=bucket, n_tokens=n,
@@ -1001,9 +1088,8 @@ class ServeEngine:
             table = np.zeros((B, maxp), np.int32)
             # One batched table build for the whole tick (the per-lane
             # Python loop was the decode hot path's host-side tax).
-            table[slots] = self.kv.table_rows(
-                [self.active[s].seq_id for s in slots], maxp
-            )
+            seq_ids = [self.active[s].seq_id for s in slots]
+            table[slots] = self.kv.table_rows(seq_ids, maxp)
             for slot in slots:
                 lane = self.active[slot]
                 tokens[slot] = (lane.generated[-1] if lane.generated
@@ -1011,14 +1097,26 @@ class ServeEngine:
                 positions[slot] = lane.length
             args = (jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(table))
+            window_tokens = 0
+            if self.kv.cfg.window is not None:
+                # The window group's rows (live pages only) and where each
+                # begins, behind the full group's table.
+                w = self.kv.cfg.window
+                wrows = np.zeros((B, w.max_pages_per_seq), np.int32)
+                wfirst = np.zeros((B,), np.int32)
+                wrows[slots], wfirst[slots] = self.kv.window_rows(seq_ids)
+                args += (jnp.asarray(wrows), jnp.asarray(wfirst))
+                window_tokens = int(np.minimum(positions[slots] + 1,
+                                               w.window).sum())
         n_lanes = len(slots)
         # A lane at position p attends over p + 1 tokens, its new one
         # included (idle lanes sit at 0 and attend over nothing).
         attended = int(positions.sum()) + n_lanes
         kv_blocks = kv_blocks_walked(positions[slots] + 1,
                                      *self._kernel_pool)
-        logits = self._run_program("decode", *args, lanes=n_lanes,
-                                   attended=attended, kv_blocks=kv_blocks)
+        logits, greedy = self._run_program(
+            "decode", *args, lanes=n_lanes, attended=attended,
+            kv_blocks=kv_blocks, window_tokens=window_tokens, greedy=True)
         with observe.span("serve.tick.emit", category="serve",
                           program="decode", tokens=n_lanes):
             # Per-token latency: every lane's next token took this step's
@@ -1042,7 +1140,7 @@ class ServeEngine:
                 if lane is None:  # pragma: no cover — nothing retires mid-loop
                     continue
                 lane.length += 1
-                self._emit(lane, int(np.argmax(logits[slot])), logits[slot])
+                self._emit(lane, int(greedy[slot]), logits[slot])
             self._decode_steps.inc()
             self._attended.inc(attended)
             self._kv_blocks.inc(kv_blocks)
@@ -1431,8 +1529,12 @@ def spin_up_replica(
                 on_cancel=on_cancel, slo_name=slo_name,
             )
             psp.block_on((engine.k_pages, engine.v_pages, engine.state))
+            windowed = engine.kv.cfg.window is not None
             psp.set(pool_bytes=engine.k_pages.nbytes + engine.v_pages.nbytes,
-                    state_bytes=sum(a.nbytes for a in engine.state))
+                    state_bytes=sum(a.nbytes for a in engine.state
+                                    if not windowed),
+                    window_pool_bytes=(engine.state[0].nbytes if windowed
+                                       else 0))
         # The spec list above already paid the model's deferred-init
         # trace; hand it to the engine so warmup/lazy compiles reuse it.
         engine._spec_cache = {s.name: s for s in specs if s.name != "init"}

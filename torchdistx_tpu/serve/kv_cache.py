@@ -56,6 +56,24 @@ the slot held (``serve/programs.py``), which is what "zeroed on
 admission" means here, and each binding counts one
 ``tdx.serve.state_resets``.
 
+**A third kind: the window group** (D7's third row).  A stack whose
+attention is windowed in some layers (``models/afmoe.py``) keeps those
+layers' keys and values in a pool of their own
+(:class:`WindowCacheConfig`, :func:`init_window_pool`), with its own
+free list and its own null page 0, because what a sequence holds there
+stops growing: a query at position ``t`` reads positions ``(t - window,
+t]``, so a page whose last position lies behind that is returned to the
+free list WHILE THE SEQUENCE LIVES (:meth:`PagedKVCache.window_advance`;
+:meth:`PagedKVCache.extend` does it for the one position of a decode
+tick).  A decoding sequence holds at most ``window / page_size + 1``
+pages there whatever its context; a prefill chunk of ``n`` positions
+holds ``(window + n) / page_size + 1`` while it runs.  The table row of
+the group (:meth:`PagedKVCache.window_rows`) holds the LIVE pages only,
+first live page first, and comes with the position of that page's
+first token, from which the programs count positions in the row.  A
+released page's content is gone: nothing of the group can be shared
+with a later reader, and a preempted sequence prefills again.
+
 Telemetry (docs/observability.md): ``tdx.serve.kv_pages_in_use``,
 ``tdx.serve.kv_occupancy`` (used token slots / allocated slots in live
 pages — the internal-fragmentation complement),
@@ -63,7 +81,11 @@ pages — the internal-fragmentation complement),
 ``tdx.serve.kv_pages_shared`` (refcount > 1 — the live copy-on-write
 exposure) gauges, refreshed on every mutation; with a state group also
 ``tdx.serve.state_slots_in_use`` and ``tdx.serve.state_slots_peak``
-(gauges) and ``tdx.serve.state_resets`` (counter, always on).
+(gauges) and ``tdx.serve.state_resets`` (counter, always on); with a
+window group ``tdx.serve.window_pages_in_use`` and
+``tdx.serve.window_pages_peak`` (gauges) and
+``tdx.serve.window_pages_released`` (counter, always on: pages returned
+behind a live sequence's window).
 """
 
 from __future__ import annotations
@@ -76,7 +98,8 @@ import numpy as np
 from .. import observe
 
 __all__ = ["KVCacheConfig", "OutOfPages", "PagedKVCache", "StateCacheConfig",
-           "init_pools", "init_state", "pool_sharding", "state_sharding"]
+           "WindowCacheConfig", "init_pools", "init_state",
+           "init_window_pool", "pool_sharding", "state_sharding"]
 
 
 class OutOfPages(RuntimeError):
@@ -109,10 +132,34 @@ class StateCacheConfig:
 
 
 @dataclass(frozen=True)
+class WindowCacheConfig:
+    """The window layer group of a cache: ``n_layers`` layers whose
+    queries read the last ``window`` positions only, in a pool of
+    ``n_pages`` pages (page 0 the group's null page) of the cache's page
+    size and heads.  ONE array holds keys and values, ``[2 * n_layers,
+    n_pages, KV, page, D]``: layer ``j``'s keys at row ``j``, its values
+    at ``n_layers + j``, so that a page's values lie ``n_layers *
+    n_pages`` flat rows behind its keys.  ``max_pages_per_seq`` is the
+    width of the group's table row: the pages a prefill chunk's window
+    can span."""
+
+    n_layers: int
+    window: int
+    n_pages: int
+    max_pages_per_seq: int
+
+    @property
+    def usable_pages(self) -> int:
+        return self.n_pages - 1
+
+
+@dataclass(frozen=True)
 class KVCacheConfig:
     """Shape of the cache, by layer group: the attention layers' pool
-    (one K and one V pool over ``n_layers`` layers) and, where the stack
-    has recurrent layers, their ``state`` group."""
+    (one K and one V pool over ``n_layers`` layers: the layers that read
+    the whole context), where the stack has recurrent layers their
+    ``state`` group, and where it has windowed attention layers their
+    ``window`` group."""
 
     n_layers: int
     kv_heads: int
@@ -120,6 +167,7 @@ class KVCacheConfig:
     page_size: int = 16
     n_pages: int = 64  # includes the reserved null page 0
     state: Optional[StateCacheConfig] = None
+    window: Optional[WindowCacheConfig] = None
 
     @property
     def usable_pages(self) -> int:
@@ -142,11 +190,21 @@ class KVCacheConfig:
         return (self.n_layers, self.n_pages, self.kv_heads,
                 self.page_size, self.head_dim)
 
+    def window_pool_shape(self) -> Tuple[int, int, int, int, int]:
+        """[2 * Lw, Pw, KV, page, D]: the window group's one array."""
+        w = self.window
+        return (2 * w.n_layers, w.n_pages, self.kv_heads, self.page_size,
+                self.head_dim)
+
 
 @dataclass
 class _Seq:
     pages: List[int] = field(default_factory=list)
     length: int = 0  # tokens currently stored
+    # The window group: the live pages, and which page of the sequence
+    # the first of them is (pages behind it went back to the free list).
+    wpages: List[int] = field(default_factory=list)
+    wfirst: int = 0
 
 
 class PagedKVCache:
@@ -179,6 +237,11 @@ class PagedKVCache:
         self._slot_of: Dict[int, int] = {}
         self.state_slots_peak = 0
         self._resets = observe.counter("tdx.serve.state_resets")
+        # The window group's own free list (its page 0 is its null page).
+        self._wfree: List[int] = [] if cfg.window is None else list(
+            range(cfg.window.n_pages - 1, 0, -1))
+        self.window_pages_peak = 0
+        self._wreleased = observe.counter("tdx.serve.window_pages_released")
         self._update_gauges()
 
     # -- queries ------------------------------------------------------------
@@ -200,6 +263,18 @@ class PagedKVCache:
     @property
     def state_slots_in_use(self) -> int:
         return len(self._slot_of)
+
+    @property
+    def window_free_pages(self) -> int:
+        return len(self._wfree)
+
+    @property
+    def window_pages_in_use(self) -> int:
+        w = self.cfg.window
+        return 0 if w is None else w.usable_pages - len(self._wfree)
+
+    def window_page_ids(self, seq_id: int) -> List[int]:
+        return list(self._seqs[seq_id].wpages)
 
     def state_slot(self, seq_id: int) -> int:
         """The slot that holds the sequence's recurrent state."""
@@ -233,8 +308,19 @@ class PagedKVCache:
         for zero external fragmentation."""
         return 0.0 if not self._seqs else 1.0 - self.occupancy()
 
-    def can_fit(self, n_tokens: int) -> bool:
-        return self.cfg.pages_for(n_tokens) <= len(self._free)
+    def can_fit(self, n_tokens: int,
+                window_tokens: Optional[int] = None) -> bool:
+        """Whether a new sequence of ``n_tokens`` fits: its pages in the
+        full group and, with a window group, the pages of its first
+        ``window_tokens`` positions there (its first prefill chunk; all
+        of it by default)."""
+        if self.cfg.pages_for(n_tokens) > len(self._free):
+            return False
+        if self.cfg.window is None:
+            return True
+        first = n_tokens if window_tokens is None else min(
+            n_tokens, window_tokens)
+        return self.cfg.pages_for(first) <= len(self._wfree)
 
     # -- mutations ----------------------------------------------------------
 
@@ -292,6 +378,10 @@ class PagedKVCache:
             raise ValueError(
                 "a cache with a recurrent state group shares no pages: a "
                 "prefix's pages hold no state to resume from")
+        if self.cfg.window is not None:
+            raise ValueError(
+                "a cache with a window group shares no pages: a prefix's "
+                "window pages are gone once its first reader has moved on")
         shared = list(shared_pages)
         need = self.cfg.pages_for(n_tokens) - len(shared)
         if need < 0:
@@ -367,11 +457,53 @@ class PagedKVCache:
         self._update_gauges()
         return src, dst
 
+    def window_advance(self, seq_id: int, start: int, end: int) -> int:
+        """Make the window group hold the pages that the program writing
+        positions ``[start, end)`` of ``seq_id`` needs — those of
+        positions ``(start - window, end)`` — returning the pages that
+        now lie wholly behind the window to the free list first.  Returns
+        how many were returned.  Raises :class:`OutOfPages`, changing
+        nothing, when the free list (with what would be returned) cannot
+        cover the rest; a no-op for a cache without the group."""
+        w = self.cfg.window
+        if w is None:
+            return 0
+        seq, page = self._seqs[seq_id], self.cfg.page_size
+        first = max(0, start - w.window + 1) // page
+        behind = min(max(0, first - seq.wfirst), len(seq.wpages))
+        kept = len(seq.wpages) - behind
+        # With nothing live left the row restarts at the window's first page.
+        new_first = seq.wfirst + behind if kept else max(seq.wfirst, first)
+        add = max(0, (end - 1) // page + 1 - (new_first + kept))
+        if kept + add > w.max_pages_per_seq:
+            raise ValueError(
+                f"sequence {seq_id}: positions [{start}, {end}) and their "
+                f"window span {kept + add} pages, the group's table row "
+                f"holds {w.max_pages_per_seq}")
+        if add > len(self._wfree) + behind:
+            raise OutOfPages(
+                f"sequence {seq_id} needs {add} more window pages, "
+                f"{len(self._wfree)} free and {behind} to return")
+        if behind:
+            self._wfree.extend(reversed(seq.wpages[:behind]))
+            del seq.wpages[:behind]
+            self._wreleased.inc(behind)
+        seq.wfirst = new_first
+        seq.wpages.extend(self._wfree.pop() for _ in range(add))
+        if behind or add:
+            self.window_pages_peak = max(self.window_pages_peak,
+                                         self.window_pages_in_use)
+            self._update_gauges()
+        return behind
+
     def extend(self, seq_id: int, new_length: int) -> List[int]:
         """Grow ``seq_id`` to hold ``new_length`` tokens, allocating at
-        most the pages the growth needs; returns the pages ADDED.  On
-        :class:`OutOfPages` nothing changes — the engine preempts a
-        victim and retries."""
+        most the pages the growth needs; returns the pages ADDED to the
+        full group.  With a window group the sequence's window moves with
+        it (:meth:`window_advance` for the new positions): past the
+        window the group's oldest page goes back to its free list.  On
+        :class:`OutOfPages` nothing changes in either group — the engine
+        preempts a victim and retries."""
         seq = self._seqs[seq_id]
         if new_length < seq.length:
             raise ValueError(
@@ -383,6 +515,8 @@ class PagedKVCache:
                 f"sequence {seq_id} needs {need} more pages, "
                 f"{len(self._free)} free"
             )
+        if self.cfg.window is not None and new_length > seq.length:
+            self.window_advance(seq_id, seq.length, new_length)
         added = [self._free.pop() for _ in range(max(0, need))]
         for p in added:
             self._ref[p] = 1
@@ -417,6 +551,12 @@ class PagedKVCache:
         seq.length = new_length
         if dropped:
             self.release(dropped)
+        # The window group loses its trailing pages alike (what it gave
+        # back behind the window stays gone: a rollback reaches a few
+        # positions, never a window).
+        wkeep = max(0, keep - seq.wfirst)
+        self._wfree.extend(reversed(seq.wpages[wkeep:]))
+        del seq.wpages[wkeep:]
         self._update_gauges()
         return len(dropped)
 
@@ -430,6 +570,7 @@ class PagedKVCache:
         if seq is None:
             return 0
         self._slot_of.pop(seq_id, None)  # the state is dropped, not saved
+        self._wfree.extend(reversed(seq.wpages))
         freed = []
         for p in seq.pages:
             if self._ref[p] == 1:
@@ -449,6 +590,8 @@ class PagedKVCache:
         self._ref.clear()
         self._slot_of.clear()
         self._free = list(range(self.cfg.n_pages - 1, 0, -1))
+        if self.cfg.window is not None:
+            self._wfree = list(range(self.cfg.window.n_pages - 1, 0, -1))
         self._update_gauges()
 
     # -- batch views --------------------------------------------------------
@@ -480,6 +623,21 @@ class PagedKVCache:
             rows[i, :len(pages)] = pages
         return rows
 
+    def window_rows(self, seq_ids: Sequence[int]):
+        """The window group's operands for these sequences: (one
+        null-padded row of LIVE pages each, ``[n, max_pages_per_seq]``
+        int32; the position of each row's first token, ``[n]`` int32).
+        A program counts a sequence's positions in the group from that
+        first token."""
+        w = self.cfg.window
+        rows = np.zeros((len(seq_ids), w.max_pages_per_seq), np.int32)
+        first = np.zeros((len(seq_ids),), np.int32)
+        for i, sid in enumerate(seq_ids):
+            seq = self._seqs[sid]
+            rows[i, :len(seq.wpages)] = seq.wpages
+            first[i] = seq.wfirst * self.cfg.page_size
+        return rows, first
+
     # -- telemetry ----------------------------------------------------------
 
     def _update_gauges(self) -> None:
@@ -495,6 +653,11 @@ class PagedKVCache:
                 len(self._slot_of))
             observe.gauge("tdx.serve.state_slots_peak").set(
                 self.state_slots_peak)
+        if self.cfg.window is not None:
+            observe.gauge("tdx.serve.window_pages_in_use").set(
+                self.window_pages_in_use)
+            observe.gauge("tdx.serve.window_pages_peak").set(
+                self.window_pages_peak)
 
 
 def pool_sharding(mesh, kv_heads: int, tp_axis: str = "tp"):
@@ -538,6 +701,14 @@ def init_state(cfg: StateCacheConfig, dtype,
 
     return (jnp.zeros(cfg.ssm_shape(), jnp.float32, device=sharding),
             jnp.zeros(cfg.conv_shape(), dtype, device=sharding))
+
+
+def init_window_pool(cfg: KVCacheConfig, dtype, sharding=None) -> "jax.Array":
+    """The zeroed window group, keys and values in one array
+    (:class:`WindowCacheConfig`): ``[2 * Lw, Pw, KV, page, D]``."""
+    import jax.numpy as jnp
+
+    return jnp.zeros(cfg.window_pool_shape(), dtype, device=sharding)
 
 
 def init_pools(cfg: KVCacheConfig, dtype,

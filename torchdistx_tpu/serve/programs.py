@@ -68,13 +68,14 @@ from jax.sharding import PartitionSpec as P
 
 from .. import abstract, chaos, compile_service, observe, transport
 from .. import config as tdx_config
-from ..models import TransformerConfig, make_gpt2, make_jamba, make_llama
-from ..models import jamba
+from ..models import (TransformerConfig, make_afmoe, make_gpt2, make_jamba,
+                      make_llama)
+from ..models import afmoe, jamba
 from ..models.layers import MLP, apply_rope, default_attention, make_norm
 from ..ops import paged_attention, paged_prefill_attention
 from ..utils.logging import get_logger
-from .kv_cache import (KVCacheConfig, StateCacheConfig, pool_sharding,
-                       state_sharding)
+from .kv_cache import (KVCacheConfig, StateCacheConfig, WindowCacheConfig,
+                       pool_sharding, state_sharding)
 
 __all__ = [
     "ServeConfig",
@@ -122,6 +123,10 @@ class ServeConfig:
     spec_buckets: Tuple[int, ...] = ()       # default: (2, 4)
     spec_decode: Optional[bool] = None
     spec_k: Optional[int] = None
+    # Pages of the window layer group's pool, its null page included (a
+    # stack with windowed attention layers only, ``cfg.afmoe``; default:
+    # every lane's window, one chunk's overhang and the null page).
+    n_window_pages: Optional[int] = None
 
     def resolve(self, cfg: TransformerConfig) -> "ResolvedServeConfig":
         page = self.page_size
@@ -159,6 +164,29 @@ class ServeConfig:
         if spec_k is None:
             spec_k = tdx_config.get().spec_k
         spec_k = max(1, min(spec_k, spec_buckets[-1]))
+        if cfg.afmoe is not None and (spec_on or self.prefix_cache):
+            on = [n for n, v in (("spec_decode", spec_on),
+                                 ("prefix_cache", self.prefix_cache)) if v]
+            raise ValueError(
+                f"{' and '.join(on)} cannot be on for a stack with windowed "
+                f"attention layers: a sequence returns its window group's "
+                f"pages behind the window while it lives, so a shared "
+                f"prefix's window pages are gone once its first reader has "
+                f"moved on (prefix cache), and the family has no verify-<k> "
+                f"program yet (speculation); pass "
+                f"ServeConfig(spec_decode=False, prefix_cache=False)")
+        n_window_pages = window_row = 0
+        if cfg.afmoe is not None:
+            # A chunk of the largest bucket reads its window too; a row of
+            # the group's table holds the pages both can span.
+            w = cfg.afmoe.window
+            window_row = -(-(w + buckets[-1]) // page) + 1
+            n_window_pages = self.n_window_pages or (
+                self.max_batch * (w // page + 2) + window_row + 1)
+            if n_window_pages - 1 < window_row:
+                raise ValueError(
+                    f"n_window_pages={n_window_pages} cannot hold one "
+                    f"sequence's chunk and window ({window_row} pages)")
         if cfg.mamba is not None and (spec_on or self.prefix_cache):
             on = [n for n, v in (("spec_decode", spec_on),
                                  ("prefix_cache", self.prefix_cache)) if v]
@@ -175,7 +203,8 @@ class ServeConfig:
             max_new_tokens=self.max_new_tokens, max_context=max_context,
             prefill_chunk=chunk, prefix_cache=self.prefix_cache,
             spec_buckets=spec_buckets, spec_decode=bool(spec_on),
-            spec_k=spec_k,
+            spec_k=spec_k, n_window_pages=n_window_pages,
+            window_pages_per_seq=window_row,
         )
 
 
@@ -196,12 +225,22 @@ class ResolvedServeConfig:
     spec_buckets: Tuple[int, ...] = (2, 4)  # compiled verify-<k> family
     spec_decode: bool = True    # speculation armed (host-side knob)
     spec_k: int = 4             # max draft length (host-side knob)
+    n_window_pages: int = 0     # the window group's pool (0: no such group)
+    window_pages_per_seq: int = 0  # width of the group's table row
 
     def kv_config(self, cfg: TransformerConfig) -> KVCacheConfig:
-        """The cache's layer groups: pages for the attention layers (all
-        layers of a gpt2 / llama stack) and, for a hybrid stack, one
-        state slot a lane for its Mamba layers."""
-        if cfg.mamba is None:
+        """The cache's layer groups: pages for the attention layers that
+        read the whole context (all layers of a gpt2 / llama stack), for
+        a hybrid stack one state slot a lane for its Mamba layers, and
+        for a stack with windowed attention layers their window group."""
+        window = None
+        if cfg.afmoe is not None:
+            n_attn, state = afmoe.n_full_layers(cfg), None
+            window = WindowCacheConfig(
+                n_layers=afmoe.n_window_layers(cfg),
+                window=cfg.afmoe.window, n_pages=self.n_window_pages,
+                max_pages_per_seq=self.window_pages_per_seq)
+        elif cfg.mamba is None:
             n_attn, state = cfg.n_layers, None
         else:
             n_attn = jamba.n_attn_layers(cfg)
@@ -212,7 +251,7 @@ class ResolvedServeConfig:
         return KVCacheConfig(
             n_layers=n_attn, kv_heads=cfg.kv_heads,
             head_dim=cfg.head_size, page_size=self.page_size,
-            n_pages=self.n_pages, state=state,
+            n_pages=self.n_pages, state=state, window=window,
         )
 
     def bucket_for(self, n_tokens: int) -> int:
@@ -235,13 +274,14 @@ class ResolvedServeConfig:
         )
 
 
-FAMILIES = ("gpt2", "llama", "jamba")
+FAMILIES = ("gpt2", "llama", "jamba", "afmoe")
 
 
 def model_family(name: str) -> str:
-    """The decode family of a zoo preset name: gpt2 and jamba presets by
-    name, any other dense decoder serves through the llama path."""
-    for family in ("gpt2", "jamba"):
+    """The decode family of a zoo preset name: gpt2, jamba and afmoe
+    presets by name, any other dense decoder serves through the llama
+    path."""
+    for family in ("gpt2", "jamba", "afmoe"):
         if family in name:
             return family
     return "llama"
@@ -250,9 +290,18 @@ def model_family(name: str) -> str:
 def make_model(family: str, cfg: TransformerConfig):
     if cfg.moe is not None:
         raise NotImplementedError(
-            f"the serving runtime covers the dense decoder families "
-            f"({', '.join(FAMILIES)}); MoE decode is future work"
+            f"the serving runtime has no program for the capacity-based "
+            f"MoEMLP (cfg.moe: tokens over capacity are dropped, no shared "
+            f"expert, every expert held); the expert layer it serves is the "
+            f"afmoe family's (cfg.afmoe: sigmoid router, dropless grouped "
+            f"products over the experts the replica holds). Families: "
+            f"{', '.join(FAMILIES)}"
         )
+    if (family == "afmoe") != (cfg.afmoe is not None):
+        raise ValueError(
+            f"decode family {family!r} with cfg.afmoe="
+            f"{'set' if cfg.afmoe is not None else 'None'}: the afmoe "
+            f"family, and no other, takes a config with cfg.afmoe")
     if (family == "jamba") != (cfg.mamba is not None):
         raise ValueError(
             f"decode family {family!r} with cfg.mamba="
@@ -264,6 +313,8 @@ def make_model(family: str, cfg: TransformerConfig):
         return make_llama(cfg)
     if family == "jamba":
         return make_jamba(cfg)
+    if family == "afmoe":
+        return make_afmoe(cfg)
     raise ValueError(
         f"unknown decode family {family!r}; the families that exist: "
         f"{' | '.join(FAMILIES)}")
@@ -327,31 +378,30 @@ def _decode_attention(mesh, kv_heads: int) -> Callable:
     )
 
 
-def _write_kv(kp, vp, base, table, k, v, start, end):
-    """Write ``k`` and ``v`` [B, S, KV, D] — the rows of positions
-    ``[start, start + S)`` of each sequence, valid below ``end`` — into
-    the flat pools [L*P, KV, page, D], in which this layer's page ``p``
-    is row ``base + p``, through the page table [B, maxp], a whole page
-    at a time: the pages the positions fall into are read, their valid
-    rows replaced, and the pages written back.  The update then indexes
-    the pool's major dim alone and its window is a page, so XLA keeps
-    the pool in the layout the decode kernel reads and updates the
-    scan's carry in place.  A scatter that indexes the slot too
+def _page_writer(table, start, end, S: int, page_size: int) -> Callable:
+    """``write(pool, x, base)``: put ``x`` [B, S, KV, D] — the rows of
+    positions ``[start, start + S)`` of each sequence, valid below
+    ``end`` — into the flat pool [rows, KV, page, D], in which page ``p``
+    of the layer is row ``base + p``, through the page table [B, maxp],
+    a whole page at a time: the pages the positions fall into are read,
+    their valid rows replaced, and the pages written back.  The update
+    then indexes the pool's major dim alone and its window is a page, so
+    XLA keeps the pool in the layout the decode kernel reads and updates
+    the scan's carry in place.  A scatter that indexes the slot too
     (``pool.at[page, :, slot].set``) makes XLA lay the pool out with the
     kv heads under the token rows, and a carried pool then changes
     layout, whole, twice a layer; one that indexes every kv head costs a
     2,048-token chunk what the carry saves (PERF.md, PR 27).  A page
     with no valid position (padding) is routed to the layer's null page,
     which gets back what it held."""
-    B, S, KV, D = k.shape
-    page_size = kp.shape[2]
+    B = table.shape[0]
     n = (S + page_size - 2) // page_size + 1  # pages S positions can span
     ords = (start // page_size)[:, None] + jnp.arange(n, dtype=jnp.int32)
     pos = ords[:, :, None] * page_size + jnp.arange(page_size,
                                                     dtype=jnp.int32)
-    src = pos - start[:, None, None]  # [B, n, page]: the slot's row of k/v
+    src = pos - start[:, None, None]  # [B, n, page]: the slot's row of x
     valid = (src >= 0) & (src < S) & (pos < end[:, None, None])
-    rows = base + jnp.where(
+    pages = jnp.where(
         valid.any(-1),
         jnp.take_along_axis(
             table, jnp.minimum(ords, table.shape[1] - 1), axis=1),
@@ -359,12 +409,21 @@ def _write_kv(kp, vp, base, table, k, v, start, end):
     src = jnp.clip(src, 0, S - 1).reshape(B, -1, 1, 1)
     valid = valid[:, :, None, :, None]
 
-    def write(pool, x):
+    def write(pool, x, base):
+        KV, D = x.shape[2:]
+        rows = base + pages
         new = jnp.take_along_axis(x, src, axis=1)
         new = new.reshape(B, n, page_size, KV, D).transpose(0, 1, 3, 2, 4)
         return pool.at[rows].set(jnp.where(valid, new, pool[rows]))
 
-    return write(kp, k), write(vp, v)
+    return write
+
+
+def _write_kv(kp, vp, base, table, k, v, start, end):
+    """Write ``k`` and ``v`` [B, S, KV, D] into the flat pools [L*P, KV,
+    page, D] at this layer's rows (:func:`_page_writer`)."""
+    write = _page_writer(table, start, end, k.shape[1], kp.shape[2])
+    return write(kp, k, base), write(vp, v, base)
 
 
 def _decode_block(cfg, blk, x, kp, vp, base, *, angles, positions,
@@ -663,6 +722,138 @@ def _build_hybrid_prefill_fn(cfg, scfg, bucket, *, chunked: bool) -> Callable:
     return _program_name(f"tdx_serve_{kind}_{bucket}")(fn)
 
 
+# ---------------------------------------------------------------------------
+# the afmoe family (models/afmoe.py): a window group beside the pools
+# ---------------------------------------------------------------------------
+#
+# The programs carry two more arrays behind the pools, as a hybrid stack's
+# carry its state: the window layer group's pool (keys and values in one
+# array, serve/kv_cache.py WindowCacheConfig) and the pairs each held
+# expert has got (int32 [expert layers, held], a running count on the
+# device that every call adds its own to and the engine reads the change
+# of), and take the window group's table row and its first token's
+# position behind the full group's,
+#
+#   decode       (params, k_pages, v_pages, w_pages, pairs, tokens [B],
+#                 positions [B], page_table [B, maxp], wtable [B, wrow],
+#                 wfirst [B])
+#   prefill-<b>  (params, k_pages, v_pages, w_pages, pairs, tokens [1, b],
+#                 length [1], page_table [1, maxp], wtable [1, wrow],
+#                 wfirst [1])
+#   chunk-<b>    (params, k_pages, v_pages, w_pages, pairs, tokens [1, b],
+#                 start [1], end [1], page_table [1, maxp],
+#                 wtable [1, wrow], wfirst [1])
+#   -> (logits, k_pages, v_pages, w_pages, pairs)
+#
+# A full-attention layer writes and reads its pages of the full group as
+# every other family's layers do.  A window layer's row holds its LIVE
+# pages only, so it counts positions from ``wfirst``: it writes at
+# ``position - wfirst`` and attends ``[max(0, end - window), end)`` less
+# ``wfirst``, at most ``window`` keys (decode) or ``chunk + window``
+# (a chunk) whatever the context.  One builder makes all three kinds: the
+# layers are walked by a Python loop (models/afmoe.py says why), and what
+# differs between the kinds is how a layer attends.  No verify-<k> and no
+# cow program: nothing of a window group can be shared or rolled back far.
+
+
+def _build_afmoe_fn(cfg, scfg, mesh, kind: str, bucket=None) -> Callable:
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "the afmoe programs are one chip's share of a layer group: the "
+            "exchange that adds the other chips' heads and experts is not "
+            "built yet, so there is nothing for a mesh to run")
+    a = cfg.afmoe
+    rows = afmoe.group_rows(cfg)
+    P, Pw = scfg.n_pages, scfg.n_window_pages
+    v_off = afmoe.n_window_layers(cfg) * Pw  # a window page's values
+    page = scfg.page_size
+
+    def body(params, k_pages, v_pages, w_pages, pairs, tokens, start, end,
+             page_table, wtable, wfirst):
+        p = afmoe.param_tree(params["params"])
+        S = tokens.shape[1]
+        positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        valid = positions < end[:, None]
+        x = afmoe.embed_tokens(cfg, p, tokens)
+        shapes = (k_pages.shape, v_pages.shape, w_pages.shape)
+        flat = lambda a_: a_.reshape((-1,) + a_.shape[2:])
+        pool = {"k": flat(k_pages), "v": flat(v_pages), "w": flat(w_pages)}
+        rel_end = jnp.maximum(end - wfirst, 0)
+        write_full = _page_writer(page_table, start, end, S, page)
+        write_win = _page_writer(wtable, start - wfirst, rel_end, S, page)
+
+        def attend(q, k, v, sliding, j):
+            """Write the call's keys and values into the layer's pages,
+            then attend as the kind does."""
+            if sliding:
+                base = j * Pw
+                pool["w"] = write_win(write_win(pool["w"], k, base), v,
+                                      base + v_off)
+                kp = vp = pool["w"]
+                table, length, window = wtable + base, rel_end, a.window
+                qpos, voff = positions - wfirst[:, None], v_off
+            else:
+                base = j * P
+                pool["k"] = write_full(pool["k"], k, base)
+                pool["v"] = write_full(pool["v"], v, base)
+                kp, vp = pool["k"], pool["v"]
+                table, length, window = page_table + base, end, None
+                qpos, voff = positions, 0
+            if kind == "prefill":  # a fresh prompt attends only itself
+                return afmoe.dense_attention(q, k, v, positions, end, window)
+            if kind == "chunk":
+                return paged_prefill_attention(
+                    q, kp, vp, qpos, length, table, window=window,
+                    v_page_offset=voff)
+            starts = None if window is None else jnp.maximum(
+                length - window, 0)
+            return paged_attention(q[:, 0], kp, vp, length, table,
+                                   starts=starts, v_page_offset=voff)[:, None]
+
+        counts = []
+        for i, lp in enumerate(p["layers"]):
+            sliding, j = rows[i]
+
+            def attention(h, lp=lp, sliding=sliding, j=j):
+                q, k, v, g = afmoe.qkvg(cfg, lp, h, positions, sliding)
+                return afmoe.attn_out(cfg, lp, attend(q, k, v, sliding, j), g)
+
+            x, got = afmoe.block(cfg, lp, i, x, valid, attention)
+            if got is not None:
+                counts.append(got)
+        if kind == "decode":
+            logits = afmoe.head_logits(cfg, p, x)[:, 0]
+        else:
+            last = jnp.clip(end - 1 - start, 0, S - 1)[:, None, None]
+            x_last = jnp.take_along_axis(x, jnp.broadcast_to(
+                last, (x.shape[0], 1, x.shape[2])), axis=1)
+            logits = afmoe.head_logits(cfg, p, x_last)[0, 0]
+        return (logits, *(pool[n].reshape(sh)
+                          for n, sh in zip("kvw", shapes)),
+                pairs + jnp.stack(counts).astype(jnp.int32))
+
+    if kind == "decode":
+        def fn(params, k_pages, v_pages, w_pages, pairs, tokens, positions,
+               page_table, wtable, wfirst):
+            # Idle and mid-prefill lanes (position 0) attend and route
+            # nothing: a length of 0, the kernel's idle contract.
+            end = jnp.where(positions > 0, positions + 1, 0)
+            return body(params, k_pages, v_pages, w_pages, pairs,
+                        tokens[:, None], positions, end, page_table, wtable,
+                        wfirst)
+
+        return _program_name("tdx_serve_decode")(fn)
+    if kind == "prefill":
+        def fn(params, k_pages, v_pages, w_pages, pairs, tokens, length,
+               page_table, wtable, wfirst):
+            return body(params, k_pages, v_pages, w_pages, pairs, tokens,
+                        jnp.zeros_like(length), length, page_table, wtable,
+                        wfirst)
+    else:
+        fn = body
+    return _program_name(f"tdx_serve_{kind}_{bucket}")(fn)
+
+
 def build_decode_fn(family: str, cfg: TransformerConfig,
                     scfg: ResolvedServeConfig, mesh=None) -> Callable:
     """The batched decode-step program:
@@ -673,6 +864,8 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
     the null page, their logits are ignored).  A hybrid stack's programs
     carry the recurrent state too (the section above)."""
     model = make_model(family, cfg)
+    if cfg.afmoe is not None:
+        return _build_afmoe_fn(cfg, scfg, mesh, "decode")
     if cfg.mamba is not None:
         return _build_hybrid_decode_fn(cfg, scfg, mesh)
     decomp = model.decode_decomposition()
@@ -712,6 +905,8 @@ def build_prefill_fn(family: str, cfg: TransformerConfig,
     page_table [1, maxp]) -> (logits [vocab], k_pages, v_pages)`` —
     logits are the LAST VALID position's (the first generated token)."""
     model = make_model(family, cfg)
+    if cfg.afmoe is not None:
+        return _build_afmoe_fn(cfg, scfg, None, "prefill", bucket)
     if cfg.mamba is not None:
         return _build_hybrid_prefill_fn(cfg, scfg, bucket, chunked=False)
     decomp = model.decode_decomposition()
@@ -754,6 +949,8 @@ def build_chunk_prefill_fn(family: str, cfg: TransformerConfig,
     Logits are the last valid position's: meaningful (the first
     generated token) only on the final chunk, ignored otherwise."""
     model = make_model(family, cfg)
+    if cfg.afmoe is not None:
+        return _build_afmoe_fn(cfg, scfg, None, "chunk", bucket)
     if cfg.mamba is not None:
         return _build_hybrid_prefill_fn(cfg, scfg, bucket, chunked=True)
     decomp = model.decode_decomposition()
@@ -802,6 +999,11 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
     batched sibling of :func:`build_chunk_prefill_fn`: same
     ``_chunk_block`` scatter-and-ragged-attend per layer, but every lane
     at once and the head applied to every position instead of the last."""
+    if cfg.afmoe is not None:
+        raise NotImplementedError(
+            "no verify-<k> program for the afmoe family yet: a rejected "
+            "draft would have to be rolled back out of a window group that "
+            "has already returned the pages behind it")
     if cfg.mamba is not None:
         raise NotImplementedError(
             "no verify-<k> program for a stack with recurrent layers: the "
@@ -864,8 +1066,8 @@ class ServeProgramSpec:
     the registry fingerprint.
 
     ``consumes`` are the positions in ``args`` of the arrays the program
-    CONSUMES: the two pools and, for a hybrid stack, the two state
-    arrays.  They are donated to the compiled program
+    CONSUMES: the two pools and, behind them, a hybrid stack's two state
+    arrays or the afmoe family's window pool and pair counts.  They are donated to the compiled program
     (:func:`compile_serving_program`), which returns each in the buffer
     it came in: after a call the arrays passed in are deleted and the
     caller owns the outputs instead (docs/serving.md §Who owns the
@@ -902,7 +1104,8 @@ def _fp(kind: str, family: str, cfg: TransformerConfig,
     shape = () if kind == "init" else (
         scfg.max_batch, scfg.page_size, scfg.n_pages,
         scfg.max_pages_per_seq, scfg.prefill_buckets,
-    )
+    ) + ((scfg.n_window_pages, scfg.window_pages_per_seq)
+         if scfg.n_window_pages else ())
     # v4: the pools and the recurrent state are donated and aliased to
     # the outputs (v3: the pools became the layer scan's carry) — same
     # key material, other compiled bytes, so artifacts published under
@@ -1003,7 +1206,21 @@ def serve_program_specs(
     # behind them (such a stack's one-sequence programs also take the
     # lane's slot; it has no cow and no verify programs).
     carried, carried_sh = (pool_sds, pool_sds), (pool_sh, pool_sh)
-    slot_sds = ()
+    slot_sds = lane_sds = ()  # operands behind a one-sequence / lanes table
+    if kv.window is not None:
+        # The window group and the held experts' pair counts, and behind
+        # every page table the group's own row and its first position.
+        carried += (
+            jax.ShapeDtypeStruct(kv.window_pool_shape(), cfg.dtype,
+                                 sharding=pool_sh),
+            jax.ShapeDtypeStruct((afmoe.n_expert_layers(cfg),
+                                  cfg.afmoe.held_experts), i32))
+        carried_sh += (pool_sh, None)
+        wrow = kv.window.max_pages_per_seq
+        slot_sds = (jax.ShapeDtypeStruct((1, wrow), i32),
+                    jax.ShapeDtypeStruct((1,), i32))
+        lane_sds = (jax.ShapeDtypeStruct((B, wrow), i32),
+                    jax.ShapeDtypeStruct((B,), i32))
     if kv.state is not None:
         st_sh = state_sharding(mesh, kv.state.d_inner)
         carried += (
@@ -1069,16 +1286,17 @@ def serve_program_specs(
             f"chunk-{b}", build_chunk_prefill_fn(family, cfg, scfg, b),
             jax.ShapeDtypeStruct((1, b), i32), one, one,
             jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds))
-    if kv.state is None:
+    pages_only = kv.state is None and kv.window is None
+    if pages_only:
         specs.append(program("cow", build_cow_fn(), one, one, model=False))
     specs.append(program(
         "decode", build_decode_fn(family, cfg, scfg, mesh),
-        lanes, lanes, jax.ShapeDtypeStruct((B, maxp), i32)))
+        lanes, lanes, jax.ShapeDtypeStruct((B, maxp), i32), *lane_sds))
     # The verify-<k> family is part of every replica shape's program set
     # REGARDLESS of the spec_decode host knob: warm once, then flip
     # speculation on or off without invalidating a byte of the registry
     # (the fingerprint-host-knob invariance test pins this).
-    for k in (scfg.spec_buckets if kv.state is None else ()):
+    for k in (scfg.spec_buckets if pages_only else ()):
         specs.append(program(
             f"verify-{k}", build_verify_fn(family, cfg, scfg, k),
             jax.ShapeDtypeStruct((B, k + 1), i32), lanes, lanes,
