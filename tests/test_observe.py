@@ -608,7 +608,9 @@ class TestServeTickSpans:
         assert (prog["program"], prog["lanes"]) == ("decode", 2)
         assert prog["attended_tokens"] == want
         assert by["serve.tick.emit"]["args"]["tokens"] == 2
-        assert by["serve.tick.d2h"]["args"]["bytes"] == 2 * 128 * 4
+        # What a plain tick brings: an int32 a lane of the batch (the
+        # [2, 128] float32 logits stay on the device).
+        assert by["serve.tick.d2h"]["args"]["bytes"] == 2 * 4
         assert by["serve.admit"]["args"]["admitted"] == 0
         step = by["serve.step"]
         for name in TICK_SPANS[1:]:  # all children of serve.step

@@ -126,6 +126,34 @@ def _greedy(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@jax.jit
+def _row(logits, slot):
+    """Row ``slot`` of a plain tick's logits.  The slot is an OPERAND: one
+    compiled program serves every lane, and a replica's first retirement in
+    a decode tick has met it.  A row taken with an index array
+    (``logits[np.array(slots)]``) would compile once for every count of
+    lanes that retire together, inside a serving window (PERF.md section 6,
+    PRs 35 and 36)."""
+    return jax.lax.dynamic_index_in_dim(logits, slot, axis=0, keepdims=False)
+
+
+class _TickRow:
+    """One lane's logits of a plain decode tick, left on the device: the
+    tick's whole ``[max_batch, vocab]`` array and the lane's slot.  Whoever
+    reads it as an array (``np.asarray``: ``_retire``, once a lane) brings
+    that one row to the host, bit for bit the row that a fetch of the whole
+    array held."""
+
+    __slots__ = ("logits", "slot")
+
+    def __init__(self, logits, slot: int):
+        self.logits, self.slot = logits, slot
+
+    def __array__(self, dtype=None, copy=None):
+        observe.counter("tdx.serve.logit_rows_fetched").inc()
+        return np.asarray(_row(self.logits, self.slot), dtype)
+
+
 @dataclass
 class Request:
     """One generation request.  ``arrival_step`` simulates staggered
@@ -689,10 +717,15 @@ class ServeEngine:
         """Call the compiled model program ``name`` on the params, the
         pools, the recurrent state (a hybrid stack) and ``args`` under
         ``serve.program`` (``state_lanes``: the lanes whose recurrent
-        state the call advances) and bring its logits to the host under
-        ``serve.tick.d2h`` (``fetch`` False: a chunk that is not a
-        prompt's last, whose logits nobody reads; ``greedy``: also every
-        row's greedy choice, made on the device: ``(logits, tokens)``).
+        state the call advances) and bring its result to the host under
+        ``serve.tick.d2h`` (``bytes``: what came).  That is the logits;
+        nothing with ``fetch`` False (a chunk that is not a prompt's last,
+        whose logits nobody reads); with ``greedy`` (a plain decode tick)
+        every row's greedy choice, made on the device, while the logits
+        stay there: ``(logits on the device, tokens on the host)``.  A row
+        of 65,536 floats is 262 KB; 128 of them took 11.7 ms of a 37 ms
+        tick to fetch, for a host that reads a row only when its lane
+        retires (``_TickRow``; PERF.md section 6, PR 36).
         While telemetry is on
         ``serve.program`` ends when the logits are ready, so the two spans
         split device time from the copy; off, nothing waits before the
@@ -731,12 +764,11 @@ class ServeEngine:
                 self._moe_max.inc(int(pairs.max()))
         if not fetch:
             return None
+        coming = _greedy(logits) if greedy else logits
         with observe.span("serve.tick.d2h", category="serve", program=name,
-                          bytes=logits.nbytes):
-            if greedy:
-                tokens = _greedy(logits)
-                return np.asarray(logits), np.asarray(tokens)
-            return np.asarray(logits)
+                          bytes=coming.nbytes):
+            host = np.asarray(coming)
+        return (logits, host) if greedy else host
 
     def _slot_arg(self, lane: _Lane) -> tuple:
         """The last operands of a one-sequence program: a hybrid stack's
@@ -1140,7 +1172,7 @@ class ServeEngine:
                 if lane is None:  # pragma: no cover — nothing retires mid-loop
                     continue
                 lane.length += 1
-                self._emit(lane, int(greedy[slot]), logits[slot])
+                self._emit(lane, int(greedy[slot]), _TickRow(logits, slot))
             self._decode_steps.inc()
             self._attended.inc(attended)
             self._kv_blocks.inc(kv_blocks)
@@ -1326,7 +1358,10 @@ class ServeEngine:
             self._attended.inc(attended)
             self._lane_ticks.inc(n_lanes)
 
-    def _emit(self, lane: _Lane, token: int, logits: np.ndarray) -> None:
+    def _emit(self, lane: _Lane, token: int,
+              logits: "np.ndarray | _TickRow") -> None:
+        """Hand ``token`` over for ``lane``; its ``logits`` are read only
+        if that ends the lane (``final_logits``)."""
         lane.generated.append(token)
         if self._drafter is not None:
             # One (order-gram -> token) pair per emitted token: the
@@ -1354,7 +1389,7 @@ class ServeEngine:
         if done:
             self._retire(lane, logits)
 
-    def _retire(self, lane: _Lane, logits: np.ndarray) -> None:
+    def _retire(self, lane: _Lane, logits: "np.ndarray | _TickRow") -> None:
         self.kv.free(lane.seq_id)
         self.active.pop(lane.slot, None)
         self._delivered.pop(lane.req.rid, None)
