@@ -272,8 +272,9 @@ def test_walk_mixed_batch(lengths):
                                         (jnp.bfloat16, 3e-2)])
 @pytest.mark.parametrize("H,KV,D", [(20, 1, 128),   # Jamba: Gp 24
                                     (32, 8, 128),   # Mistral / Llama-3
-                                    (4, 4, 64)],    # GPT-2: MHA, 64
-                         ids=["jamba", "mistral", "gpt2"])
+                                    (4, 4, 64),     # GPT-2: MHA, 64
+                                    (30, 30, 128)],  # Olmo-Hybrid: group 1
+                         ids=["jamba", "mistral", "gpt2", "olmo-hybrid"])
 def test_walk_head_layouts_of_the_served_families(H, KV, D, dtype, atol):
     page, maxp = 16, 36
     lengths = [maxp * page, 0, 530, 17]

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from torchdistx_tpu import compile_service, observe
-from torchdistx_tpu.models import TINY, TINY_AFMOE, TINY_JAMBA
+from torchdistx_tpu.models import TINY, TINY_AFMOE, TINY_JAMBA, TINY_OLMO_HYBRID
 from torchdistx_tpu.observe.costmodel import program_costs
 from torchdistx_tpu.serve import (Request, ServeConfig, ServeEngine,
                                   serve_program_specs)
@@ -27,11 +27,15 @@ FAMILIES = {
     # the full group's two pools
     "afmoe": (TINY_AFMOE, ServeConfig(**SHAPE, prefix_cache=False,
                                       spec_decode=False)),
+    # the delta-rule state and the conv tail over q, k and v's channels
+    "olmo_hybrid": (TINY_OLMO_HYBRID, ServeConfig(**SHAPE, prefix_cache=False,
+                                                  spec_decode=False)),
 }
 PROGRAMS = {
     "llama": ("prefill-8", "chunk-8", "cow", "decode", "verify-2"),
     "jamba": ("prefill-8", "chunk-8", "decode"),
     "afmoe": ("prefill-8", "chunk-8", "decode"),
+    "olmo_hybrid": ("prefill-8", "chunk-8", "decode"),
 }
 KINDS = [(f, p) for f, ps in PROGRAMS.items() for p in ps]
 
@@ -157,7 +161,8 @@ class _FailsOnce:
 @pytest.mark.parametrize("family,program,nth", [
     ("llama", "decode", 3), ("llama", "prefill-8", 2), ("llama", "chunk-8", 2),
     ("llama", "cow", 1), ("jamba", "decode", 3), ("jamba", "prefill-8", 2),
-    ("jamba", "chunk-8", 2), ("afmoe", "decode", 3), ("afmoe", "chunk-8", 2)])
+    ("jamba", "chunk-8", 2), ("afmoe", "decode", 3), ("afmoe", "chunk-8", 2),
+    ("olmo_hybrid", "decode", 3), ("olmo_hybrid", "chunk-8", 2)])
 def test_fault_inside_a_donated_call_rebuilds_the_pools(built, family,
                                                         program, nth):
     assert issubclass(jax.errors.JaxRuntimeError,

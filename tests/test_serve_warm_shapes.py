@@ -53,6 +53,10 @@ FAMILIES = {
         max_batch=4, page_size=8, n_pages=24, max_pages_per_seq=8,
         prefill_buckets=(8, 16), prefill_chunk=16, prefix_cache=False,
         spec_decode=False), True),
+    "olmo_hybrid": ("tiny-olmo-hybrid", ServeConfig(
+        max_batch=4, page_size=8, n_pages=20, max_pages_per_seq=8,
+        prefill_buckets=(8, 16), prefix_cache=False, spec_decode=False),
+        False),
 }
 
 

@@ -189,8 +189,10 @@ def one_chip():
     (8, 25, 25, 64, 16, 48, jnp.bfloat16),     # gpt2-xl: ``_page_kernel``
     (32, 32, 8, 128, 16, 800, jnp.bfloat16),   # Mistral under mixed-queue
     (128, 6, 1, 128, 16, 800, jnp.bfloat16),   # trinity: the full layer
+    (128, 30, 30, 128, 16, 48, jnp.bfloat16),  # olmo-hybrid: MHA, group 1
 ], ids=["mistral-48", "mistral-260", "jamba", "tp2-shard", "float32",
-        "page8-bf16", "gpt2-xl", "mistral-800", "trinity-full"])
+        "page8-bf16", "gpt2-xl", "mistral-800", "trinity-full",
+        "olmo-hybrid-full"])
 def test_paged_attention_compiles_for_v5e(one_chip, B, H, KV, D, page, maxp,
                                           dtype):
     def arg(shape, dt):
@@ -223,6 +225,40 @@ def test_paged_attention_from_a_first_position_compiles_for_v5e(one_chip):
     assert "tdx_paged_attention_decode" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kernel, S", [("decode", 1), ("chunk", 128),
+                                       ("chunk", 512)])
+def test_the_delta_rule_kernels_compile_for_v5e(one_chip, kernel, S):
+    """The two Gated DeltaNet kernels at Olmo-Hybrid's widths (30 heads,
+    d_k 96, d_v 192): the decode update on the whole state of 6 linear
+    layers and 128 lanes, in place; the chunk on one sequence of a
+    bucket's positions.  The state stays one buffer (aliased)."""
+    from torchdistx_tpu.ops import gdn
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    if kernel == "decode":
+        fn = jax.jit(lambda st, lay, q, k, v, b, g, nv: gdn.gdn_decode_update(
+            st, lay, q, k, v, b, g, nv, interpret=False), donate_argnums=0)
+        compiled = fn.lower(
+            arg((6, 128, 96, 5760), f32), arg((), i32),
+            arg((128, 30, 96), bf), arg((128, 30, 96), bf),
+            arg((128, 30, 192), bf), arg((128, 30), f32),
+            arg((128, 30), f32), arg((128,), i32)).compile()
+        assert compiled.memory_analysis().alias_size_in_bytes == (
+            6 * 128 * 96 * 5760 * 4)
+    else:
+        fn = jax.jit(lambda q, k, v, b, g, s0, nv: gdn.gdn_chunk(
+            q, k, v, b, g, s0, nv, interpret=False))
+        compiled = fn.lower(
+            arg((S, 30, 96), bf), arg((S, 30, 96), bf),
+            arg((S, 30, 192), bf), arg((S, 30), f32), arg((S, 30), f32),
+            arg((30, 96, 192), f32), arg((), i32)).compile()
+    assert getattr(gdn, kernel.upper() if kernel == "chunk"
+                   else "DECODE_UPDATE") in compiled.as_text()
+
+
 # -- the serving programs, whole, at the benchmark's cells' widths ------------
 #
 # Every program that takes the pools consumes them and returns them in
@@ -239,6 +275,8 @@ _CELLS = {
     "jamba2-3b-chat-backlog": ("jamba2-3b", "chat-backlog-wide"),
     "trinity-large-mixed-queue": ("trinity-large-preview-tp8-d5",
                                   "mixed-queue"),
+    "olmo-hybrid-7b-d8-chat-backlog": ("olmo-hybrid-7b-d8",
+                                       "chat-backlog-wide"),
     # no cell of the manifest (PERF.md 7: measured and left out in PR 34);
     # the dense stack at the same traffic's widths, 800 pages a sequence
     "mistral-under-mixed-queue": ("mistral-7b-v0.3-d12", "mixed-queue"),
@@ -262,7 +300,7 @@ def _cell_specs(cell):
         cfg = json.load(f)
     with open(os.path.join(root, "benchmark", "traffic", mix + ".json")) as f:
         engine = json.load(f)["engine"]
-    if cfg["family"] in ("jamba", "afmoe"):
+    if cfg["family"] in ("jamba", "afmoe", "olmo_hybrid"):
         from benchmark import harness
 
         fam = harness.load_module(root, cfg["family_module"])
@@ -299,6 +337,12 @@ _ALIAS_CASES = [
     ("trinity-large-mixed-queue", "decode", ()),
     ("trinity-large-mixed-queue", "prefill-256", ()),
     ("trinity-large-mixed-queue", "chunk-2048", ()),
+    # The delta-rule stack: the decode kernel works in place on the whole
+    # state; the one-sequence programs re-lay the conv tail (0.05 GB) for
+    # their lane slice as jamba's do.
+    ("olmo-hybrid-7b-d8-chat-backlog", "decode", ()),
+    ("olmo-hybrid-7b-d8-chat-backlog", "prefill-128", (4,)),
+    ("olmo-hybrid-7b-d8-chat-backlog", "chunk-512", (4,)),
     ("mistral-under-mixed-queue", "decode", ()),
     ("mistral-under-mixed-queue", "chunk-2048", ()),
 ]

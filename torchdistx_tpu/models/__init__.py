@@ -1,4 +1,5 @@
-"""Model families: GPT-2, Llama, T5, Mixtral, ViT — flax.linen, TPU-first."""
+"""Model families: GPT-2, Llama, T5, Mixtral, ViT, and the hybrid stacks
+(Jamba, AFMoE, Olmo-Hybrid) — flax.linen, TPU-first."""
 
 from .configs import (
     GPT2_125M,
@@ -12,12 +13,14 @@ from .configs import (
     TINY_GPT2,
     TINY_JAMBA,
     TINY_MOE,
+    TINY_OLMO_HYBRID,
     TINY_T5,
     TINY_VIT,
     VIT_B16,
     VIT_L16,
     AfmoeConfig,
     EncDecConfig,
+    GatedDeltaNetConfig,
     MambaConfig,
     MoEConfig,
     TransformerConfig,
@@ -28,6 +31,7 @@ from .afmoe import AfmoeModel, make_afmoe
 from .gpt2 import GPT2Model, make_gpt2
 from .jamba import JambaModel, make_jamba
 from .llama import LlamaModel, make_llama
+from .olmo_hybrid import OlmoHybridModel, make_olmo_hybrid
 from .mixtral import make_mixtral
 from .plans import decoder_lm_plan, t5_plan, vit_plan
 from .t5 import T5Model, make_t5
@@ -40,6 +44,7 @@ __all__ = [
     "VisionConfig",
     "MoEConfig",
     "MambaConfig",
+    "GatedDeltaNetConfig",
     "PRESETS",
     "GPT2_125M",
     "LLAMA3_8B",
@@ -51,6 +56,7 @@ __all__ = [
     "TINY_GPT2",
     "TINY_JAMBA",
     "TINY_MOE",
+    "TINY_OLMO_HYBRID",
     "TINY_T5",
     "TINY_VIT",
     "VIT_B16",
@@ -60,6 +66,7 @@ __all__ = [
     "GPT2Model",
     "JambaModel",
     "LlamaModel",
+    "OlmoHybridModel",
     "PipelineDecomposition",
     "T5Model",
     "ViTModel",
@@ -68,6 +75,7 @@ __all__ = [
     "make_jamba",
     "make_llama",
     "make_mixtral",
+    "make_olmo_hybrid",
     "make_t5",
     "make_vit",
     "decoder_lm_plan",

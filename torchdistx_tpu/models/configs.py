@@ -36,6 +36,25 @@ class MambaConfig:
 
 
 @dataclass(frozen=True)
+class GatedDeltaNetConfig:
+    """A hybrid stack's linear-attention side (Olmo-Hybrid;
+    models/olmo_hybrid.py): Gated DeltaNet mixers in every layer but one a
+    period, which is a full-attention layer.  A linear layer's state is a
+    ``[d_k, d_v]`` matrix a head, advanced by a gated rank-one delta rule;
+    ``allow_neg_eigval`` widens the rule's step ``beta`` from (0, 1) to
+    (0, 2).  Layer ``i`` is a full-attention layer iff
+    ``i % attn_period == attn_offset``."""
+
+    n_heads: int = 30       # key heads = value heads
+    d_k: int = 96
+    d_v: int = 192
+    d_conv: int = 4
+    allow_neg_eigval: bool = True
+    attn_period: int = 4
+    attn_offset: int = 3
+
+
+@dataclass(frozen=True)
 class AfmoeConfig:
     """The AFMoE stack (arcee-ai Trinity; models/afmoe.py): sandwich-normed
     blocks with gated, QK-normed attention that is windowed (with rotary)
@@ -81,6 +100,8 @@ class TransformerConfig:
     moe: Optional[MoEConfig] = None
     mamba: Optional[MambaConfig] = None  # hybrid stack (models/jamba.py)
     afmoe: Optional[AfmoeConfig] = None  # AFMoE stack (models/afmoe.py)
+    # hybrid linear-attention stack (models/olmo_hybrid.py)
+    olmo_hybrid: Optional[GatedDeltaNetConfig] = None
 
     dtype: jnp.dtype = jnp.bfloat16  # activation/compute dtype
     param_dtype: jnp.dtype = jnp.float32
@@ -258,6 +279,16 @@ TINY_JAMBA = TransformerConfig(
                       attn_period=14, attn_offset=7),
 )
 
+# Two whole periods of Olmo-Hybrid's pattern (three Gated DeltaNet layers,
+# then full attention with QK-norm), no positions, untied head.
+TINY_OLMO_HYBRID = TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=4,
+    head_dim=16, d_ff=128, max_seq_len=256, positions="none",
+    tie_embeddings=False, norm_eps=1e-6, dtype=jnp.float32,
+    olmo_hybrid=GatedDeltaNetConfig(n_heads=2, d_k=16, d_v=32, d_conv=4,
+                                    attn_period=4, attn_offset=3),
+)
+
 TINY_AFMOE = TransformerConfig(
     vocab_size=256, d_model=64, n_layers=5, n_heads=4, n_kv_heads=1,
     head_dim=16, d_ff=128, max_seq_len=256, rope_theta=10000.0,
@@ -303,6 +334,7 @@ PRESETS = {
     "tiny-moe": TINY_MOE,
     "tiny-jamba": TINY_JAMBA,
     "tiny-afmoe": TINY_AFMOE,
+    "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "tiny-t5": TINY_T5,
     "tiny-vit": TINY_VIT,
 }

@@ -192,17 +192,20 @@ def _row(tree, i):
 
 
 def scan_layers(cfg, p, x, carry, mamba_layer: Callable,
-                attn_layer: Callable):
+                attn_layer: Callable, *, counts=None, rec: str = "mamba"):
     """Thread ``(x, carry)`` through the stack.  ``mamba_layer(m, f, x,
     carry, g)`` and ``attn_layer(a, f, x, carry, j)`` get a layer's rows
-    of ``p["mamba"]`` / ``p["attn"]`` and ``p["ffn"]`` and its index
-    AMONG ITS KIND (``g`` of the Mamba layers, ``j`` of the attention
-    layers: the row of a cache that holds only that kind), and return
-    ``(x, carry)``.  ``carry`` is whatever the caller caches (pools and
-    states, or nothing); it is the scans' carry, so a layer updates it
-    in place."""
-    periods, pre, post = layer_counts(cfg)
-    per = cfg.mamba.attn_period
+    of ``p[rec]`` (the recurrent layers' group, ``"mamba"`` here) /
+    ``p["attn"]`` and ``p["ffn"]`` and its index AMONG ITS KIND (``g`` of
+    the recurrent layers, ``j`` of the attention layers: the row of a
+    cache that holds only that kind), and return ``(x, carry)``.
+    ``carry`` is whatever the caller caches (pools and states, or
+    nothing); it is the scans' carry, so a layer updates it in place.
+    ``counts`` (periods, recurrent layers before a period's attention
+    layer, after) is this family's :func:`layer_counts` unless another
+    family with the same pattern passes its own (models/olmo_hybrid.py)."""
+    periods, pre, post = counts or layer_counts(cfg)
+    per = pre + post + 1
     i32 = jnp.int32
 
     def mamba_run(x, carry, first_layer, first_g, n):
@@ -211,7 +214,7 @@ def scan_layers(cfg, p, x, carry, mamba_layer: Callable,
 
         def body(c, j):
             x, carry = c
-            return mamba_layer(_row(p["mamba"], first_g + j),
+            return mamba_layer(_row(p[rec], first_g + j),
                                _row(p["ffn"], first_layer + j),
                                x, carry, first_g + j), None
 
@@ -228,6 +231,31 @@ def scan_layers(cfg, p, x, carry, mamba_layer: Callable,
 
     return jax.lax.scan(period, (x, carry),
                         jnp.arange(periods, dtype=i32))[0]
+
+
+def block(cfg, f, x, mixer: Callable):
+    """One layer around its mixer, pre-norm: ``h = x + mixer(RMSNorm(x))``,
+    ``y = h + MLP(RMSNorm(h))``; ``f`` the layer's row of ``p["ffn"]``."""
+    eps = cfg.norm_eps
+    x = x + mixer(rms_norm(x, f["norm0"], eps))
+    return x + mlp(cfg, f, rms_norm(x, f["norm1"], eps))
+
+
+def serve_mixer(cfg, m, h, ssm, conv, g, mixer_state):
+    """The Mamba mixer as the serving programs run it on the cache's state
+    (serve/programs.py, the hybrid builders): ``mixer_state(ssm, conv, g)``
+    -> ``(s, tail, n_valid, put)`` gives the rows of layer ``g`` that the
+    call advances and how to put them back.  Returns ``(out, ssm, conv)``."""
+    # Reading the layer's rows and putting them back is part of the
+    # recurrence it belongs to: the write fuses with the update, and the
+    # fusion takes its root's name.
+    scope = DECODE_UPDATE if h.shape[1] == 1 else CHUNK_SCAN
+    with jax.named_scope(scope):
+        s, tail, n_valid, put = mixer_state(ssm, conv, g)
+    out, s, tail = mamba_mixer(cfg, m, h, s, tail, n_valid)
+    with jax.named_scope(scope):
+        ssm, conv = put(ssm, conv, g, s, tail)
+    return out, ssm, conv
 
 
 def embed_tokens(cfg, p, tokens):

@@ -96,7 +96,7 @@ import numpy as np
 
 from .. import chaos, compile_service, observe, transport
 from ..observe import reqledger
-from ..models import PRESETS, TransformerConfig
+from ..models import PRESETS, TransformerConfig, olmo_hybrid
 from ..ops.paged_attention import kv_blocks_walked
 from ..utils.logging import get_logger
 from .kv_cache import (OutOfPages, PagedKVCache, init_pools, init_state,
@@ -301,6 +301,13 @@ class ServeEngine:
         self._moe_pairs = observe.counter("tdx.serve.moe_routed_pairs")
         self._moe_hit = observe.counter("tdx.serve.moe_experts_hit")
         self._moe_max = observe.counter("tdx.serve.moe_pairs_max_expert")
+        # The olmo_hybrid family: positions advanced through the delta
+        # rule, summed over its linear layers, by decode ticks and by
+        # prefill / chunk calls (always on).
+        self._gdn_layers = (olmo_hybrid.n_linear_layers(cfg)
+                            if cfg.olmo_hybrid is not None else 0)
+        self._gdn_decode = observe.counter("tdx.serve.gdn_decode_positions")
+        self._gdn_prefill = observe.counter("tdx.serve.gdn_prefill_positions")
 
     # -- pools ----------------------------------------------------------------
     #
@@ -712,8 +719,9 @@ class ServeEngine:
                 chaos.execute(fault)
 
     def _run_program(self, name: str, *args, lanes: int, attended: int,
-                     kv_blocks: int = 0, window_tokens: int = 0,
-                     fetch: bool = True, greedy: bool = False):
+                     positions: int = 0, kv_blocks: int = 0,
+                     window_tokens: int = 0, fetch: bool = True,
+                     greedy: bool = False):
         """Call the compiled model program ``name`` on the params, the
         pools, the recurrent state (a hybrid stack) and ``args`` under
         ``serve.program`` (``state_lanes``: the lanes whose recurrent
@@ -730,19 +738,30 @@ class ServeEngine:
         ``serve.program`` ends when the logits are ready, so the two spans
         split device time from the copy; off, nothing waits before the
         fetch.  ``attended`` is the context the program's ``lanes`` attend
-        over, counted before anything retires; ``kv_blocks`` the blocks
-        the decode kernel walks for it (a program that attends through
+        over, counted before anything retires; ``positions`` the real
+        positions the call advances its lanes by, all lanes together (a
+        decode tick's live lanes, a prefill's or a chunk's tokens): with
+        Gated DeltaNet layers, times their number, they are the span's
+        ``gdn_positions`` and go to ``tdx.serve.gdn_decode_positions``
+        (decode) or ``tdx.serve.gdn_prefill_positions``; ``kv_blocks``
+        the blocks the decode kernel walks for it (a program that attends through
         jnp gathers walks none).  With a window group ``window_tokens`` is
         what the lanes attend over in a window layer (``min(context,
         window)`` each), and the call's pair counts come to the host with
         the logits: ``routed_pairs`` (pairs that landed on a held expert,
         over the expert layers) and ``experts_hit`` (held experts with at
         least one) on the span, and the ``tdx.serve.moe_*`` counters."""
+        gdn = positions * self._gdn_layers
+        if gdn:
+            (self._gdn_decode if name == "decode"
+             else self._gdn_prefill).inc(gdn)
         with observe.span("serve.program", category="serve", program=name,
                           lanes=lanes, attended_tokens=attended,
                           kv_blocks=kv_blocks,
                           state_lanes=(lanes if self.kv.cfg.state is not None
-                                       else 0)) as sp:
+                                       else 0),
+                          **({"gdn_positions": gdn} if self._gdn_layers
+                             else {})) as sp:
             logits, self.k_pages, self.v_pages, *state = self._program(name)(
                 self.params, self.k_pages, self.v_pages, *self.state, *args)
             self.state = tuple(state)
@@ -912,7 +931,7 @@ class ServeEngine:
                                 jnp.asarray([L], jnp.int32),
                                 jnp.asarray(row), *self._slot_arg(lane))
                     logits = self._run_program(
-                        name, *args, lanes=1, attended=L,
+                        name, *args, lanes=1, attended=L, positions=L,
                         window_tokens=self._window_tokens(L))
                     lane.length = L
                     reqledger.on_event(req.rid, "prefill", bucket=bucket,
@@ -972,7 +991,7 @@ class ServeEngine:
                     jnp.asarray([s + n], jnp.int32), jnp.asarray(row),
                     *self._slot_arg(lane))
         logits = self._run_program(
-            name, *args, lanes=1, attended=s + n,
+            name, *args, lanes=1, attended=s + n, positions=n,
             window_tokens=self._window_tokens(s + n), fetch=s + n >= L)
         lane.length = s + n
         observe.counter("tdx.serve.prefill_chunks").inc()
@@ -1148,6 +1167,7 @@ class ServeEngine:
                                      *self._kernel_pool)
         logits, greedy = self._run_program(
             "decode", *args, lanes=n_lanes, attended=attended,
+            positions=n_lanes,
             kv_blocks=kv_blocks, window_tokens=window_tokens, greedy=True)
         with observe.span("serve.tick.emit", category="serve",
                           program="decode", tokens=n_lanes):

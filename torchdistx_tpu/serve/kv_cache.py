@@ -42,10 +42,10 @@ contents), so no reader of a shared page ever observes a mutation.
 
 **Two kinds of state** (the start of ROADMAP D7).  A cache is described
 by its layer groups: the attention layers' pages above, and, for a
-stack with recurrent layers (``models/jamba.py``), a
-:class:`StateCacheConfig`: one **slot** a batch lane holding that lane's
-recurrent state in every Mamba layer, of a fixed size whatever the
-context.  A slot is not paged and not shared: it is bound to a sequence
+stack with recurrent layers (``models/jamba.py``,
+``models/olmo_hybrid.py``), a :class:`StateCacheConfig`: one **slot** a
+batch lane holding that lane's recurrent state in every recurrent
+(Mamba or Gated DeltaNet) layer, of a fixed size whatever the context.  A slot is not paged and not shared: it is bound to a sequence
 when the sequence is allocated (``alloc(..., slot=lane)``) and dropped
 with it (:meth:`PagedKVCache.free`) — there is nothing to return to a
 free list, and nothing of it survives a preemption, so a preempted
@@ -111,24 +111,31 @@ class OutOfPages(RuntimeError):
 @dataclass(frozen=True)
 class StateCacheConfig:
     """The recurrent layer group of a cache: ``lanes`` slots, each the
-    state of one sequence in all ``n_layers`` Mamba layers.  The SSM
-    state is float32 ``[L, lanes, d_state, d_inner]`` — channels minor:
-    a minor dim of ``d_state`` = 16 would pad to the chip's 128 lanes,
-    eight times the bytes — and the conv tail (the last ``d_conv - 1``
-    inputs of the depthwise conv) ``[L, d_conv-1, lanes, d_inner]`` in
-    the activation dtype, lanes second-minor for the same reason."""
+    state of one sequence in all ``n_layers`` recurrent layers.  The
+    state is float32 ``[L, lanes, d_state, d_inner]`` — its wide side
+    minor: a Mamba layer's ``[16, d_inner]`` (a minor dim of ``d_state``
+    = 16 would pad to the chip's 128 lanes, eight times the bytes), a
+    Gated DeltaNet layer's ``[d_k, H * d_v]`` (a minor dim of ``d_v`` =
+    192 would pad to 256) — and the conv tail (the last ``d_conv - 1``
+    inputs of the depthwise conv) ``[L, d_conv-1, lanes, conv_channels]``
+    in the activation dtype, lanes second-minor for the same reason.
+    The conv runs over ``d_inner`` channels in a Mamba layer and over
+    q, k and v's (``2 H d_k + H d_v``) in a delta-rule layer:
+    ``conv_channels`` None is ``d_inner``."""
 
     n_layers: int
     d_inner: int
     d_state: int
     d_conv: int
     lanes: int
+    conv_channels: Optional[int] = None
 
     def ssm_shape(self) -> Tuple[int, int, int, int]:
         return (self.n_layers, self.lanes, self.d_state, self.d_inner)
 
     def conv_shape(self) -> Tuple[int, int, int, int]:
-        return (self.n_layers, self.d_conv - 1, self.lanes, self.d_inner)
+        return (self.n_layers, self.d_conv - 1, self.lanes,
+                self.conv_channels or self.d_inner)
 
 
 @dataclass(frozen=True)

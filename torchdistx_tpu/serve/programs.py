@@ -69,8 +69,8 @@ from jax.sharding import PartitionSpec as P
 from .. import abstract, chaos, compile_service, observe, transport
 from .. import config as tdx_config
 from ..models import (TransformerConfig, make_afmoe, make_gpt2, make_jamba,
-                      make_llama)
-from ..models import afmoe, jamba
+                      make_llama, make_olmo_hybrid)
+from ..models import afmoe, jamba, olmo_hybrid
 from ..models.layers import MLP, apply_rope, default_attention, make_norm
 from ..ops import paged_attention, paged_prefill_attention
 from ..utils.logging import get_logger
@@ -187,12 +187,13 @@ class ServeConfig:
                 raise ValueError(
                     f"n_window_pages={n_window_pages} cannot hold one "
                     f"sequence's chunk and window ({window_row} pages)")
-        if cfg.mamba is not None and (spec_on or self.prefix_cache):
+        if _recurrent(cfg) and (spec_on or self.prefix_cache):
             on = [n for n, v in (("spec_decode", spec_on),
                                  ("prefix_cache", self.prefix_cache)) if v]
+            kind = "Mamba" if cfg.mamba is not None else "Gated DeltaNet"
             raise ValueError(
                 f"{' and '.join(on)} cannot be on for a stack with recurrent "
-                f"(Mamba) layers: a recurrent state is one value a lane, not "
+                f"({kind}) layers: a recurrent state is one value a lane, not "
                 f"a row a token, so a rejected draft cannot be rolled back "
                 f"out of it (verify-<k>) and a shared prefix's pages hold no "
                 f"state to resume from (prefix cache); pass "
@@ -231,8 +232,11 @@ class ResolvedServeConfig:
     def kv_config(self, cfg: TransformerConfig) -> KVCacheConfig:
         """The cache's layer groups: pages for the attention layers that
         read the whole context (all layers of a gpt2 / llama stack), for
-        a hybrid stack one state slot a lane for its Mamba layers, and
-        for a stack with windowed attention layers their window group."""
+        a hybrid stack one state slot a lane for its recurrent layers
+        (Mamba: ``[16, d_inner]`` and a conv over ``d_inner`` channels;
+        Gated DeltaNet: ``[d_k, H d_v]`` and a conv over q, k and v's
+        channels), and for a stack with windowed attention layers their
+        window group."""
         window = None
         if cfg.afmoe is not None:
             n_attn, state = afmoe.n_full_layers(cfg), None
@@ -240,6 +244,13 @@ class ResolvedServeConfig:
                 n_layers=afmoe.n_window_layers(cfg),
                 window=cfg.afmoe.window, n_pages=self.n_window_pages,
                 max_pages_per_seq=self.window_pages_per_seq)
+        elif cfg.olmo_hybrid is not None:
+            n_attn = olmo_hybrid.n_full_layers(cfg)
+            H, dk, dv, HV, C = olmo_hybrid.widths(cfg)
+            state = StateCacheConfig(
+                n_layers=olmo_hybrid.n_linear_layers(cfg), d_inner=HV,
+                d_state=dk, d_conv=cfg.olmo_hybrid.d_conv,
+                lanes=self.max_batch, conv_channels=C)
         elif cfg.mamba is None:
             n_attn, state = cfg.n_layers, None
         else:
@@ -274,17 +285,22 @@ class ResolvedServeConfig:
         )
 
 
-FAMILIES = ("gpt2", "llama", "jamba", "afmoe")
+FAMILIES = ("gpt2", "llama", "jamba", "afmoe", "olmo_hybrid")
 
 
 def model_family(name: str) -> str:
-    """The decode family of a zoo preset name: gpt2, jamba and afmoe
-    presets by name, any other dense decoder serves through the llama
-    path."""
-    for family in ("gpt2", "jamba", "afmoe"):
-        if family in name:
+    """The decode family of a zoo preset name: gpt2, jamba, afmoe and
+    olmo_hybrid (``tiny-olmo-hybrid``) presets by name, any other dense
+    decoder serves through the llama path."""
+    for family in ("gpt2", "jamba", "afmoe", "olmo_hybrid"):
+        if family.replace("_", "-") in name:
             return family
     return "llama"
+
+
+def _recurrent(cfg: TransformerConfig) -> bool:
+    """Whether the stack has recurrent layers (a state slot a lane)."""
+    return cfg.mamba is not None or cfg.olmo_hybrid is not None
 
 
 def make_model(family: str, cfg: TransformerConfig):
@@ -307,6 +323,12 @@ def make_model(family: str, cfg: TransformerConfig):
             f"decode family {family!r} with cfg.mamba="
             f"{'set' if cfg.mamba is not None else 'None'}: the jamba "
             f"family, and no other, takes a config with Mamba layers")
+    if (family == "olmo_hybrid") != (cfg.olmo_hybrid is not None):
+        raise ValueError(
+            f"decode family {family!r} with cfg.olmo_hybrid="
+            f"{'set' if cfg.olmo_hybrid is not None else 'None'}: the "
+            f"olmo_hybrid family, and no other, takes a config with Gated "
+            f"DeltaNet layers")
     if family == "gpt2":
         return make_gpt2(cfg)
     if family == "llama":
@@ -315,6 +337,8 @@ def make_model(family: str, cfg: TransformerConfig):
         return make_jamba(cfg)
     if family == "afmoe":
         return make_afmoe(cfg)
+    if family == "olmo_hybrid":
+        return make_olmo_hybrid(cfg)
     raise ValueError(
         f"unknown decode family {family!r}; the families that exist: "
         f"{' | '.join(FAMILIES)}")
@@ -542,7 +566,8 @@ def _scan_blocks(decomp, p, x, k_pages, v_pages, block_step):
 
 
 # ---------------------------------------------------------------------------
-# hybrid stacks (models/jamba.py): recurrent layers beside attention layers
+# hybrid stacks: recurrent layers beside attention layers (models/jamba.py,
+# models/olmo_hybrid.py)
 # ---------------------------------------------------------------------------
 #
 # The programs of a hybrid stack take and return two more arrays than the
@@ -558,56 +583,71 @@ def _scan_blocks(decomp, p, x, k_pages, v_pages, block_step):
 #   -> (logits, k_pages, v_pages, ssm, conv)
 #
 # and all four ride the layer loops as their carry, as PR 27 made the
-# pools ride: a Mamba layer reads and writes row ``g`` of the state (all
-# lanes in decode, lane ``slot`` in a prefill), an attention layer its
-# pages at ``j * P + page``.  There is no verify-<k> and no cow program:
-# a recurrent state cannot be rolled back and shares nothing.
+# pools ride: a recurrent layer reads and writes row ``g`` of the state
+# (all lanes in decode, lane ``slot`` in a prefill), an attention layer
+# its pages at ``j * P + page``.  There is no verify-<k> and no cow
+# program: a recurrent state cannot be rolled back and shares nothing.
+#
+# One set of builders serves every such family; the family's model module
+# supplies what differs: its parameter tree, embedding and head, the walk
+# over its layer pattern (``scan_layers``), the block around a mixer (``block``:
+# pre-norm for jamba, post-norm for olmo_hybrid), the attention layer's
+# projections (``qkv`` / ``attn_out``) and the recurrent mixer on the
+# cache's state (``serve_mixer(cfg, m, h, ssm, conv, g, mixer_state)``).
+# ``mixer_state(ssm, conv, g)`` -> ``(s, tail, n_valid, put)`` is the
+# builders' state accessor: the rows of layer ``g`` the call advances and
+# how to put them back; a decode tick's is marked ``every_lane`` (with
+# its ``n_valid``), which a mixer whose kernel works in place on the whole
+# state takes instead.
 
 
-def _hybrid_layers(cfg, mixer_state, attention):
-    """The two layer bodies of :func:`models.jamba.scan_layers` over the
-    carry ``(kp, vp, ssm, conv)``.  ``mixer_state(ssm, conv, g)`` ->
-    ``(s, tail, n_valid, put)``: the state rows the call works on and
-    how to put the new ones back; ``attention(a, h, kp, vp, j)`` ->
-    ``(attn [B, S, H, D], kp, vp)``."""
-    eps = cfg.norm_eps
+def _hybrid_model(cfg):
+    """The model module of a hybrid stack's family."""
+    return olmo_hybrid if cfg.olmo_hybrid is not None else jamba
 
-    def ffn(f, x):
-        return x + jamba.mlp(cfg, f, jamba.rms_norm(x, f["norm1"], eps))
 
-    def mamba_layer(m, f, x, carry, g):
+def _hybrid_layers(cfg, fam, mixer_state, attention):
+    """The two layer bodies of ``fam.scan_layers`` over the carry ``(kp, vp,
+    ssm, conv)``.  ``attention(q, k, v, kp, vp, j)`` -> ``(attn [B, S, H,
+    D], kp, vp)``."""
+
+    def rec_layer(m, f, x, carry, g):
         kp, vp, ssm, conv = carry
-        # Reading the layer's rows and putting them back is part of the
-        # recurrence it belongs to: the write fuses with the update, and
-        # the fusion takes its root's name.
-        scope = (jamba.DECODE_UPDATE if x.shape[1] == 1 else jamba.CHUNK_SCAN)
-        with jax.named_scope(scope):
-            s, tail, n_valid, put = mixer_state(ssm, conv, g)
-        out, s, tail = jamba.mamba_mixer(
-            cfg, m, jamba.rms_norm(x, f["norm0"], eps), s, tail, n_valid)
-        with jax.named_scope(scope):
-            ssm, conv = put(ssm, conv, g, s, tail)
-        return ffn(f, x + out), (kp, vp, ssm, conv)
+
+        def mixer(h):
+            nonlocal ssm, conv
+            out, ssm, conv = fam.serve_mixer(cfg, m, h, ssm, conv, g,
+                                             mixer_state)
+            return out
+
+        x = fam.block(cfg, f, x, mixer)
+        return x, (kp, vp, ssm, conv)
 
     def attn_layer(a, f, x, carry, j):
         kp, vp, ssm, conv = carry
-        attn, kp, vp = attention(
-            a, jamba.rms_norm(x, f["norm0"], eps), kp, vp, j)
-        return ffn(f, x + jamba.attn_out(cfg, a, attn)), (kp, vp, ssm, conv)
 
-    return mamba_layer, attn_layer
+        def mixer(h):
+            nonlocal kp, vp
+            attn, kp, vp = attention(*fam.qkv(cfg, a, h), kp, vp, j)
+            return fam.attn_out(cfg, a, attn)
+
+        x = fam.block(cfg, f, x, mixer)
+        return x, (kp, vp, ssm, conv)
+
+    return rec_layer, attn_layer
 
 
 def _run_hybrid(cfg, p, x, k_pages, v_pages, ssm, conv, mixer_state,
                 attention):
     """x through the stack with the pools (viewed flat, as
     :func:`_scan_blocks` views them) and the states as the loops' carry."""
+    fam = _hybrid_model(cfg)
     pool_shape = k_pages.shape
     flat = (pool_shape[0] * pool_shape[1],) + pool_shape[2:]
-    x, (kp, vp, ssm, conv) = jamba.scan_layers(
+    x, (kp, vp, ssm, conv) = fam.scan_layers(
         cfg, p, x,
         (k_pages.reshape(flat), v_pages.reshape(flat), ssm, conv),
-        *_hybrid_layers(cfg, mixer_state, attention))
+        *_hybrid_layers(cfg, fam, mixer_state, attention))
     return x, kp.reshape(pool_shape), vp.reshape(pool_shape), ssm, conv
 
 
@@ -627,37 +667,50 @@ def _lane_state(slot, fresh, n_valid):
         _, _, N, Di = ssm.shape
         s = jax.lax.dynamic_slice(ssm, (g, slot, 0, 0), (1, 1, N, Di))[0]
         tail = jax.lax.dynamic_slice(
-            conv, (g, 0, slot, 0), (1, conv.shape[1], 1, Di))[0]
+            conv, (g, 0, slot, 0), (1, conv.shape[1], 1, conv.shape[3]))[0]
         return (jnp.where(fresh, 0.0, s),
                 jnp.where(fresh, jnp.zeros_like(tail), tail), n_valid, put)
 
     return mixer_state
 
 
+def _every_lane(n_valid):
+    """``mixer_state`` of the decode program: layer ``g``'s rows of every
+    lane, ``n_valid`` (1, or 0 for a lane that sits the tick out) a lane.
+    Marked ``every_lane`` for a mixer that takes the whole state."""
+
+    def put(ssm, conv, g, s, tail):
+        return (jax.lax.dynamic_update_index_in_dim(ssm, s, g, 0),
+                jax.lax.dynamic_update_index_in_dim(conv, tail, g, 0))
+
+    def mixer_state(ssm, conv, g):
+        return (jax.lax.dynamic_index_in_dim(ssm, g, 0, keepdims=False),
+                jax.lax.dynamic_index_in_dim(conv, g, 0, keepdims=False),
+                n_valid, put)
+
+    mixer_state.every_lane = True
+    mixer_state.n_valid = n_valid
+    return mixer_state
+
+
 def _build_hybrid_decode_fn(cfg, scfg, mesh) -> Callable:
+    if cfg.olmo_hybrid is not None and mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "the olmo_hybrid programs run on one chip: the delta-rule "
+            "kernels are not partitioned over a mesh yet")
+    fam = _hybrid_model(cfg)
     attend = _decode_attention(mesh, cfg.kv_heads)
     n_pages = scfg.n_pages
 
     @_program_name("tdx_serve_decode")
     def decode_fn(params, k_pages, v_pages, ssm, conv, tokens, positions,
                   page_table):
-        p = jamba.param_tree(params["params"])
-        x = jamba.embed_tokens(cfg, p, tokens[:, None])
+        p = fam.param_tree(params["params"])
+        x = fam.embed_tokens(cfg, p, tokens[:, None])
         live = positions > 0  # idle and mid-prefill lanes sit the tick out
         lengths = jnp.where(live, positions + 1, 0)
-        n_valid = live.astype(jnp.int32)
 
-        def mixer_state(ssm, conv, g):
-            def put(ssm, conv, g, s, tail):
-                return (jax.lax.dynamic_update_index_in_dim(ssm, s, g, 0),
-                        jax.lax.dynamic_update_index_in_dim(conv, tail, g, 0))
-
-            return (jax.lax.dynamic_index_in_dim(ssm, g, 0, keepdims=False),
-                    jax.lax.dynamic_index_in_dim(conv, g, 0, keepdims=False),
-                    n_valid, put)
-
-        def attention(a, h, kp, vp, j):
-            q, k, v = jamba.qkv(cfg, a, h)
+        def attention(q, k, v, kp, vp, j):
             base = j * n_pages
             kp, vp = _write_kv(kp, vp, base, page_table, k, v, positions,
                                positions + 1)
@@ -665,8 +718,9 @@ def _build_hybrid_decode_fn(cfg, scfg, mesh) -> Callable:
             return o[:, None], kp, vp
 
         x, k_pages, v_pages, ssm, conv = _run_hybrid(
-            cfg, p, x, k_pages, v_pages, ssm, conv, mixer_state, attention)
-        logits = jamba.head_logits(cfg, p, x)[:, 0]
+            cfg, p, x, k_pages, v_pages, ssm, conv,
+            _every_lane(live.astype(jnp.int32)), attention)
+        logits = fam.head_logits(cfg, p, x)[:, 0]
         return logits, k_pages, v_pages, ssm, conv
 
     return decode_fn
@@ -676,21 +730,21 @@ def _build_hybrid_prefill_fn(cfg, scfg, bucket, *, chunked: bool) -> Callable:
     """``prefill-<b>`` (``chunked`` False: a fresh prompt, dense causal
     attention over the bucket, state from zero) and ``chunk-<b>`` (a
     prompt's positions ``[start, end)``: attention through the page
-    table over what earlier chunks wrote, the scan resumed from the
-    state and the conv tail they left)."""
+    table over what earlier chunks wrote, the recurrence resumed from
+    the state and the conv tail they left)."""
+    fam = _hybrid_model(cfg)
     n_pages = scfg.n_pages
     kind = "chunk" if chunked else "prefill"
 
     def body(params, k_pages, v_pages, ssm, conv, tokens, start, end,
              page_table, slot):
-        p = jamba.param_tree(params["params"])
+        p = fam.param_tree(params["params"])
         S = tokens.shape[1]
         positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
-        x = jamba.embed_tokens(cfg, p, tokens)
+        x = fam.embed_tokens(cfg, p, tokens)
         mixer_state = _lane_state(slot[0], start[0] == 0, end - start)
 
-        def attention(a, h, kp, vp, j):
-            q, k, v = jamba.qkv(cfg, a, h)
+        def attention(q, k, v, kp, vp, j):
             base = j * n_pages
             kp, vp = _write_kv(kp, vp, base, page_table, k, v,
                                positions[:, 0], end)
@@ -708,7 +762,7 @@ def _build_hybrid_prefill_fn(cfg, scfg, bucket, *, chunked: bool) -> Callable:
         last = jnp.clip(end - 1 - start, 0, S - 1)[:, None, None]
         x_last = jnp.take_along_axis(x, jnp.broadcast_to(
             last, (x.shape[0], 1, x.shape[2])), axis=1)
-        return (jamba.head_logits(cfg, p, x_last)[0, 0], k_pages, v_pages,
+        return (fam.head_logits(cfg, p, x_last)[0, 0], k_pages, v_pages,
                 ssm, conv)
 
     if chunked:
@@ -866,7 +920,7 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
     model = make_model(family, cfg)
     if cfg.afmoe is not None:
         return _build_afmoe_fn(cfg, scfg, mesh, "decode")
-    if cfg.mamba is not None:
+    if _recurrent(cfg):
         return _build_hybrid_decode_fn(cfg, scfg, mesh)
     decomp = model.decode_decomposition()
     attend = _decode_attention(mesh, cfg.kv_heads)
@@ -907,7 +961,7 @@ def build_prefill_fn(family: str, cfg: TransformerConfig,
     model = make_model(family, cfg)
     if cfg.afmoe is not None:
         return _build_afmoe_fn(cfg, scfg, None, "prefill", bucket)
-    if cfg.mamba is not None:
+    if _recurrent(cfg):
         return _build_hybrid_prefill_fn(cfg, scfg, bucket, chunked=False)
     decomp = model.decode_decomposition()
 
@@ -951,7 +1005,7 @@ def build_chunk_prefill_fn(family: str, cfg: TransformerConfig,
     model = make_model(family, cfg)
     if cfg.afmoe is not None:
         return _build_afmoe_fn(cfg, scfg, None, "chunk", bucket)
-    if cfg.mamba is not None:
+    if _recurrent(cfg):
         return _build_hybrid_prefill_fn(cfg, scfg, bucket, chunked=True)
     decomp = model.decode_decomposition()
 
@@ -1004,7 +1058,7 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
             "no verify-<k> program for the afmoe family yet: a rejected "
             "draft would have to be rolled back out of a window group that "
             "has already returned the pages behind it")
-    if cfg.mamba is not None:
+    if _recurrent(cfg):
         raise NotImplementedError(
             "no verify-<k> program for a stack with recurrent layers: the "
             "tick would advance every lane's state over its whole draft, "
