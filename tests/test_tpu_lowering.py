@@ -367,6 +367,9 @@ def test_serving_program_aliases_its_pools_on_v5e(one_chip, monkeypatch, cell,
     text = compiled.as_text()
     if program == "decode":
         assert "tdx_paged_attention_decode" in text
+    if cell.startswith("trinity"):
+        # the expert products are the program's own kernel, not XLA's
+        assert "tdx_moe_experts_gmm" in text and "ragged-dot" not in text
     # Parameters are numbered over the flattened arguments: the consumed
     # ones follow the parameter tree's leaves.
     first = len(jax.tree.leaves(spec.args[:spec.consumes[0]]))
@@ -387,3 +390,33 @@ def test_serving_program_aliases_its_pools_on_v5e(one_chip, monkeypatch, cell,
             continue
         assert not [c for c in copies if f"= {_hlo_shape(a)}" in c], (
             program, _hlo_shape(a))
+
+
+# -- the expert layers' grouped product: lowered once a shape ---------------
+#
+# The trinity cell's programs call the grouped-matmul kernel three times
+# in each of four expert layers: 12 call sites a program, of one shape.
+# Behind its inner ``jax.jit`` each distinct shape lowers to ONE Mosaic
+# module, which every site of that shape calls; lowered once a site it
+# would cost the cell's ``setup_s`` the lowering twelve times over (four
+# shapes a program, one for each of a few tiers of rows, cost it 5.3 s).
+
+
+@pytest.mark.parametrize("program", ["prefill-256", "prefill-1024",
+                                     "prefill-2048", "chunk-256",
+                                     "chunk-1024", "chunk-2048", "decode"])
+def test_the_afmoe_programs_lower_the_grouped_kernel_once_a_shape(
+        one_chip, monkeypatch, program):
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = _cell_specs("trinity-large-mixed-queue")[program]
+    text = jax.jit(spec.fn).lower(*jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        spec.args)).as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "tdx_moe_experts_gmm" in line]
+    sites = re.findall(r"call @\w*gmm\w*\(.*?\) : (\(.*?\))", text)
+    assert len(kernels) == len(set(sites)) == 1
+    assert len(sites) == 3 * 4                  # three products, four layers
+    assert "ragged_dot" not in text

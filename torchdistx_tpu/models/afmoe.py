@@ -28,8 +28,10 @@ on experts held elsewhere contribute nothing here (the exchange that
 would add the other chips' parts is not in this repository yet).  With
 ``held_experts == n_experts`` it is the whole layer.  Nothing is dropped
 and there is no capacity: the (token, choice) pairs are sorted by
-expert and go through three grouped products (:func:`jax.lax.ragged_dot`,
-which XLA lowers to a grouped-matmul kernel on the chip) under the scope
+expert and go through three grouped products, each the Pallas kernel
+``tdx_moe_experts_gmm`` (:func:`..ops.grouped_matmul.grouped_matmul`,
+which reads a held expert's matrix once for each tile of rows its pairs
+touch and none of an expert that got no pair), under the scope
 ``tdx_moe_experts``; the router runs under ``tdx_moe_router``.  The call
 also returns how many pairs each held expert got.
 
@@ -48,6 +50,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.grouped_matmul import grouped_matmul
 from .configs import TransformerConfig
 from .layers import apply_rope
 
@@ -200,35 +203,16 @@ def held_expert_sum(cfg, lp, x, idx, w, valid):
     sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
     xs = x.astype(cfg.dtype)[order // k]                      # [T*k, d]
 
-    def products(rows):
-        """The three grouped products over the first ``rows`` sorted
-        pairs (every held pair is among them), zero-padded to T*k."""
-        with jax.named_scope(EXPERTS):
-            dot = lambda lhs, name: jax.lax.ragged_dot(
-                lhs, lp[name].astype(cfg.dtype), sizes)
-            head = xs[:rows]
-            act = (jax.nn.silu(dot(head, "experts_w_gate"))
-                   * dot(head, "experts_w_up"))
-            y = dot(act.astype(cfg.dtype), "experts_w_down")
-        return jnp.pad(y, ((0, T * k - rows), (0, 0)))
-
-    # The grouped product's time follows the rows it is GIVEN, not the
-    # rows that belong to a group, and a replica that holds an eighth of
-    # the experts gets about an eighth of the pairs (more or less of them
-    # as the router favours its experts).  So the products run over the
-    # smallest of a few fixed shares of the rows that holds every held
-    # pair: one and a half times the expected share (the expectation
-    # itself would be passed by every other call), doubling, up to all
-    # rows.  Each is exact; nothing is ever dropped.
-    tiers = [-(-3 * T * k * n // (2 * a.n_experts) // 8) * 8]
-    while tiers[-1] * 2 < T * k:
-        tiers.append(tiers[-1] * 2)
-    tiers = [t for t in tiers if t < T * k] + [T * k]
-    if len(tiers) > 1:
-        fits = sum((sizes.sum() > t).astype(jnp.int32) for t in tiers[:-1])
-        y = jax.lax.switch(fits, [lambda t=t: products(t) for t in tiers])
-    else:
-        y = products(T * k)
+    # The kernel's work follows the pairs, not the rows it is given (a
+    # tile of rows past the last group is never visited; an idle step of
+    # its static grid copies nothing), so the products take all T*k rows
+    # and a program lowers ONE shape of the kernel: a few tiers of rows,
+    # a shape each, cost the trinity cell 5.3 s of set-up in lowering.
+    with jax.named_scope(EXPERTS):
+        dot = lambda lhs, name: grouped_matmul(
+            lhs, lp[name].astype(cfg.dtype), sizes)
+        act = jax.nn.silu(dot(xs, "experts_w_gate")) * dot(xs, "experts_w_up")
+        y = dot(act.astype(cfg.dtype), "experts_w_down")
     # Rows past the last group belong to no expert and hold whatever the
     # kernel left there: taken out by selection, never by a product.
     wk = jnp.where(held, w.reshape(-1), 0.0)[order]
