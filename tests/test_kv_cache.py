@@ -96,16 +96,22 @@ def test_table_row_padding_and_overflow():
 
 
 def test_gauges_track_pool_state():
+    """The gauges are set by ``publish_gauges`` (the engine's once a
+    step), not by the transitions themselves."""
     observe.enable(True)
     try:
         kv = PagedKVCache(_cfg())
+        n0 = len(observe.tracer().events)
         kv.alloc(1, 5)
+        assert len(observe.tracer().events) == n0  # no sample a transition
+        kv.publish_gauges()
         snap = {r["name"]: r["value"]
                 for r in observe.counters().snapshot()
                 if r["type"] == "gauge"}
         assert snap["tdx.serve.kv_pages_in_use"] == 2
         assert snap["tdx.serve.kv_pool_pages"] == 7
         kv.free(1)
+        kv.publish_gauges()
         snap = {r["name"]: r["value"]
                 for r in observe.counters().snapshot()
                 if r["type"] == "gauge"}

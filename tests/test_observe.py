@@ -575,8 +575,12 @@ def tick_engine(tick_replica):
     return eng
 
 
-TICK_SPANS = ("serve.step", "serve.admit", "serve.tick.tables",
-              "serve.program", "serve.tick.d2h", "serve.tick.emit")
+# A plain decode tick's spans: the program call's two dispatches (the
+# program, then the greedy choice) and its one wait.
+TICK_SPANS = ("serve.step", "serve.admit", "serve.admit.deadlines",
+              "serve.tick.tables", "serve.program", "serve.program.launch",
+              "serve.program.launch", "serve.program.wait", "serve.tick.d2h",
+              "serve.tick.emit", "serve.gauges")
 
 
 class TestServeTickSpans:

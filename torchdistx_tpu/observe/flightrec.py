@@ -107,11 +107,15 @@ def feed(event: dict) -> None:
         _ring.append(event)
 
 
+_config = None  # the config module, looked up once: armed() gates every span
+
+
 def armed() -> bool:
     """Whether a flight dir is configured (the every-hook gate)."""
-    from .. import config
-
-    return bool(config.get().flight_dir)
+    global _config
+    if _config is None:
+        from .. import config as _config
+    return bool(_config.get().flight_dir)
 
 
 def ring_events() -> List[dict]:

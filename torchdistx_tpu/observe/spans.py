@@ -25,6 +25,7 @@ never imported: a process that has not loaded it mirrors nothing.
 from __future__ import annotations
 
 import json
+import os
 import sys
 import threading
 import time
@@ -406,8 +407,6 @@ class Tracer:
 
 
 def _pid() -> int:
-    import os
-
     return os.getpid()
 
 

@@ -70,10 +70,10 @@ Telemetry (docs/observability.md): ``tdx.serve.tokens_per_s``,
 ``preempted_requests``, plus ``requests_completed`` / ``prefills`` /
 ``decode_steps`` / ``attended_tokens`` / ``decode_lane_ticks`` counters
 and ``serve.step`` / ``serve.prefill`` / ``serve.spin_up`` spans; inside
-a step, ``serve.admit``, ``serve.tick.tables``, ``serve.program``,
-``serve.tick.d2h`` and ``serve.tick.emit`` split the host's part of a
-tick from the device's (``serve.program`` waits for the logits while
-telemetry is on, so what lies outside it is the time the chip idles).
+a step ``serve.admit``, ``serve.tick.tables``, ``serve.program`` (with
+its ``.launch`` and ``.wait`` children), ``serve.tick.d2h``,
+``serve.tick.emit`` and ``serve.gauges`` name the host's moments: a
+traced tick waits for the device where an untraced one does, no more.
 SLOs (docs/observability.md §SLOs): every engine feeds sliding windows
 over TTFT, per-token latency, and queue wait
 (:class:`~torchdistx_tpu.observe.slo.ServeSLO`), published as
@@ -648,7 +648,10 @@ class ServeEngine:
                 self._take_serve_faults()
                 admitted = self._n_admitted
                 with observe.span("serve.admit", category="serve") as sp:
-                    self._expire_deadlines()
+                    with observe.span(
+                            "serve.admit.deadlines", category="serve",
+                            scanned=len(self.active) + len(self.waiting)):
+                        self._expire_deadlines()
                     self._advance_prefill()
                     self._admit()
                     sp.set(admitted=self._n_admitted - admitted,
@@ -694,7 +697,8 @@ class ServeEngine:
                     self.kv.reset()
                     self._init_pools()
                     observe.counter("tdx.serve.pool_rebuilds").inc()
-        self._gauges()
+            with observe.span("serve.gauges", category="serve"):
+                self._gauges()
 
     # -- admission / prefill ------------------------------------------------
 
@@ -724,8 +728,7 @@ class ServeEngine:
                      greedy: bool = False):
         """Call the compiled model program ``name`` on the params, the
         pools, the recurrent state (a hybrid stack) and ``args`` under
-        ``serve.program`` (``state_lanes``: the lanes whose recurrent
-        state the call advances) and bring its result to the host under
+        ``serve.program`` and bring its result to the host under
         ``serve.tick.d2h`` (``bytes``: what came).  That is the logits;
         nothing with ``fetch`` False (a chunk that is not a prompt's last,
         whose logits nobody reads); with ``greedy`` (a plain decode tick)
@@ -734,18 +737,28 @@ class ServeEngine:
         of 65,536 floats is 262 KB; 128 of them took 11.7 ms of a 37 ms
         tick to fetch, for a host that reads a row only when its lane
         retires (``_TickRow``; PERF.md section 6, PR 36).
-        While telemetry is on
-        ``serve.program`` ends when the logits are ready, so the two spans
-        split device time from the copy; off, nothing waits before the
-        fetch.  ``attended`` is the context the program's ``lanes`` attend
-        over, counted before anything retires; ``positions`` the real
-        positions the call advances its lanes by, all lanes together (a
-        decode tick's live lanes, a prefill's or a chunk's tokens): with
-        Gated DeltaNet layers, times their number, they are the span's
-        ``gdn_positions`` and go to ``tdx.serve.gdn_decode_positions``
-        (decode) or ``tdx.serve.gdn_prefill_positions``; ``kv_blocks``
-        the blocks the decode kernel walks for it (a program that attends through
-        jnp gathers walks none).  With a window group ``window_tokens`` is
+
+        ``serve.program`` runs from the call until what the engine reads
+        is ready, and its children tile it in the order the host runs
+        them: ``serve.program.launch`` for each dispatch (``call``: the
+        ``program``, then a plain tick's ``greedy`` choice, dispatched
+        before anything waits) and ``serve.program.wait`` for each wait
+        (the afmoe family's pair counts, then, while telemetry is on, the
+        tokens or logits).  Off, that last span is the shared no-op and
+        does not wait: the fetch under ``serve.tick.d2h`` does, so a traced
+        call makes the same device calls and host waits as an untraced one
+        (its copy queued behind the call, where the untraced fetch queues
+        it) and ``serve.tick.d2h`` is what is left of the copy.
+
+        ``attended`` is the context the program's ``lanes`` attend over,
+        counted before anything retires; ``positions`` the real positions
+        the call advances its lanes by, all lanes together (a decode
+        tick's live lanes, a verify tick's rows, a prefill's or a chunk's
+        tokens): with Gated DeltaNet layers, times their number, they go
+        to ``tdx.serve.gdn_decode_positions`` (decode) or
+        ``tdx.serve.gdn_prefill_positions``; ``kv_blocks`` the blocks the
+        decode kernel walks for it (a program that attends through jnp
+        gathers walks none).  With a window group ``window_tokens`` is
         what the lanes attend over in a window layer (``min(context,
         window)`` each), and the call's pair counts come to the host with
         the logits: ``routed_pairs`` (pairs that landed on a held expert,
@@ -757,15 +770,13 @@ class ServeEngine:
              else self._gdn_prefill).inc(gdn)
         with observe.span("serve.program", category="serve", program=name,
                           lanes=lanes, attended_tokens=attended,
-                          kv_blocks=kv_blocks,
-                          state_lanes=(lanes if self.kv.cfg.state is not None
-                                       else 0),
-                          **({"gdn_positions": gdn} if self._gdn_layers
-                             else {})) as sp:
-            logits, self.k_pages, self.v_pages, *state = self._program(name)(
-                self.params, self.k_pages, self.v_pages, *self.state, *args)
+                          kv_blocks=kv_blocks, positions=positions) as sp:
+            with observe.span("serve.program.launch", category="serve",
+                              program=name, call="program"):
+                logits, self.k_pages, self.v_pages, *state = self._program(
+                    name)(self.params, self.k_pages, self.v_pages,
+                          *self.state, *args)
             self.state = tuple(state)
-            sp.block_on(logits)
             # The pair counts ride to the host with the logits: a chunk
             # whose logits nobody reads waits for nothing, so the host
             # prepares the next call while the device runs this one, and
@@ -773,7 +784,9 @@ class ServeEngine:
             # (the device keeps a running sum).  With telemetry on every
             # call is waited for anyway and the span gets its own counts.
             if self.kv.cfg.window is not None and (fetch or observe.enabled()):
-                seen = np.asarray(self.state[1])
+                with observe.span("serve.program.wait", category="serve",
+                                  program=name):
+                    seen = np.asarray(self.state[1])
                 pairs, self._pairs_seen = seen - self._pairs_seen, seen
                 routed, hit = int(pairs.sum()), int((pairs > 0).sum())
                 sp.set(routed_pairs=routed, experts_hit=hit,
@@ -781,9 +794,20 @@ class ServeEngine:
                 self._moe_pairs.inc(routed)
                 self._moe_hit.inc(hit)
                 self._moe_max.inc(int(pairs.max()))
+            coming = logits
+            if fetch and greedy:
+                with observe.span("serve.program.launch", category="serve",
+                                  program=name, call="greedy"):
+                    coming = _greedy(logits)
+            if fetch and observe.enabled():
+                # Queue the copy behind the call before waiting, as the
+                # untraced fetch does: waiting first would add a round
+                # trip (0.4 ms on the chip's host) that only traced ticks
+                # paid.
+                coming.copy_to_host_async()
+            _wait_traced(name, coming)
         if not fetch:
             return None
-        coming = _greedy(logits) if greedy else logits
         with observe.span("serve.tick.d2h", category="serve", program=name,
                           bytes=coming.nbytes):
             host = np.asarray(coming)
@@ -1021,14 +1045,16 @@ class ServeEngine:
         if moved is not None:
             src, dst = moved
             with observe.span("serve.program", category="serve",
-                              program="cow", lanes=1,
-                              attended_tokens=0, kv_blocks=0) as sp:
-                self.k_pages, self.v_pages = self._program("cow")(
-                    self.k_pages, self.v_pages,
-                    jnp.asarray([src], jnp.int32),
-                    jnp.asarray([dst], jnp.int32),
-                )
-                sp.block_on(self.k_pages)
+                              program="cow", lanes=1, attended_tokens=0,
+                              kv_blocks=0, positions=0):
+                with observe.span("serve.program.launch", category="serve",
+                                  program="cow", call="program"):
+                    self.k_pages, self.v_pages = self._program("cow")(
+                        self.k_pages, self.v_pages,
+                        jnp.asarray([src], jnp.int32),
+                        jnp.asarray([dst], jnp.int32),
+                    )
+                _wait_traced("cow", self.k_pages)
             observe.counter("tdx.serve.cow_copies").inc()
             reqledger.on_cow(lane.req.rid, replica=self.slo.name)
 
@@ -1159,12 +1185,12 @@ class ServeEngine:
                 args += (jnp.asarray(wrows), jnp.asarray(wfirst))
                 window_tokens = int(np.minimum(positions[slots] + 1,
                                                w.window).sum())
-        n_lanes = len(slots)
-        # A lane at position p attends over p + 1 tokens, its new one
-        # included (idle lanes sit at 0 and attend over nothing).
-        attended = int(positions.sum()) + n_lanes
-        kv_blocks = kv_blocks_walked(positions[slots] + 1,
-                                     *self._kernel_pool)
+            n_lanes = len(slots)
+            # A lane at position p attends over p + 1 tokens, its new one
+            # included (idle lanes sit at 0 and attend over nothing).
+            attended = int(positions.sum()) + n_lanes
+            kv_blocks = kv_blocks_walked(positions[slots] + 1,
+                                         *self._kernel_pool)
         logits, greedy = self._run_program(
             "decode", *args, lanes=n_lanes, attended=attended,
             positions=n_lanes,
@@ -1260,7 +1286,8 @@ class ServeEngine:
         rejected positions — every emitted token is the token plain
         decode would have produced, speculation only changes how many
         arrive per tick."""
-        drafts = self._drafts_for(self._decodable())
+        with observe.span("serve.tick.drafts", category="serve"):
+            drafts = self._drafts_for(self._decodable())
         if not any(drafts.values()) and not self._pending_verify_faults:
             # Nothing proposed anywhere (cold drafter): plain decode is
             # the same tick at width 1, without the rollback tax.
@@ -1315,7 +1342,8 @@ class ServeEngine:
         # p + d + 1 tokens at its last row: its ``end``.
         attended = int(end.sum())
         logits = self._run_program(f"verify-{kb}", *args, lanes=n_lanes,
-                                   attended=attended)
+                                   attended=attended,
+                                   positions=int(end.sum() - start.sum()))
         with observe.span("serve.tick.emit", category="serve",
                           program=f"verify-{kb}") as sp:
             dt = time.perf_counter() - t_step
@@ -1470,6 +1498,7 @@ class ServeEngine:
             observe.gauge("tdx.serve.spec_accepted").set(self.spec_accepted)
             observe.gauge("tdx.serve.spec_accept_rate").set(
                 round(self.spec_accepted / self.spec_drafted, 4))
+        self.kv.publish_gauges()
         if reqledger.enabled():
             reqledger.occupancy_sample(
                 replica=self.slo.name,
@@ -1486,6 +1515,16 @@ class ServeEngine:
         # clock regardless of tick rate).
         if self._step_no % 32 == 0 or not (self.waiting or self.active):
             self.slo.publish()
+
+
+def _wait_traced(program: str, value) -> None:
+    """The last wait of a program call, ``serve.program.wait``: for
+    ``value`` to be ready while telemetry is on; off, the span is the
+    shared no-op and nothing waits (the host's next read of ``value``
+    does, where it reads one)."""
+    with observe.span("serve.program.wait", category="serve",
+                      program=program) as sp:
+        sp.block_on(value)
 
 
 # ---------------------------------------------------------------------------

@@ -79,7 +79,8 @@ Telemetry (docs/observability.md): ``tdx.serve.kv_pages_in_use``,
 pages — the internal-fragmentation complement),
 ``tdx.serve.kv_pool_pages``, ``tdx.serve.kv_pages_free``, and
 ``tdx.serve.kv_pages_shared`` (refcount > 1 — the live copy-on-write
-exposure) gauges, refreshed on every mutation; with a state group also
+exposure) gauges, refreshed by :meth:`PagedKVCache.publish_gauges` (the
+engine calls it once a step, not on every mutation); with a state group also
 ``tdx.serve.state_slots_in_use`` and ``tdx.serve.state_slots_peak``
 (gauges) and ``tdx.serve.state_resets`` (counter, always on); with a
 window group ``tdx.serve.window_pages_in_use`` and
@@ -249,7 +250,6 @@ class PagedKVCache:
             range(cfg.window.n_pages - 1, 0, -1))
         self.window_pages_peak = 0
         self._wreleased = observe.counter("tdx.serve.window_pages_released")
-        self._update_gauges()
 
     # -- queries ------------------------------------------------------------
 
@@ -368,7 +368,6 @@ class PagedKVCache:
         for p in pages:
             self._ref[p] = 1
         self._seqs[seq_id] = _Seq(pages=pages, length=n_tokens)
-        self._update_gauges()
         return list(pages)
 
     def alloc_shared(self, seq_id: int, shared_pages: Sequence[int],
@@ -411,7 +410,6 @@ class PagedKVCache:
         for p in fresh:
             self._ref[p] = 1
         self._seqs[seq_id] = _Seq(pages=shared + fresh, length=n_tokens)
-        self._update_gauges()
         return shared + fresh
 
     def retain(self, pages: Iterable[int]) -> None:
@@ -437,7 +435,6 @@ class PagedKVCache:
                 self._ref[p] = n - 1
         if freed:
             self._free.extend(reversed(freed))
-            self._update_gauges()
         return len(freed)
 
     def cow_page(self, seq_id: int,
@@ -461,7 +458,6 @@ class PagedKVCache:
         self._ref[src] -= 1
         self._ref[dst] = 1
         seq.pages[page_index] = dst
-        self._update_gauges()
         return src, dst
 
     def window_advance(self, seq_id: int, start: int, end: int) -> int:
@@ -500,7 +496,6 @@ class PagedKVCache:
         if behind or add:
             self.window_pages_peak = max(self.window_pages_peak,
                                          self.window_pages_in_use)
-            self._update_gauges()
         return behind
 
     def extend(self, seq_id: int, new_length: int) -> List[int]:
@@ -529,8 +524,6 @@ class PagedKVCache:
             self._ref[p] = 1
         seq.pages.extend(added)
         seq.length = new_length
-        if added:
-            self._update_gauges()
         return added
 
     def rollback(self, seq_id: int, new_length: int) -> int:
@@ -564,7 +557,6 @@ class PagedKVCache:
         wkeep = max(0, keep - seq.wfirst)
         self._wfree.extend(reversed(seq.wpages[wkeep:]))
         del seq.wpages[wkeep:]
-        self._update_gauges()
         return len(dropped)
 
     def free(self, seq_id: int) -> int:
@@ -586,20 +578,17 @@ class PagedKVCache:
             else:
                 self._ref[p] -= 1
         self._free.extend(reversed(freed))
-        self._update_gauges()
         return len(freed)
 
     def reset(self) -> None:
         """Free every sequence and every outstanding reference (replica
-        drain): one free-list rebuild and one gauge refresh, not N
-        :meth:`free` calls."""
+        drain): one free-list rebuild, not N :meth:`free` calls."""
         self._seqs.clear()
         self._ref.clear()
         self._slot_of.clear()
         self._free = list(range(self.cfg.n_pages - 1, 0, -1))
         if self.cfg.window is not None:
             self._wfree = list(range(self.cfg.window.n_pages - 1, 0, -1))
-        self._update_gauges()
 
     # -- batch views --------------------------------------------------------
 
@@ -647,7 +636,11 @@ class PagedKVCache:
 
     # -- telemetry ----------------------------------------------------------
 
-    def _update_gauges(self) -> None:
+    def publish_gauges(self) -> None:
+        """Set every gauge of the pool from its state now (telemetry on
+        only).  The mutations leave the gauges alone: each ``set`` is an
+        event in the tracer, and a decode tick makes a mutation a lane;
+        the engine publishes once a step, under ``serve.gauges``."""
         if not observe.enabled():
             return
         observe.gauge("tdx.serve.kv_pages_in_use").set(self.pages_in_use)
