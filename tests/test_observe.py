@@ -589,27 +589,38 @@ class TestServeTickSpans:
 
         eng.submit(Request(f"{tag}-short", [3, 1, 4], max_new_tokens=3))
         eng.submit(Request(f"{tag}-long", [2, 7, 1, 8, 2], max_new_tokens=6))
-        eng.step()  # admits both, prefills, and decodes once
+        # Admits both and prefills (and decodes once, on a replica that
+        # reads each tick before it dispatches the next).
+        eng.step()
         assert len(eng.active) == 2
 
     def test_tick_yields_the_spans_and_counts_a_retiring_lane(
             self, telemetry, tick_engine):
+        """The step in which `on-short` retires.  This replica decodes
+        ahead (no drafter): that step dispatches a tick for the lanes that
+        go on, then hands over the tick before it, both lanes'."""
         eng = tick_engine
         self._two_lanes(eng, "on")
-        lengths = {l.req.rid: l.length for l in eng.active.values()}
-        before = {n: observe.counter(n).value for n in (
-            "tdx.serve.attended_tokens", "tdx.serve.decode_lane_ticks")}
-        n0 = len(observe.tracer().events)
-        eng.step()  # a decode tick in which `on-short` retires
+        while True:
+            # The lanes of the tick this step dispatches, and the position
+            # each writes (counting the positions of a tick in flight).
+            ticked = {l.req.rid: l.length + l.ahead
+                      for l in eng.active.values() if eng._decodes(l)}
+            before = {n: observe.counter(n).value for n in (
+                "tdx.serve.attended_tokens", "tdx.serve.decode_lane_ticks")}
+            n0 = len(observe.tracer().events)
+            eng.step()
+            if "on-short" not in {l.req.rid for l in eng.active.values()}:
+                break
         assert [l.req.rid for l in eng.active.values()] == ["on-long"]
         evs = [e for e in list(observe.tracer().events)[n0:]
                if e["ph"] == "X"]
         assert sorted(e["name"] for e in evs) == sorted(TICK_SPANS)
         by = {e["name"]: e for e in evs}
         # Each lane attends over its context with the new token in it.
-        want = sum(n + 1 for n in lengths.values())
+        want = sum(n + 1 for n in ticked.values())
         prog = by["serve.program"]["args"]
-        assert (prog["program"], prog["lanes"]) == ("decode", 2)
+        assert (prog["program"], prog["lanes"]) == ("decode", len(ticked))
         assert prog["attended_tokens"] == want
         assert by["serve.tick.emit"]["args"]["tokens"] == 2
         # What a plain tick brings: an int32 a lane of the batch (the
@@ -626,7 +637,7 @@ class TestServeTickSpans:
                 "tdx.serve.attended_tokens"] == want
         assert observe.counter(
             "tdx.serve.decode_lane_ticks").value - before[
-                "tdx.serve.decode_lane_ticks"] == 2
+                "tdx.serve.decode_lane_ticks"] == len(ticked)
         eng.run()
 
     def test_decode_tick_counts_the_blocks_the_kernel_walks(

@@ -91,16 +91,25 @@ def test_launch_and_wait_tile_the_program_call(replicas, telemetry, family):
     parts = [e for e in events if e["name"] in (
         "serve.program.launch", "serve.program.wait")]
     assert calls
+    ahead = eng._decodes_ahead()
     covered = total = 0.0
     for call in calls:
         kids = _inside(call, parts)
         names = [(e["name"].rsplit(".", 1)[1], e["args"].get("call"))
                  for e in kids]
         # The order the host runs them in: the program's dispatch first, a
-        # wait last; a plain tick's choice dispatched before its wait.
-        assert names[0] == ("launch", "program") and names[-1][0] == "wait"
+        # wait last; a plain tick's choice dispatched before its wait.  A
+        # call read later (a replica that decodes ahead) makes every
+        # launch before any wait, and a tick's span holds at most the wait
+        # for the tick before it.
+        kinds = [k for k, _ in names]
+        assert names[0] == ("launch", "program")
+        assert kinds == sorted(kinds) if ahead else kinds[-1] == "wait"
         if call["args"]["program"] == "decode":
             assert ("launch", "greedy") in names
+            assert ("ahead" in call["args"]) == ahead
+            if ahead:
+                assert kinds.count("wait") == call["args"]["ahead"]
         for a, b in zip(kids, kids[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 1.0  # none overlaps
         assert {e["args"]["program"] for e in kids} == {
@@ -109,6 +118,12 @@ def test_launch_and_wait_tile_the_program_call(replicas, telemetry, family):
         total += call["dur"]
     assert covered <= total + len(calls)
     assert covered >= 0.98 * total, (covered, total)
+    # Each call dispatched ahead is waited for once, later: every plain
+    # tick, and the last row of each of the three prompts.
+    later = [e for e in parts if e["args"].get("ahead") == 1]
+    assert all(e["name"] == "serve.program.wait" for e in later)
+    decodes = sum(c["args"]["program"] == "decode" for c in calls)
+    assert len(later) == ((decodes + 3) if ahead else 0)
 
 
 def _record(monkeypatch, eng):
@@ -148,6 +163,8 @@ def _record(monkeypatch, eng):
     monkeypatch.setattr(jax, "block_until_ready", block_until_ready)
     monkeypatch.setattr(engine_mod, "_greedy",
                         calls("greedy", engine_mod._greedy))
+    monkeypatch.setattr(engine_mod, "_merge",
+                        calls("merge", engine_mod._merge))
     monkeypatch.setattr(engine_mod, "_row", calls("row", engine_mod._row))
     monkeypatch.setattr(eng, "_programs", {
         n: calls(n, p) for n, p in eng._programs.items()})
@@ -159,7 +176,9 @@ def test_a_traced_tick_waits_where_an_untraced_one_does(
         replicas, monkeypatch, family):
     """One plain decode tick untraced and one traced, on the same lanes:
     the same device calls in the same order, the host waiting at the same
-    places, as often (``TICK_WAITS``)."""
+    places, as often (``TICK_WAITS``).  A replica that decodes ahead makes
+    its tick's inputs from the tick before (``merge``) and waits once, for
+    that tick's tokens."""
     eng = replicas(family)
     programs = dict(eng._programs)
     eng.run([])
@@ -168,6 +187,9 @@ def test_a_traced_tick_waits_where_an_untraced_one_does(
         eng.submit(r)
     eng.step()  # admission and prefills
     assert len(eng.active) == 3 and not eng.waiting
+    ahead = eng._decodes_ahead()
+    if ahead:
+        eng.step()  # the first tick, with none in flight before it
     ticks = {}
     try:
         for traced in (False, True):
@@ -179,11 +201,72 @@ def test_a_traced_tick_waits_where_an_untraced_one_does(
     finally:
         observe.enable(None)
         eng._programs = programs
-    want = [("call", "decode")]
+    want = [("call", "merge")] if ahead else []
+    want.append(("call", "decode"))
     if family == "afmoe":
         want.append(("wait", 1))
     want += [("call", "greedy"), ("wait", TICK_WAITS[family])]
     assert ticks[False] == ticks[True] == want
+    eng.run()
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("family", ["jamba", "llama", "olmo_hybrid"])
+def test_a_tick_dispatched_ahead_is_waited_for_once_in_the_next_step(
+        replicas, monkeypatch, family, traced):
+    """Over four steps of a replica that decodes ahead, the host's one wait
+    in each step is for the tokens of the tick dispatched in the step
+    before, after this step's tick was dispatched; a tick is never waited
+    for in the step that dispatched it."""
+    eng = replicas(family)
+    assert eng._decodes_ahead()
+    eng.run([])
+    for r in _traffic(eng, f"next-{family}", step=4):
+        r.max_new_tokens = 9
+        eng.submit(r)
+    eng.step()  # admission and prefills
+    made, log = [], []
+    greedy, block = engine_mod._greedy, jax.block_until_ready
+
+    def choose(logits):
+        out = greedy(logits)
+        made.append(out)
+        log.append(("tick", len(made) - 1))
+        return out
+
+    def wait(a):
+        for i, m in enumerate(made):
+            if a is m and ("wait", i) not in log:
+                log.append(("wait", i))
+
+    class Spy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(a, *args, **kw):
+            wait(a)
+            return np.asarray(a, *args, **kw)
+
+    def block_until_ready(x):
+        jax.tree.map(wait, x)
+        return block(x)
+
+    observe.enable(traced)
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(engine_mod, "np", Spy())
+            m.setattr(jax, "block_until_ready", block_until_ready)
+            m.setattr(engine_mod, "_greedy", choose)
+            steps = []
+            for _ in range(4):
+                n0 = len(log)
+                eng.step()
+                steps.append(log[n0:])
+    finally:
+        observe.enable(None)
+    assert steps == [[("tick", 0)]] + [
+        [("tick", i), ("wait", i - 1)] for i in range(1, 4)]
     eng.run()
 
 

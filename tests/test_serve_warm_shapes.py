@@ -28,6 +28,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark import harness  # noqa: E402
 import torchdistx_tpu.config as tdx_config  # noqa: E402
+import torchdistx_tpu.serve.engine as engine_mod  # noqa: E402
 from torchdistx_tpu import compile_service, observe  # noqa: E402
 from torchdistx_tpu.serve import (Request, ServeConfig,  # noqa: E402
                                   spin_up_replica)
@@ -148,7 +149,13 @@ def warmed(request, compiles):
     assert compiles.asked > asked, "the listener hears no compile request"
     rng = np.random.default_rng(36)
     _zeros_pass(eng)
-    _requests_pass(eng, chunked, rng)
+    merge, merged = engine_mod._merge, []
+    engine_mod._merge = lambda *a: merged.append(1) or merge(*a)
+    try:
+        _requests_pass(eng, chunked, rng)
+    finally:
+        engine_mod._merge = merge
+    eng.warm_merges = len(merged)  # token merges the warm-up made
     compiles.close_setup()
     yield request.param, eng, rng
     jax.clear_caches()  # many bring-ups in one process (the verify skill)
@@ -233,6 +240,23 @@ def test_no_rare_path_asks_for_a_program_after_the_warm_up(warmed, compiles):
     assert compiles.in_window() == 0, (
         f"{family}: {compiles.in_window()} program(s) first asked of the "
         f"compile cache after the warm-up")
+
+
+def test_the_warm_up_meets_the_token_merge_of_a_tick_dispatched_ahead(
+        warmed, compiles):
+    """A replica that decodes ahead makes a tick's input tokens on the
+    device from the tick before (``engine._merge``): the warm-up's
+    requests of three tokens each run a tick dispatched while the one
+    before is unread, so the merge is met there, and a drive of many
+    such ticks asks the compile cache for nothing."""
+    family, eng, rng = warmed
+    if not eng._decodes_ahead():
+        assert eng.warm_merges == 0
+        return
+    assert eng.warm_merges >= 1
+    _steps(eng, [Request(f"merge-{i}", _ids(rng, 4 + i), max_new_tokens=9)
+                 for i in range(3)])
+    assert compiles.in_window() == 0
 
 
 @pytest.mark.parametrize("how", ["a_count_met", "a_new_count"])

@@ -46,6 +46,14 @@ rejected positions' K/V, so cache state and every emitted token stay
 bitwise what plain decode would produce — speculation is purely a
 throughput knob (docs/serving.md §Speculative decoding).
 
+A replica with no drafter whose plain tick reads nothing but its tokens
+**decodes ahead** (docs/serving.md §Decoding ahead): each step dispatches
+its tick, its input tokens merged on the device from the tick before, and
+only then reads the tick before and the step's prompt rows, so the host's
+part of a tick (emission, retirement, the next admission and tables) runs
+while the chip works.  One tick is in flight at most; the token streams are
+the synchronous engine's, token for token.
+
 When the pool cannot cover a lane's growth the engine **preempts** the
 youngest lane (frees its pages, requeues the whole request at the front
 of the queue — greedy decode regenerates it identically), the vLLM
@@ -127,6 +135,16 @@ def _greedy(logits):
 
 
 @jax.jit
+def _merge(prev, host):
+    """The input tokens of a tick dispatched ahead: a lane that decoded in
+    the tick before (``host`` -1) takes that tick's greedy token, still on
+    the device; a lane whose last token came to the host (through a
+    prefill) takes ``host``.  ``[max_batch]`` whatever the traffic, so the
+    benchmark's warm-up meets it (docs/serving.md §Decoding ahead)."""
+    return jnp.where(host < 0, prev, host)
+
+
+@jax.jit
 def _row(logits, slot):
     """Row ``slot`` of a plain tick's logits.  The slot is an OPERAND: one
     compiled program serves every lane, and a replica's first retirement in
@@ -142,16 +160,35 @@ class _TickRow:
     tick's whole ``[max_batch, vocab]`` array and the lane's slot.  Whoever
     reads it as an array (``np.asarray``: ``_retire``, once a lane) brings
     that one row to the host, bit for bit the row that a fetch of the whole
-    array held."""
+    array held.  ``row`` is that row when it was cut on the device already,
+    right behind its tick (a tick dispatched ahead, for a lane whose budget
+    ends with it: cut later, the row would queue behind the next tick)."""
 
-    __slots__ = ("logits", "slot")
+    __slots__ = ("logits", "slot", "row")
 
-    def __init__(self, logits, slot: int):
-        self.logits, self.slot = logits, slot
+    def __init__(self, logits, slot: int, row=None):
+        self.logits, self.slot, self.row = logits, slot, row
 
     def __array__(self, dtype=None, copy=None):
         observe.counter("tdx.serve.logit_rows_fetched").inc()
-        return np.asarray(_row(self.logits, self.slot), dtype)
+        row = _row(self.logits, self.slot) if self.row is None else self.row
+        return np.asarray(row, dtype)
+
+
+class _Tick:
+    """A plain decode tick dispatched and not read yet (docs/serving.md
+    §Decoding ahead): its ``(slot, lane)`` pairs, so that a token read late
+    reaches only the lane it was made for; its logits and greedy tokens on
+    the device; the rows cut behind it for the lanes whose budget it ends;
+    whether it was dispatched while the tick before it was unread
+    (``ahead``); and when its tables began (``t0``)."""
+
+    __slots__ = ("lanes", "logits", "tokens", "rows", "ahead", "t0")
+
+    def __init__(self, lanes, ahead: bool, t0: float):
+        self.lanes, self.ahead, self.t0 = lanes, ahead, t0
+        self.logits = self.tokens = None  # set when the tick is dispatched
+        self.rows = {}
 
 
 @dataclass
@@ -189,6 +226,7 @@ class _Lane:
     admitted_step: int = 0
     prefilling: bool = False       # mid-chunked-prefill; decode skips it
     spec_k: int = 0                # current draft length cap (adaptive)
+    ahead: int = 0                 # positions of ticks dispatched, unread
 
 
 class ServeEngine:
@@ -308,6 +346,16 @@ class ServeEngine:
                             if cfg.olmo_hybrid is not None else 0)
         self._gdn_decode = observe.counter("tdx.serve.gdn_decode_positions")
         self._gdn_prefill = observe.counter("tdx.serve.gdn_prefill_positions")
+        # Decoding ahead (docs/serving.md): the plain tick in flight, the
+        # prompts' last rows dispatched this step and not read yet, when a
+        # tick was last read; plain ticks dispatched while the tick before
+        # was unread, and lane-ticks run for a lane that had retired (the
+        # mechanism's cost).  Always on.
+        self._tick: Optional[_Tick] = None
+        self._rows: List[tuple] = []
+        self._t_read = 0.0
+        self._ahead_ticks = observe.counter("tdx.serve.decode_ticks_ahead")
+        self._discarded = observe.counter("tdx.serve.lane_ticks_discarded")
 
     # -- pools ----------------------------------------------------------------
     #
@@ -466,6 +514,7 @@ class ServeEngine:
         while (self.waiting or self.active) and (
                 self._step_no - start) < max_steps:
             self.step()
+        self._drain_ahead()
         if self.waiting or self.active:
             raise RuntimeError(
                 f"serve loop hit max_steps={max_steps} with "
@@ -490,6 +539,7 @@ class ServeEngine:
                     f"drain hit max_steps={max_steps} with "
                     f"{len(self.active)} lanes still active"
                 )
+            self._drain_ahead()
             leftover = list(self.waiting)
             self.waiting.clear()
             # A drained replica holds no sequences; drop the prefix
@@ -506,6 +556,7 @@ class ServeEngine:
         ``flap`` fault path uses this: an intermittent replica fault
         costs the batch a replay, not the replica its life.  Returns
         the number of lanes requeued."""
+        self._drain_ahead()
         n = len(self.active)
         for slot in list(self.active):
             self._preempt(slot, reason=reason)
@@ -527,6 +578,7 @@ class ServeEngine:
                 continue
             self.active.pop(slot)
             self.kv.free(lane.seq_id)
+            self._discarded.inc(lane.ahead)
             self._delivered.pop(rid, None)
             self.cancelled[rid] = list(lane.generated)
             observe.instant("serve.cancel", category="serve", rid=rid,
@@ -591,6 +643,7 @@ class ServeEngine:
                 f"install_params with {len(self.active)} active lanes; "
                 f"drain first"
             )
+        self._tick = None  # with no lane active it holds retired lanes only
         self.prefix.clear()
         self.params = params
         self.weight_version = version
@@ -604,6 +657,7 @@ class ServeEngine:
                 f"release_kv with {len(self.active)} active lanes; "
                 f"drain first"
             )
+        self._tick = None
         self.k_pages = self.v_pages = None
         self.state = ()
         self.kv = PagedKVCache(self.scfg.kv_config(self.cfg))
@@ -636,7 +690,8 @@ class ServeEngine:
         had already consumed the pools leaves them deleted: they are
         allocated anew and the prefix cache, whose pages' content went
         with them, is dropped; the requeued lanes recompute over the new
-        pools (docs/serving.md failure matrix)."""
+        pools (docs/serving.md failure matrix).  A tick dispatched ahead is
+        read and handed over before the requeue, where it can be read."""
         self._step_no += 1
         if self._t0 is None:
             self._t0 = time.perf_counter()
@@ -685,6 +740,14 @@ class ServeEngine:
                     error=f"{type(e).__name__}: {e}"[:300],
                     active=len(self.active), waiting=len(self.waiting),
                 )
+                # Prompts whose last rows were not read go back with the
+                # other lanes; the tick in flight is read first where it
+                # can be (it was dispatched before the faulted call).
+                self._rows.clear()
+                try:
+                    self._drain_ahead()
+                except self._retryable:
+                    pass  # it failed too: its lanes recompute
                 for slot in list(self.active):
                     self._preempt(slot, reason="fault")
                 if pools_lost:
@@ -725,7 +788,9 @@ class ServeEngine:
     def _run_program(self, name: str, *args, lanes: int, attended: int,
                      positions: int = 0, kv_blocks: int = 0,
                      window_tokens: int = 0, fetch: bool = True,
-                     greedy: bool = False):
+                     greedy: bool = False, defer: bool = False,
+                     then: Optional[Callable] = None,
+                     ahead: Optional[int] = None):
         """Call the compiled model program ``name`` on the params, the
         pools, the recurrent state (a hybrid stack) and ``args`` under
         ``serve.program`` and bring its result to the host under
@@ -763,14 +828,23 @@ class ServeEngine:
         window)`` each), and the call's pair counts come to the host with
         the logits: ``routed_pairs`` (pairs that landed on a held expert,
         over the expert layers) and ``experts_hit`` (held experts with at
-        least one) on the span, and the ``tdx.serve.moe_*`` counters."""
+        least one) on the span, and the ``tdx.serve.moe_*`` counters.
+
+        With ``defer`` (a replica that decodes ahead, docs/serving.md) the
+        call is read later in the step or in the next one: the copy of what
+        will be read (the tokens or the logits) is queued behind the call,
+        traced or not, nothing waits, ``then(logits, what will be read)``
+        runs inside the span after the launches, and what will be read
+        comes back on the device; ``ahead`` goes on the span."""
         gdn = positions * self._gdn_layers
         if gdn:
             (self._gdn_decode if name == "decode"
              else self._gdn_prefill).inc(gdn)
+        extra = {} if ahead is None else {"ahead": ahead}
         with observe.span("serve.program", category="serve", program=name,
                           lanes=lanes, attended_tokens=attended,
-                          kv_blocks=kv_blocks, positions=positions) as sp:
+                          kv_blocks=kv_blocks, positions=positions,
+                          **extra) as sp:
             with observe.span("serve.program.launch", category="serve",
                               program=name, call="program"):
                 logits, self.k_pages, self.v_pages, *state = self._program(
@@ -799,13 +873,20 @@ class ServeEngine:
                 with observe.span("serve.program.launch", category="serve",
                                   program=name, call="greedy"):
                     coming = _greedy(logits)
-            if fetch and observe.enabled():
-                # Queue the copy behind the call before waiting, as the
-                # untraced fetch does: waiting first would add a round
-                # trip (0.4 ms on the chip's host) that only traced ticks
-                # paid.
+            if defer:
                 coming.copy_to_host_async()
-            _wait_traced(name, coming)
+                if then is not None:
+                    then(logits, coming)
+            else:
+                if fetch and observe.enabled():
+                    # Queue the copy behind the call before waiting, as the
+                    # untraced fetch does: waiting first would add a round
+                    # trip (0.4 ms on the chip's host) that only traced
+                    # ticks paid.
+                    coming.copy_to_host_async()
+                _wait_traced(name, coming)
+        if defer:
+            return coming
         if not fetch:
             return None
         with observe.span("serve.tick.d2h", category="serve", program=name,
@@ -956,12 +1037,13 @@ class ServeEngine:
                                 jnp.asarray(row), *self._slot_arg(lane))
                     logits = self._run_program(
                         name, *args, lanes=1, attended=L, positions=L,
-                        window_tokens=self._window_tokens(L))
+                        window_tokens=self._window_tokens(L),
+                        defer=self._decodes_ahead())
                     lane.length = L
                     reqledger.on_event(req.rid, "prefill", bucket=bucket,
                                        n=L, replica=self.slo.name)
                 else:
-                    logits = self._run_chunk(lane)  # None → more chunks
+                    name, logits = self._run_chunk(lane)  # None → more
         except BaseException:
             # The request left the queue and its pages are allocated,
             # but it is not in `active` yet — step()'s fault handler
@@ -985,13 +1067,14 @@ class ServeEngine:
         observe.counter("tdx.serve.prefills").inc()
         observe.counter("tdx.serve.prefill_tokens").inc(L - start)
         if logits is not None:
-            self._finish_prefill(lane, logits)
+            self._finish_prefill(lane, logits, name)
 
-    def _run_chunk(self, lane: _Lane) -> Optional[np.ndarray]:
+    def _run_chunk(self, lane: _Lane):
         """One prefill chunk for ``lane``: copy-on-write its first page
         if shared, run the bucketed chunk program over the next
-        ``prefill_chunk`` prompt tokens.  Returns the final position's
-        logits when the prompt is complete, else ``None``."""
+        ``prefill_chunk`` prompt tokens.  Returns the program's name and
+        the final position's logits when the prompt is complete (still on
+        the device where the replica decodes ahead), else ``None``."""
         req = lane.req
         L = len(req.tokens)
         s = lane.length
@@ -1014,14 +1097,16 @@ class ServeEngine:
             args = (jnp.asarray(toks), jnp.asarray([s], jnp.int32),
                     jnp.asarray([s + n], jnp.int32), jnp.asarray(row),
                     *self._slot_arg(lane))
+        last = s + n >= L
         logits = self._run_program(
             name, *args, lanes=1, attended=s + n, positions=n,
-            window_tokens=self._window_tokens(s + n), fetch=s + n >= L)
+            window_tokens=self._window_tokens(s + n), fetch=last,
+            defer=last and self._decodes_ahead())
         lane.length = s + n
         observe.counter("tdx.serve.prefill_chunks").inc()
         reqledger.on_chunk(req.rid, bucket=bucket, n_tokens=n,
                            replica=self.slo.name)
-        return logits
+        return name, logits
 
     def _cow_for(self, lane: _Lane, page_index: int) -> None:
         """Give ``lane`` a private copy of its ``page_index``-th page if
@@ -1076,15 +1161,21 @@ class ServeEngine:
                 continue
             if self._pending_chunk_faults:
                 chaos.execute(self._pending_chunk_faults.pop(0))
-            logits = self._run_chunk(lane)
+            name, logits = self._run_chunk(lane)
             if logits is not None:
-                self._finish_prefill(lane, logits)
+                self._finish_prefill(lane, logits, name)
 
-    def _finish_prefill(self, lane: _Lane, logits: np.ndarray) -> None:
+    def _finish_prefill(self, lane: _Lane, logits, program: str) -> None:
         """The prompt's K/V is fully written: publish its full pages to
         the prefix cache (BEFORE the first emit — retirement may free
         the sequence immediately, and the cache's references are what
-        keep the pages alive), then deliver the first token (TTFT)."""
+        keep the pages alive), then deliver the first token (TTFT).
+        Logits still on the device (a replica that decodes ahead) are read
+        after the step's tick is dispatched (``_decode_ahead_step``); the
+        lane sits that tick out.  ``program`` is the call that made them."""
+        if not isinstance(logits, np.ndarray):
+            self._rows.append((lane, program, logits))
+            return
         lane.prefilling = False
         req = lane.req
         L = len(req.tokens)
@@ -1113,9 +1204,17 @@ class ServeEngine:
 
     # -- decode ---------------------------------------------------------------
 
+    def _decodes(self, lane: _Lane) -> bool:
+        """Whether ``lane`` takes part in the next tick: it is prefilled,
+        and the ticks dispatched for it and not read (``ahead``) leave its
+        budget and the context cap room for one more token.  A lane whose
+        budget ends with the tick in flight sits the next one out."""
+        return (not lane.prefilling
+                and len(lane.generated) + lane.ahead < lane.req.max_new_tokens
+                and lane.length + lane.ahead < self.scfg.max_context)
+
     def _decodable(self) -> List[int]:
-        return [s for s in sorted(self.active)
-                if not self.active[s].prefilling]
+        return [s for s in sorted(self.active) if self._decodes(self.active[s])]
 
     def _ensure_capacity(self) -> None:
         """Every decoding lane must own a page slot for its next token;
@@ -1125,11 +1224,11 @@ class ServeEngine:
         for slot in sorted(self.active,
                            key=lambda s: (self.active[s].admitted_step, s)):
             lane = self.active.get(slot)
-            if lane is None or lane.prefilling:
+            if lane is None or not self._decodes(lane):
                 continue
             while True:
                 try:
-                    self.kv.extend(lane.seq_id, lane.length + 1)
+                    self.kv.extend(lane.seq_id, lane.length + lane.ahead + 1)
                     break
                 except OutOfPages:
                     if self.prefix.evict():
@@ -1142,13 +1241,150 @@ class ServeEngine:
                     if victim == slot:
                         break  # this lane itself was the youngest
 
+    def _decodes_ahead(self) -> bool:
+        """Whether this replica dispatches a plain tick before it reads the
+        one before (docs/serving.md §Decoding ahead): it has no drafter,
+        which needs a tick's tokens to propose for the next, and its plain
+        tick reads nothing but its tokens (the afmoe family's reads the
+        held experts' pair counts, carried in the donated state)."""
+        return self._drafter is None and self.kv.cfg.window is None
+
     def _decode_step(self) -> None:
+        if self._decodes_ahead():
+            self._decode_ahead_step()
+            return
         if not self._decodable():
             return
         if self._drafter is not None:
             self._spec_decode_step()
         else:
             self._plain_decode_step()
+
+    def _decode_ahead_step(self) -> None:
+        """Dispatch this step's tick, then read the one before and the
+        step's prompt rows while it runs: the host's part of a tick (the
+        emit, retirement, callbacks, the next step's admission and tables)
+        overlaps the chip's.  At most one tick is in flight; a step with no
+        lane to decode only reads (drains)."""
+        prev = self._tick
+        tick, tokens = self._dispatch_tick(prev)
+        if tick is None:
+            self._drain_ahead()
+        else:
+            self._tick = tick
+            if prev is not None:
+                self._emit_tick(prev, tokens)
+        if not self._rows:
+            return
+        rows, self._rows = self._rows, []
+        for lane, program, logits in rows:
+            host = _read_late(program, logits)
+            if self.active.get(lane.slot) is lane:  # not preempted meanwhile
+                self._finish_prefill(lane, host, program)
+
+    def _dispatch_tick(self, prev: Optional[_Tick]):
+        """Build and dispatch a plain tick without reading it; returns the
+        tick (None with no lane to decode) and ``prev``'s tokens.  A lane's
+        position moves one a tick whatever its token, so room is reserved
+        before ``prev`` is read; a lane that decoded in ``prev`` takes its
+        token on the device (``_merge``).  Inside the call's span, after its
+        launches, the rows of the lanes whose budget this tick ends are cut
+        behind it and ``prev``'s tokens are read (``_read_late``)."""
+        with observe.span("serve.tick.tables", category="serve",
+                          program="decode"):
+            t0 = time.perf_counter()
+            self._ensure_capacity()
+            slots = self._decodable()
+            if not slots:
+                return None, None
+            B = self.scfg.max_batch
+            maxp = self.scfg.max_pages_per_seq
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            table = np.zeros((B, maxp), np.int32)
+            lanes = [(s, self.active[s]) for s in slots]
+            table[slots] = self.kv.table_rows(
+                [lane.seq_id for _, lane in lanes], maxp)
+            ending, merge = [], False
+            for slot, lane in lanes:
+                positions[slot] = lane.length + lane.ahead
+                if lane.ahead:
+                    tokens[slot], merge = -1, True  # ``prev``'s, on the device
+                else:
+                    tokens[slot] = (lane.generated[-1] if lane.generated
+                                    else lane.req.tokens[-1])
+                lane.ahead += 1
+                if not self._decodes(lane):
+                    ending.append(slot)
+            toks = jnp.asarray(tokens)
+            if merge:
+                toks = _merge(prev.tokens, toks)
+            args = (toks, jnp.asarray(positions), jnp.asarray(table))
+            n_lanes = len(slots)
+            attended = int(positions.sum()) + n_lanes
+            kv_blocks = kv_blocks_walked(positions[slots] + 1,
+                                         *self._kernel_pool)
+            self._decode_steps.inc()
+            self._attended.inc(attended)
+            self._kv_blocks.inc(kv_blocks)
+            self._lane_ticks.inc(n_lanes)
+            if prev is not None:
+                self._ahead_ticks.inc()
+            tick, read = _Tick(lanes, prev is not None, t0), []
+
+            def behind(logits, greedy):
+                tick.logits, tick.tokens = logits, greedy
+                for slot in ending:
+                    with observe.span("serve.program.launch",
+                                      category="serve", program="decode",
+                                      call="row"):
+                        tick.rows[slot] = row = _row(logits, slot)
+                        row.copy_to_host_async()
+                if prev is not None:
+                    read.append(_read_late("decode", prev.tokens))
+
+        self._run_program(
+            "decode", *args, lanes=n_lanes, attended=attended,
+            positions=n_lanes, kv_blocks=kv_blocks, greedy=True, defer=True,
+            then=behind, ahead=int(prev is not None))
+        return tick, read[0] if read else None
+
+    def _emit_tick(self, tick: _Tick, tokens: np.ndarray) -> None:
+        """Hand ``tick``'s tokens over to the lanes it was dispatched for
+        that are still active (a lane that retired, was cancelled or
+        preempted meanwhile gets nothing).  Token latency runs from the
+        read before, or from the tick's tables for a tick dispatched with
+        none unread."""
+        with observe.span("serve.tick.emit", category="serve",
+                          program="decode") as sp:
+            live = [(s, lane) for s, lane in tick.lanes
+                    if self.active.get(s) is lane]
+            sp.set(tokens=len(live))
+            now = time.perf_counter()
+            dt = now - (self._t_read if tick.ahead else tick.t0)
+            self._t_read = now
+            if live:
+                self._tok_hist.observe(dt, n=len(live))
+                self.slo.observe_token_latency(dt, n=len(live))
+            if reqledger.enabled():
+                for _, lane in live:
+                    reqledger.on_decode(lane.req.rid, n_lanes=len(tick.lanes),
+                                        replica=self.slo.name)
+            for slot, lane in live:
+                lane.ahead -= 1
+                lane.length += 1
+                self._emit(lane, int(tokens[slot]),
+                           _TickRow(tick.logits, slot, tick.rows.get(slot)))
+            # The tick's device arrays are released here, under the span.
+            tick.logits = tick.tokens = tick.rows = None
+
+    def _drain_ahead(self) -> None:
+        """Read the tick in flight, if any, and hand its tokens over: before
+        a step with no lane to decode, ``drain()``, the fault handler's
+        requeue, and whatever reads the engine's lanes as settled."""
+        tick, self._tick = self._tick, None
+        if tick is not None:
+            self._emit_tick(tick, _read_late("decode", tick.tokens))
 
     def _plain_decode_step(self) -> None:
         with observe.span("serve.tick.tables", category="serve",
@@ -1440,6 +1676,7 @@ class ServeEngine:
     def _retire(self, lane: _Lane, logits: "np.ndarray | _TickRow") -> None:
         self.kv.free(lane.seq_id)
         self.active.pop(lane.slot, None)
+        self._discarded.inc(lane.ahead)  # an eos found after its next tick
         self._delivered.pop(lane.req.rid, None)
         self.results[lane.req.rid] = list(lane.generated)
         self.final_logits[lane.req.rid] = np.asarray(logits, np.float32)
@@ -1525,6 +1762,19 @@ def _wait_traced(program: str, value) -> None:
     with observe.span("serve.program.wait", category="serve",
                       program=program) as sp:
         sp.block_on(value)
+
+
+def _read_late(program: str, value) -> np.ndarray:
+    """Bring ``value`` to the host, the result of a call dispatched before
+    another one was (docs/serving.md §Decoding ahead): its copy was queued
+    when the call was dispatched, so what the host waits for is the call.
+    ``serve.program.wait`` (``ahead`` 1) around ``serve.tick.d2h``, traced
+    or not the same one wait."""
+    with observe.span("serve.program.wait", category="serve",
+                      program=program, ahead=1):
+        with observe.span("serve.tick.d2h", category="serve",
+                          program=program, bytes=value.nbytes):
+            return np.asarray(value)
 
 
 # ---------------------------------------------------------------------------
