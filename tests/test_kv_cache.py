@@ -241,3 +241,78 @@ def test_a_cache_without_the_group_ignores_it():
     kv.alloc(1, 5)
     assert kv.window_advance(1, 0, 5) == 0 and kv.window_pages_in_use == 0
     assert kv.can_fit(8, 4)
+
+
+# The pools of the benchmark's serving cells as the parent of the latent
+# group laid them out (K, V; the window group; the state's two arrays): a
+# fourth group kind must leave every one of them as it was.
+_CELL_POOLS = {
+    "mistral7b-chat-backlog": ((12, 5086, 8, 16, 128), None, None),
+    "jamba2-3b-chat-backlog": ((2, 6400, 1, 16, 128), None,
+                               ((26, 128, 16, 5120), (26, 3, 128, 5120))),
+    "trinity-large-mixed-queue": ((1, 32769, 1, 16, 128),
+                                  (8, 33281, 1, 16, 128), None),
+    "olmo-hybrid-7b-d8-chat-backlog": ((2, 6400, 30, 16, 128), None,
+                                       ((6, 128, 96, 5760),
+                                        (6, 3, 128, 11520))),
+}
+_CELL_FILES = {
+    "mistral7b-chat-backlog": ("mistral-7b-v0.3-d12", "chat-backlog"),
+    "jamba2-3b-chat-backlog": ("jamba2-3b", "chat-backlog-wide"),
+    "trinity-large-mixed-queue": ("trinity-large-preview-tp8-d5",
+                                  "mixed-queue"),
+    "olmo-hybrid-7b-d8-chat-backlog": ("olmo-hybrid-7b-d8",
+                                       "chat-backlog-wide"),
+    "pangu-ultra-moe-long-doc-backlog": ("openpangu-ultra-moe-718b-ep16-d5",
+                                         "long-doc-backlog"),
+}
+
+
+def _cell_kv(cell):
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import adapters, configs, harness
+
+    conf, mix = _CELL_FILES[cell]
+    with open(os.path.join(root, "benchmark/configs", conf + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmark/traffic", mix + ".json")) as f:
+        engine = json.load(f)["engine"]
+    if "family_module" in cfg:
+        fam = harness.load_module(root, cfg["family_module"])
+        tcfg = fam.transformer_config(cfg, fam.dims(cfg))
+    else:
+        tcfg = adapters.transformer_config(cfg, configs.dims(cfg))
+    return adapters.serve_config(cfg, engine).resolve(tcfg).kv_config(tcfg)
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_POOLS))
+def test_the_cells_pools_keep_their_shapes(cell):
+    kv = _cell_kv(cell)
+    pool, window, state = _CELL_POOLS[cell]
+    assert not kv.latent
+    assert kv.pool_shape() == kv.v_pool_shape() == pool
+    assert (kv.window_pool_shape() if kv.window else None) == window
+    assert ((kv.state.ssm_shape(), kv.state.conv_shape())
+            if kv.state else None) == state
+
+
+def test_a_latent_group_is_one_pool_of_rows_and_no_values():
+    """The pangu cell's: 5 layers of 32,768 pages of 16 rows, a row the
+    512-wide latent and the 64-wide rotary key padded to 640; an empty V
+    pool (zero bytes) and the full group's free list and null page."""
+    kv = _cell_kv("pangu-ultra-moe-long-doc-backlog")
+    assert kv.latent and kv.pool_shape() == (5, 32769, 1, 16, 640)
+    assert kv.v_pool_shape() == (5, 0, 1, 16, 640)
+    assert 5 * 32769 * 16 * 640 * 2 == pytest.approx(3.36e9, rel=2e-3)
+    small = KVCacheConfig(n_layers=2, kv_heads=1, head_dim=128, page_size=4,
+                          n_pages=8, latent=True)
+    k, v = init_pools(small, "bfloat16")
+    assert k.shape == (2, 8, 1, 4, 128) and v.size == 0 and v.nbytes == 0
+    cache = PagedKVCache(small)
+    assert cache.alloc(1, 9) == [1, 2, 3] and cache.free_pages == 4

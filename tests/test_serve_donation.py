@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from torchdistx_tpu import compile_service, observe
-from torchdistx_tpu.models import TINY, TINY_AFMOE, TINY_JAMBA, TINY_OLMO_HYBRID
+from torchdistx_tpu.models import (TINY, TINY_AFMOE, TINY_JAMBA,
+                                   TINY_OLMO_HYBRID, TINY_PANGU_ULTRA_MOE)
 from torchdistx_tpu.observe.costmodel import program_costs
 from torchdistx_tpu.serve import (Request, ServeConfig, ServeEngine,
                                   serve_program_specs)
@@ -30,13 +31,19 @@ FAMILIES = {
     # the delta-rule state and the conv tail over q, k and v's channels
     "olmo_hybrid": (TINY_OLMO_HYBRID, ServeConfig(**SHAPE, prefix_cache=False,
                                                   spec_decode=False)),
+    # the latent group (its V pool has no page) and the pair counts
+    "pangu_ultra_moe": (TINY_PANGU_ULTRA_MOE, ServeConfig(
+        **SHAPE, prefix_cache=False, spec_decode=False)),
 }
 PROGRAMS = {
     "llama": ("prefill-8", "chunk-8", "cow", "decode", "verify-2"),
     "jamba": ("prefill-8", "chunk-8", "decode"),
     "afmoe": ("prefill-8", "chunk-8", "decode"),
     "olmo_hybrid": ("prefill-8", "chunk-8", "decode"),
+    "pangu_ultra_moe": ("prefill-8", "chunk-8", "decode"),
 }
+# What a model program carries: the two pools and the state behind them.
+CARRIED = {"llama": 2, "pangu_ultra_moe": 3}
 KINDS = [(f, p) for f, ps in PROGRAMS.items() for p in ps]
 
 
@@ -69,7 +76,7 @@ def _engine(family, built, programs=None):
 def test_program_consumes_and_aliases_its_pools(built, family, program):
     specs, progs, params = built[family]
     spec = specs[program]
-    n = 2 if family == "llama" else 4  # the pools; the state behind them
+    n = CARRIED.get(family, 4)  # the pools; the state behind them
     first = 0 if program == "cow" else 1
     assert spec.consumes == tuple(range(first, first + n))
     carried = [spec.args[i] for i in spec.consumes]
@@ -162,7 +169,8 @@ class _FailsOnce:
     ("llama", "decode", 3), ("llama", "prefill-8", 2), ("llama", "chunk-8", 2),
     ("llama", "cow", 1), ("jamba", "decode", 3), ("jamba", "prefill-8", 2),
     ("jamba", "chunk-8", 2), ("afmoe", "decode", 3), ("afmoe", "chunk-8", 2),
-    ("olmo_hybrid", "decode", 3), ("olmo_hybrid", "chunk-8", 2)])
+    ("olmo_hybrid", "decode", 3), ("olmo_hybrid", "chunk-8", 2),
+    ("pangu_ultra_moe", "decode", 3), ("pangu_ultra_moe", "chunk-8", 2)])
 def test_fault_inside_a_donated_call_rebuilds_the_pools(built, family,
                                                         program, nth):
     assert issubclass(jax.errors.JaxRuntimeError,
@@ -198,7 +206,7 @@ def test_fault_inside_a_donated_call_rebuilds_the_pools(built, family,
     assert not eng._pools_lost()
     assert len(eng.prefix) == 0 and not eng.active
     assert eng.kv.pages_in_use == 0 and eng.kv.state_slots_in_use == 0
-    assert len(eng.state) == (0 if family == "llama" else 2)
+    assert len(eng.state) == CARRIED.get(family, 4) - 2
     assert eng.kv.window_pages_in_use == 0
     assert {r.rid for r in eng.waiting} == {
         r.rid for r in reqs} - set(eng.results)

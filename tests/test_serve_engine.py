@@ -33,6 +33,10 @@ FAMILIES = {
     "olmo_hybrid": ("tiny-olmo-hybrid", ServeConfig(
         max_batch=4, page_size=8, n_pages=40, max_pages_per_seq=8,
         prefill_buckets=(8, 16), prefix_cache=False, spec_decode=False)),
+    "pangu_ultra_moe": ("tiny-pangu-ultra-moe", ServeConfig(
+        max_batch=4, page_size=8, n_pages=40, max_pages_per_seq=8,
+        prefill_buckets=(8, 16), prefill_chunk=16, prefix_cache=False,
+        spec_decode=False)),
 }
 SPECULATING = [f for f, (_, s) in FAMILIES.items() if s.spec_decode is None]
 # How a request ends, and the tick it ends in.

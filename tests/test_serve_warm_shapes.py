@@ -58,6 +58,10 @@ FAMILIES = {
         max_batch=4, page_size=8, n_pages=20, max_pages_per_seq=8,
         prefill_buckets=(8, 16), prefix_cache=False, spec_decode=False),
         False),
+    "pangu_ultra_moe": ("tiny-pangu-ultra-moe", ServeConfig(
+        max_batch=4, page_size=8, n_pages=20, max_pages_per_seq=8,
+        prefill_buckets=(8, 16), prefill_chunk=16, prefix_cache=False,
+        spec_decode=False), True),
 }
 
 

@@ -259,6 +259,33 @@ def test_the_delta_rule_kernels_compile_for_v5e(one_chip, kernel, S):
                    else "DECODE_UPDATE") in compiled.as_text()
 
 
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_the_latent_kernels_compile_for_v5e(one_chip, kernel):
+    """The two MLA kernels at openPangu-Ultra-MoE's widths (128 heads, a
+    cached row of 512 + 64 padded to 640; qk 192, v 128): the decode walk
+    over 64 lanes of up to 1,088 pages in a pool of five layers, and a
+    2,048-query chunk over the table's 17,408 keys."""
+    from torchdistx_tpu.ops import mla
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    i32 = jnp.int32
+    if kernel == "decode":
+        fn = jax.jit(lambda q, pool, n, t: mla.mla_decode(
+            q, pool, n, t, v_width=512, scale=0.07, interpret=False))
+        compiled = fn.lower(arg((64, 128, 640)), arg((5 * 32769, 1, 16, 640)),
+                            arg((64,), i32), arg((64, 1088), i32)).compile()
+    else:
+        fn = jax.jit(lambda *a: mla.mla_prefill(*a, scale=0.07,
+                                                interpret=False))
+        compiled = fn.lower(
+            arg((128, 2048, 128)), arg((128, 2048, 64)),
+            arg((128, 17408, 128)), arg((128, 17408, 128)),
+            arg((17408, 64)), arg((), i32), arg((), i32)).compile()
+    assert getattr(mla, kernel.upper()) in compiled.as_text()
+
+
 # -- the serving programs, whole, at the benchmark's cells' widths ------------
 #
 # Every program that takes the pools consumes them and returns them in
@@ -277,6 +304,8 @@ _CELLS = {
                                   "mixed-queue"),
     "olmo-hybrid-7b-d8-chat-backlog": ("olmo-hybrid-7b-d8",
                                        "chat-backlog-wide"),
+    "pangu-ultra-moe-long-doc-backlog": ("openpangu-ultra-moe-718b-ep16-d5",
+                                         "long-doc-backlog"),
     # no cell of the manifest (PERF.md 7: measured and left out in PR 34);
     # the dense stack at the same traffic's widths, 800 pages a sequence
     "mistral-under-mixed-queue": ("mistral-7b-v0.3-d12", "mixed-queue"),
@@ -300,7 +329,7 @@ def _cell_specs(cell):
         cfg = json.load(f)
     with open(os.path.join(root, "benchmark", "traffic", mix + ".json")) as f:
         engine = json.load(f)["engine"]
-    if cfg["family"] in ("jamba", "afmoe", "olmo_hybrid"):
+    if cfg["family"] in ("jamba", "afmoe", "olmo_hybrid", "pangu_ultra_moe"):
         from benchmark import harness
 
         fam = harness.load_module(root, cfg["family_module"])
@@ -343,6 +372,9 @@ _ALIAS_CASES = [
     ("olmo-hybrid-7b-d8-chat-backlog", "decode", ()),
     ("olmo-hybrid-7b-d8-chat-backlog", "prefill-128", (4,)),
     ("olmo-hybrid-7b-d8-chat-backlog", "chunk-512", (4,)),
+    # The latent family: the latent group (its V pool has no page) and the
+    # pair counts (its chunk's kernel: ``test_the_latent_kernels_compile``).
+    ("pangu-ultra-moe-long-doc-backlog", "decode", ()),
     ("mistral-under-mixed-queue", "decode", ()),
     ("mistral-under-mixed-queue", "chunk-2048", ()),
 ]
@@ -365,9 +397,13 @@ def test_serving_program_aliases_its_pools_on_v5e(one_chip, monkeypatch, cell,
         spec.args))
     compiled, _ = compile_serving_program(spec)
     text = compiled.as_text()
+    latent = cell.startswith("pangu")
     if program == "decode":
-        assert "tdx_paged_attention_decode" in text
-    if cell.startswith("trinity"):
+        assert ("tdx_mla_decode" if latent
+                else "tdx_paged_attention_decode") in text
+    if latent and program.startswith("chunk"):
+        assert "tdx_mla_prefill" in text
+    if cell.startswith(("trinity", "pangu")):
         # the expert products are the program's own kernel, not XLA's
         assert "tdx_moe_experts_gmm" in text and "ragged-dot" not in text
     # Parameters are numbered over the flattened arguments: the consumed
@@ -379,14 +415,17 @@ def test_serving_program_aliases_its_pools_on_v5e(one_chip, monkeypatch, cell,
     assert aliased == set(range(first, first + len(spec.consumes)))
     carried = [spec.args[i] for i in spec.consumes]
     # The afmoe family's pair counts, int32 [4, 32], are laid out in a
-    # whole (8, 128) tile on the chip: 2,048 bytes for their 512.
-    tile_pad = 1536 if cell.startswith("trinity") else 0
+    # whole (8, 128) tile on the chip: 2,048 bytes for their 512; the
+    # latent family's, int32 [4, 16], in the same 2,048 for their 256.
+    tile_pad = {"trinity": 1536, "pangu": 1792}.get(cell.split("-")[0], 0)
     assert program_costs(compiled)["alias_bytes"] == tile_pad + sum(
         a.size * jnp.dtype(a.dtype).itemsize for a in carried)
     copies = [line.strip()[:120] for line in text.splitlines()
               if re.search(r"= \S+ copy\(", line)]
     for i, a in zip(spec.consumes, carried):
-        if i in layout_copies:
+        # The latent group's V pool has no element: XLA folds it into a
+        # constant, and a copy of it moves no byte.
+        if i in layout_copies or a.size == 0:
             continue
         assert not [c for c in copies if f"= {_hlo_shape(a)}" in c], (
             program, _hlo_shape(a))

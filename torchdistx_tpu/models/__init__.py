@@ -1,5 +1,5 @@
 """Model families: GPT-2, Llama, T5, Mixtral, ViT, and the hybrid stacks
-(Jamba, AFMoE, Olmo-Hybrid) — flax.linen, TPU-first."""
+(Jamba, AFMoE, Olmo-Hybrid, openPangu-Ultra-MoE) — flax.linen, TPU-first."""
 
 from .configs import (
     GPT2_125M,
@@ -14,6 +14,7 @@ from .configs import (
     TINY_JAMBA,
     TINY_MOE,
     TINY_OLMO_HYBRID,
+    TINY_PANGU_ULTRA_MOE,
     TINY_T5,
     TINY_VIT,
     VIT_B16,
@@ -22,7 +23,9 @@ from .configs import (
     EncDecConfig,
     GatedDeltaNetConfig,
     MambaConfig,
+    MLAConfig,
     MoEConfig,
+    RoutedExperts,
     TransformerConfig,
     VisionConfig,
 )
@@ -32,6 +35,7 @@ from .gpt2 import GPT2Model, make_gpt2
 from .jamba import JambaModel, make_jamba
 from .llama import LlamaModel, make_llama
 from .olmo_hybrid import OlmoHybridModel, make_olmo_hybrid
+from .pangu_ultra_moe import PanguUltraMoEModel, make_pangu_ultra_moe
 from .mixtral import make_mixtral
 from .plans import decoder_lm_plan, t5_plan, vit_plan
 from .t5 import T5Model, make_t5
@@ -45,6 +49,8 @@ __all__ = [
     "MoEConfig",
     "MambaConfig",
     "GatedDeltaNetConfig",
+    "MLAConfig",
+    "RoutedExperts",
     "PRESETS",
     "GPT2_125M",
     "LLAMA3_8B",
@@ -57,6 +63,7 @@ __all__ = [
     "TINY_JAMBA",
     "TINY_MOE",
     "TINY_OLMO_HYBRID",
+    "TINY_PANGU_ULTRA_MOE",
     "TINY_T5",
     "TINY_VIT",
     "VIT_B16",
@@ -67,6 +74,7 @@ __all__ = [
     "JambaModel",
     "LlamaModel",
     "OlmoHybridModel",
+    "PanguUltraMoEModel",
     "PipelineDecomposition",
     "T5Model",
     "ViTModel",
@@ -76,6 +84,7 @@ __all__ = [
     "make_llama",
     "make_mixtral",
     "make_olmo_hybrid",
+    "make_pangu_ultra_moe",
     "make_t5",
     "make_vit",
     "decoder_lm_plan",

@@ -21,7 +21,9 @@ With ``d`` the model width and RMSNorm ``x * rsqrt(mean(x^2) + eps) * w``::
            y = Shared(x) + sum_{e in S} w_e Expert_e(x)
 
 **Held experts.**  The layer is told which experts it holds
-(``AfmoeConfig.first_expert`` / ``held_experts``): it routes over all
+(``RoutedExperts.first_expert`` / ``held_experts``: :func:`experts`, the
+afmoe config's or, in the ``pangu_ultra_moe`` family that shares this
+layer, the stack's own): it routes over all
 ``n_experts`` and computes the part of the sum that its own experts
 give, as one chip of an expert-parallel group does; the pairs that land
 on experts held elsewhere contribute nothing here (the exchange that
@@ -51,7 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.grouped_matmul import grouped_matmul
-from .configs import TransformerConfig
+from .configs import RoutedExperts, TransformerConfig
 from .layers import apply_rope
 
 F32 = jnp.float32
@@ -70,13 +72,26 @@ def check(cfg: TransformerConfig) -> None:
         raise ValueError(
             f"layer_types {a.layer_types!r} is not one of 'sliding' | 'full' "
             f"for each of the {cfg.n_layers} layers")
-    if not (0 <= a.first_expert and a.held_experts >= 1
-            and a.first_expert + a.held_experts <= a.n_experts):
+    check_experts(cfg)
+
+
+def check_experts(cfg) -> None:
+    """The expert layer's share is one the router can route to."""
+    e = experts(cfg)
+    if not (0 <= e.first_expert and e.held_experts >= 1
+            and e.first_expert + e.held_experts <= e.n_experts):
         raise ValueError(
-            f"held experts [{a.first_expert}, {a.first_expert + a.held_experts}"
-            f") are not among the {a.n_experts} the router scores")
-    if not (0 <= a.n_dense_layers <= cfg.n_layers):
-        raise ValueError(f"n_dense_layers={a.n_dense_layers}")
+            f"held experts [{e.first_expert}, {e.first_expert + e.held_experts}"
+            f") are not among the {e.n_experts} the router scores")
+    if not (0 <= e.n_dense_layers <= cfg.n_layers):
+        raise ValueError(f"n_dense_layers={e.n_dense_layers}")
+
+
+def experts(cfg) -> RoutedExperts:
+    """The expert layer's routing and share: ``cfg.experts`` where the
+    stack gives its own (``models/pangu_ultra_moe.py``), else the afmoe
+    config's."""
+    return cfg.experts if cfg.experts is not None else cfg.afmoe.experts
 
 
 def is_sliding(cfg, i: int) -> bool:
@@ -84,7 +99,7 @@ def is_sliding(cfg, i: int) -> bool:
 
 
 def is_dense(cfg, i: int) -> bool:
-    return i < cfg.afmoe.n_dense_layers
+    return i < experts(cfg).n_dense_layers
 
 
 def group_rows(cfg) -> List[Tuple[bool, int]]:
@@ -108,7 +123,7 @@ def n_full_layers(cfg) -> int:
 
 
 def n_expert_layers(cfg) -> int:
-    return cfg.n_layers - cfg.afmoe.n_dense_layers
+    return cfg.n_layers - experts(cfg).n_dense_layers
 
 
 # -- layer math (pure functions of one layer's parameters) -------------------
@@ -178,7 +193,7 @@ def route(cfg, lp, x):
     [T, k] float32).  Scores, choice and weights in float32 at full
     matmul precision: a choice that flips on rounding swaps a whole
     expert's output."""
-    a = cfg.afmoe
+    a = experts(cfg)
     with jax.named_scope(ROUTER):
         s = jax.nn.sigmoid(jnp.dot(
             x.astype(F32), lp["router"].astype(F32), precision=HIGHEST))
@@ -193,7 +208,7 @@ def held_expert_sum(cfg, lp, x, idx, w, valid):
     """The held experts' part of ``sum_e w_e Expert_e(x)``: x [T, d],
     idx / w [T, k], valid [T] (a padded position routes nowhere) ->
     (float32 [T, d], pairs a held expert got, int32 [held])."""
-    a = cfg.afmoe
+    a = experts(cfg)
     T, d = x.shape
     k, n = a.top_k, a.held_experts
     local = idx.reshape(-1) - a.first_expert                  # [T*k]
@@ -294,7 +309,7 @@ def layer_shapes(cfg, i: int) -> dict:
     """name -> (shape, kind) of layer ``i``'s parameters; kind is
     ``"w"`` (a matrix), ``"experts"`` (one matrix a held expert),
     ``"scale"`` (a norm's gains) or ``"bias"`` (the selection bias)."""
-    a, d, hd = cfg.afmoe, cfg.d_model, cfg.head_size
+    d, hd = cfg.d_model, cfg.head_size
     H, KV = cfg.n_heads, cfg.kv_heads
     out = {n: ((d,), "scale") for n in (
         "norm_in", "norm_post_attn", "norm_pre_mlp", "norm_post_mlp")}
@@ -304,22 +319,29 @@ def layer_shapes(cfg, i: int) -> dict:
         "wo": ((H, hd, d), "w"),
         "q_norm": ((hd,), "scale"), "k_norm": ((hd,), "scale"),
     })
-    if is_dense(cfg, i):
-        out.update({"w_gate": ((d, cfg.d_ff), "w"),
-                    "w_up": ((d, cfg.d_ff), "w"),
-                    "w_down": ((cfg.d_ff, d), "w")})
-    else:
-        de, n = a.d_expert, a.held_experts
-        out.update({
-            "router": ((d, a.n_experts), "w"),
-            "router_bias": ((a.n_experts,), "bias"),
-            "experts_w_gate": ((n, d, de), "experts"),
-            "experts_w_up": ((n, d, de), "experts"),
-            "experts_w_down": ((n, de, d), "experts"),
-            "shared_w_gate": ((d, de), "w"), "shared_w_up": ((d, de), "w"),
-            "shared_w_down": ((de, d), "w"),
-        })
+    out.update(ffn_shapes(cfg, i))
     return out
+
+
+def ffn_shapes(cfg, i: int) -> dict:
+    """The feed-forward part of layer ``i``'s parameters (as
+    :func:`layer_shapes`): a dense MLP's three matrices, or the router,
+    its selection bias, the held experts and the shared one."""
+    d = cfg.d_model
+    if is_dense(cfg, i):
+        return {"w_gate": ((d, cfg.d_ff), "w"), "w_up": ((d, cfg.d_ff), "w"),
+                "w_down": ((cfg.d_ff, d), "w")}
+    e = experts(cfg)
+    de, n = e.d_expert, e.held_experts
+    return {
+        "router": ((d, e.n_experts), "w"),
+        "router_bias": ((e.n_experts,), "bias"),
+        "experts_w_gate": ((n, d, de), "experts"),
+        "experts_w_up": ((n, d, de), "experts"),
+        "experts_w_down": ((n, de, d), "experts"),
+        "shared_w_gate": ((d, de), "w"), "shared_w_up": ((d, de), "w"),
+        "shared_w_down": ((de, d), "w"),
+    }
 
 
 def _experts_init(std: float):

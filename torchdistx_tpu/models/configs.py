@@ -55,6 +55,49 @@ class GatedDeltaNetConfig:
 
 
 @dataclass(frozen=True)
+class RoutedExperts:
+    """What the expert layer of ``models/afmoe.py`` (``route``,
+    ``held_expert_sum``, ``moe``) reads, whichever family's stack it sits
+    in: ``n_dense_layers`` leading dense MLPs, then layers whose router
+    scores all ``n_experts`` (sigmoid, a selection bias, top ``top_k``,
+    weights normalised and times ``route_scale``) and whose replica holds
+    the ``held_experts`` from ``first_expert`` on, each a gated MLP of
+    width ``d_expert``, as is the one shared expert."""
+
+    n_experts: int = 256
+    top_k: int = 8
+    d_expert: int = 2048
+    n_dense_layers: int = 1
+    route_scale: float = 2.5
+    first_expert: int = 0
+    held_experts: int = 16
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention, MLA (models/mla.py): queries through a rank
+    ``q_rank`` bottleneck, keys and values from ONE normed latent of
+    ``kv_rank`` a token, which is what the cache holds beside a rotary
+    key of ``rope_dim`` shared by the heads.  A head's query and key are
+    ``nope_dim + rope_dim`` wide, its value ``v_dim``."""
+
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """One cached row: the latent, then the rotary key."""
+        return self.kv_rank + self.rope_dim
+
+
+@dataclass(frozen=True)
 class AfmoeConfig:
     """The AFMoE stack (arcee-ai Trinity; models/afmoe.py): sandwich-normed
     blocks with gated, QK-normed attention that is windowed (with rotary)
@@ -73,6 +116,14 @@ class AfmoeConfig:
     route_scale: float = 2.448
     first_expert: int = 0
     held_experts: int = 32
+
+    @property
+    def experts(self) -> RoutedExperts:
+        return RoutedExperts(
+            n_experts=self.n_experts, top_k=self.top_k,
+            d_expert=self.d_expert, n_dense_layers=self.n_dense_layers,
+            route_scale=self.route_scale, first_expert=self.first_expert,
+            held_experts=self.held_experts)
 
 
 @dataclass(frozen=True)
@@ -102,6 +153,9 @@ class TransformerConfig:
     afmoe: Optional[AfmoeConfig] = None  # AFMoE stack (models/afmoe.py)
     # hybrid linear-attention stack (models/olmo_hybrid.py)
     olmo_hybrid: Optional[GatedDeltaNetConfig] = None
+    # latent attention over routed experts (models/pangu_ultra_moe.py)
+    mla: Optional[MLAConfig] = None
+    experts: Optional[RoutedExperts] = None
 
     dtype: jnp.dtype = jnp.bfloat16  # activation/compute dtype
     param_dtype: jnp.dtype = jnp.float32
@@ -299,6 +353,18 @@ TINY_AFMOE = TransformerConfig(
         window=16, first_expert=0, held_experts=4),
 )
 
+# A leading dense layer and an expert layer of latent attention, the
+# widths cut to CPU size (n_heads x nope_dim != d_model, as published).
+TINY_PANGU_ULTRA_MOE = TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=2, n_heads=4, d_ff=128,
+    max_seq_len=256, rope_theta=10000.0, tie_embeddings=False,
+    norm_eps=1e-5, dtype=jnp.float32,
+    mla=MLAConfig(q_rank=32, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8),
+    experts=RoutedExperts(n_experts=8, top_k=2, d_expert=32,
+                          n_dense_layers=1, route_scale=2.5, first_expert=0,
+                          held_experts=4),
+)
+
 TINY_T5 = EncDecConfig(
     encoder=_T5_STACK.replace(
         vocab_size=256, d_model=64, n_layers=2, n_heads=4, head_dim=16, d_ff=128,
@@ -335,6 +401,7 @@ PRESETS = {
     "tiny-jamba": TINY_JAMBA,
     "tiny-afmoe": TINY_AFMOE,
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
+    "tiny-pangu-ultra-moe": TINY_PANGU_ULTRA_MOE,
     "tiny-t5": TINY_T5,
     "tiny-vit": TINY_VIT,
 }

@@ -93,6 +93,7 @@ replica that survived a fault leaves the evidence.
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -105,6 +106,7 @@ import numpy as np
 from .. import chaos, compile_service, observe, transport
 from ..observe import reqledger
 from ..models import PRESETS, TransformerConfig, olmo_hybrid
+from ..ops import mla as mla_ops
 from ..ops.paged_attention import kv_blocks_walked
 from ..utils.logging import get_logger
 from .kv_cache import (OutOfPages, PagedKVCache, init_pools, init_state,
@@ -332,7 +334,14 @@ class ServeEngine:
         self._kv_blocks = observe.counter("tdx.serve.attn_kv_blocks")
         _, _, kv_local, page, head_dim = self.k_pages.sharding.shard_shape(
             self.k_pages.shape)
-        self._kernel_pool = (page, kv_local, head_dim, self.k_pages.dtype)
+        if self.kv.cfg.latent:
+            self._walked = functools.partial(
+                mla_ops.blocks_walked, page_size=page, width=head_dim,
+                dtype=self.k_pages.dtype)
+        else:
+            self._walked = functools.partial(
+                kv_blocks_walked, page_size=page, kv_heads=kv_local,
+                head_dim=head_dim, dtype=self.k_pages.dtype)
         self._n_admitted = 0  # lifetime; serve.admit reports a tick's share
         # The afmoe family: what its expert layers routed to the experts
         # this replica holds, from the counts every call brings back.
@@ -346,6 +355,12 @@ class ServeEngine:
                             if cfg.olmo_hybrid is not None else 0)
         self._gdn_decode = observe.counter("tdx.serve.gdn_decode_positions")
         self._gdn_prefill = observe.counter("tdx.serve.gdn_prefill_positions")
+        # The pangu_ultra_moe family: latent rows its decode ticks read and
+        # query-key pairs its prefill and chunk calls attend, summed over
+        # its latent-attention layers (always on).
+        self._mla_layers = cfg.n_layers if cfg.mla is not None else 0
+        self._mla_decode = observe.counter("tdx.serve.mla_decode_tokens")
+        self._mla_prefill = observe.counter("tdx.serve.mla_prefill_pairs")
         # Decoding ahead (docs/serving.md): the plain tick in flight, the
         # prompts' last rows dispatched this step and not read yet, when a
         # tick was last read; plain ticks dispatched while the tick before
@@ -372,12 +387,22 @@ class ServeEngine:
         of the family threads behind them (``self.state``): a hybrid
         stack's recurrent layer group (ssm, conv; a lane's slot is the
         lane's index), the afmoe family's window layer group and the
-        held experts' running pair counts, else nothing."""
+        held experts' running pair counts, the pangu_ultra_moe family's
+        pair counts, else nothing.  ``_pairs_at``: where in ``state`` the
+        pair counts are (None without an expert layer)."""
         cfg, kv = self.cfg, self.kv.cfg
         sharding = pool_sharding(self.mesh, cfg.kv_heads)
         self.k_pages, self.v_pages = init_pools(kv, cfg.dtype, sharding)
         self.state: tuple = ()
-        if kv.state is not None:
+        self._pairs_at: Optional[int] = None
+        if kv.latent:
+            from ..models import afmoe
+
+            self.state = (jnp.zeros((afmoe.n_expert_layers(cfg),
+                                     cfg.experts.held_experts), jnp.int32),)
+            self._pairs_at = 0
+            self._pairs_seen = np.zeros(self.state[0].shape, np.int32)
+        elif kv.state is not None:
             self.state = init_state(
                 kv.state, cfg.dtype,
                 state_sharding(self.mesh, kv.state.d_inner))
@@ -388,6 +413,7 @@ class ServeEngine:
                 init_window_pool(kv, cfg.dtype, sharding),
                 jnp.zeros((afmoe.n_expert_layers(cfg),
                            cfg.afmoe.held_experts), jnp.int32))
+            self._pairs_at = 1
             self._pairs_seen = np.zeros(self.state[1].shape, np.int32)
 
     def _pools_lost(self) -> bool:
@@ -841,6 +867,15 @@ class ServeEngine:
             (self._gdn_decode if name == "decode"
              else self._gdn_prefill).inc(gdn)
         extra = {} if ahead is None else {"ahead": ahead}
+        if self._mla_layers and name == "decode":
+            extra["mla_attended"] = attended * self._mla_layers
+            self._mla_decode.inc(extra["mla_attended"])
+        elif self._mla_layers:
+            # Positions [attended - positions, attended), each with its
+            # causal context: n s + n (n + 1) / 2 pairs a layer.
+            n, s = positions, attended - positions
+            extra["mla_pairs"] = (n * s + n * (n + 1) // 2) * self._mla_layers
+            self._mla_prefill.inc(extra["mla_pairs"])
         with observe.span("serve.program", category="serve", program=name,
                           lanes=lanes, attended_tokens=attended,
                           kv_blocks=kv_blocks, positions=positions,
@@ -857,14 +892,15 @@ class ServeEngine:
             # its pairs are counted with the next call that is fetched
             # (the device keeps a running sum).  With telemetry on every
             # call is waited for anyway and the span gets its own counts.
-            if self.kv.cfg.window is not None and (fetch or observe.enabled()):
+            if self._pairs_at is not None and (fetch or observe.enabled()):
                 with observe.span("serve.program.wait", category="serve",
                                   program=name):
-                    seen = np.asarray(self.state[1])
+                    seen = np.asarray(self.state[self._pairs_at])
                 pairs, self._pairs_seen = seen - self._pairs_seen, seen
                 routed, hit = int(pairs.sum()), int((pairs > 0).sum())
-                sp.set(routed_pairs=routed, experts_hit=hit,
-                       window_tokens=window_tokens)
+                sp.set(routed_pairs=routed, experts_hit=hit)
+                if self.kv.cfg.window is not None:
+                    sp.set(window_tokens=window_tokens)
                 self._moe_pairs.inc(routed)
                 self._moe_hit.inc(hit)
                 self._moe_max.inc(int(pairs.max()))
@@ -1245,9 +1281,10 @@ class ServeEngine:
         """Whether this replica dispatches a plain tick before it reads the
         one before (docs/serving.md §Decoding ahead): it has no drafter,
         which needs a tick's tokens to propose for the next, and its plain
-        tick reads nothing but its tokens (the afmoe family's reads the
-        held experts' pair counts, carried in the donated state)."""
-        return self._drafter is None and self.kv.cfg.window is None
+        tick reads nothing but its tokens (the afmoe and pangu_ultra_moe
+        families' read the held experts' pair counts, carried in the
+        donated state)."""
+        return self._drafter is None and self._pairs_at is None
 
     def _decode_step(self) -> None:
         if self._decodes_ahead():
@@ -1322,8 +1359,7 @@ class ServeEngine:
             args = (toks, jnp.asarray(positions), jnp.asarray(table))
             n_lanes = len(slots)
             attended = int(positions.sum()) + n_lanes
-            kv_blocks = kv_blocks_walked(positions[slots] + 1,
-                                         *self._kernel_pool)
+            kv_blocks = self._walked(positions[slots] + 1)
             self._decode_steps.inc()
             self._attended.inc(attended)
             self._kv_blocks.inc(kv_blocks)
@@ -1425,8 +1461,7 @@ class ServeEngine:
             # A lane at position p attends over p + 1 tokens, its new one
             # included (idle lanes sit at 0 and attend over nothing).
             attended = int(positions.sum()) + n_lanes
-            kv_blocks = kv_blocks_walked(positions[slots] + 1,
-                                         *self._kernel_pool)
+            kv_blocks = self._walked(positions[slots] + 1)
         logits, greedy = self._run_program(
             "decode", *args, lanes=n_lanes, attended=attended,
             positions=n_lanes,

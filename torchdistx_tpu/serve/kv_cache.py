@@ -74,6 +74,15 @@ first token, from which the programs count positions in the row.  A
 released page's content is gone: nothing of the group can be shared
 with a later reader, and a preempted sequence prefills again.
 
+**A latent group** (:attr:`KVCacheConfig.latent`): a stack of latent
+attention (``models/mla.py``) caches one row a token and layer, the
+normed latent and the rotary key that every head shares, and no values:
+a value is read from the same row (its first ``kv_rank`` columns).  Its
+pool is the K pool with one "head" as wide as the row, ``[L, P, 1, page,
+row]``, and the V pool is empty (no page: zero bytes, so the programs
+carry it as every family does).  The free list, the page tables, the
+null page and the gauges are the full group's.
+
 Telemetry (docs/observability.md): ``tdx.serve.kv_pages_in_use``,
 ``tdx.serve.kv_occupancy`` (used token slots / allocated slots in live
 pages — the internal-fragmentation complement),
@@ -165,9 +174,10 @@ class WindowCacheConfig:
 class KVCacheConfig:
     """Shape of the cache, by layer group: the attention layers' pool
     (one K and one V pool over ``n_layers`` layers: the layers that read
-    the whole context), where the stack has recurrent layers their
-    ``state`` group, and where it has windowed attention layers their
-    ``window`` group."""
+    the whole context; with ``latent``, one pool of rows ``head_dim`` wide
+    under one kv head, and an empty V pool), where the stack has
+    recurrent layers their ``state`` group, and where it has windowed
+    attention layers their ``window`` group."""
 
     n_layers: int
     kv_heads: int
@@ -176,6 +186,7 @@ class KVCacheConfig:
     n_pages: int = 64  # includes the reserved null page 0
     state: Optional[StateCacheConfig] = None
     window: Optional[WindowCacheConfig] = None
+    latent: bool = False
 
     @property
     def usable_pages(self) -> int:
@@ -197,6 +208,12 @@ class KVCacheConfig:
         (:mod:`torchdistx_tpu.ops.paged_attention`)."""
         return (self.n_layers, self.n_pages, self.kv_heads,
                 self.page_size, self.head_dim)
+
+    def v_pool_shape(self) -> Tuple[int, int, int, int, int]:
+        """The V pool's shape: the K pool's, or no page at all in a latent
+        group, whose values are the first columns of its rows."""
+        L, P, KV, page, D = self.pool_shape()
+        return (L, 0 if self.latent else P, KV, page, D)
 
     def window_pool_shape(self) -> Tuple[int, int, int, int, int]:
         """[2 * Lw, Pw, KV, page, D]: the window group's one array."""
@@ -713,10 +730,10 @@ def init_window_pool(cfg: KVCacheConfig, dtype, sharding=None) -> "jax.Array":
 
 def init_pools(cfg: KVCacheConfig, dtype,
                sharding=None) -> Tuple["jax.Array", "jax.Array"]:
-    """The zeroed device pools (k_pages, v_pages), [L, P, KV, page, D],
-    committed to ``sharding`` (:func:`pool_sharding`) when given."""
+    """The zeroed device pools (k_pages, v_pages), [L, P, KV, page, D]
+    (a latent group's V pool has no page), committed to ``sharding``
+    (:func:`pool_sharding`) when given."""
     import jax.numpy as jnp
 
-    shape = cfg.pool_shape()
-    return (jnp.zeros(shape, dtype, device=sharding),
-            jnp.zeros(shape, dtype, device=sharding))
+    return (jnp.zeros(cfg.pool_shape(), dtype, device=sharding),
+            jnp.zeros(cfg.v_pool_shape(), dtype, device=sharding))

@@ -63,16 +63,18 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import abstract, chaos, compile_service, observe, transport
 from .. import config as tdx_config
 from ..models import (TransformerConfig, make_afmoe, make_gpt2, make_jamba,
-                      make_llama, make_olmo_hybrid)
-from ..models import afmoe, jamba, olmo_hybrid
+                      make_llama, make_olmo_hybrid, make_pangu_ultra_moe)
+from ..models import afmoe, jamba, mla, olmo_hybrid, pangu_ultra_moe
 from ..models.layers import MLP, apply_rope, default_attention, make_norm
 from ..ops import paged_attention, paged_prefill_attention
+from ..ops.mla import mla_decode, mla_prefill
 from ..utils.logging import get_logger
 from .kv_cache import (KVCacheConfig, StateCacheConfig, WindowCacheConfig,
                        pool_sharding, state_sharding)
@@ -187,6 +189,16 @@ class ServeConfig:
                 raise ValueError(
                     f"n_window_pages={n_window_pages} cannot hold one "
                     f"sequence's chunk and window ({window_row} pages)")
+        if cfg.mla is not None and (spec_on or self.prefix_cache):
+            on = [n for n, v in (("spec_decode", spec_on),
+                                 ("prefix_cache", self.prefix_cache)) if v]
+            raise ValueError(
+                f"{' and '.join(on)} cannot be on for a stack of latent "
+                f"attention: the family has no verify-<k> program yet "
+                f"(speculation), and its prefix cache would have to share "
+                f"latent pages, which no program copies on write (prefix "
+                f"cache); pass ServeConfig(spec_decode=False, "
+                f"prefix_cache=False)")
         if _recurrent(cfg) and (spec_on or self.prefix_cache):
             on = [n for n, v in (("spec_decode", spec_on),
                                  ("prefix_cache", self.prefix_cache)) if v]
@@ -235,9 +247,15 @@ class ResolvedServeConfig:
         a hybrid stack one state slot a lane for its recurrent layers
         (Mamba: ``[16, d_inner]`` and a conv over ``d_inner`` channels;
         Gated DeltaNet: ``[d_k, H d_v]`` and a conv over q, k and v's
-        channels), and for a stack with windowed attention layers their
-        window group."""
+        channels), for a stack with windowed attention layers their
+        window group, and for a stack of latent attention one latent row
+        a token and layer (``models/mla.py``) and no values."""
         window = None
+        if cfg.mla is not None:
+            return KVCacheConfig(
+                n_layers=cfg.n_layers, kv_heads=1,
+                head_dim=mla.cache_width(cfg), page_size=self.page_size,
+                n_pages=self.n_pages, latent=True)
         if cfg.afmoe is not None:
             n_attn, state = afmoe.n_full_layers(cfg), None
             window = WindowCacheConfig(
@@ -285,14 +303,16 @@ class ResolvedServeConfig:
         )
 
 
-FAMILIES = ("gpt2", "llama", "jamba", "afmoe", "olmo_hybrid")
+FAMILIES = ("gpt2", "llama", "jamba", "afmoe", "olmo_hybrid",
+            "pangu_ultra_moe")
 
 
 def model_family(name: str) -> str:
-    """The decode family of a zoo preset name: gpt2, jamba, afmoe and
-    olmo_hybrid (``tiny-olmo-hybrid``) presets by name, any other dense
-    decoder serves through the llama path."""
-    for family in ("gpt2", "jamba", "afmoe", "olmo_hybrid"):
+    """The decode family of a zoo preset name: gpt2, jamba, afmoe,
+    olmo_hybrid (``tiny-olmo-hybrid``) and pangu_ultra_moe presets by
+    name, any other dense decoder serves through the llama path."""
+    for family in ("gpt2", "jamba", "afmoe", "olmo_hybrid",
+                   "pangu_ultra_moe"):
         if family.replace("_", "-") in name:
             return family
     return "llama"
@@ -329,6 +349,12 @@ def make_model(family: str, cfg: TransformerConfig):
             f"{'set' if cfg.olmo_hybrid is not None else 'None'}: the "
             f"olmo_hybrid family, and no other, takes a config with Gated "
             f"DeltaNet layers")
+    if (family == "pangu_ultra_moe") != (cfg.mla is not None):
+        raise ValueError(
+            f"decode family {family!r} with cfg.mla="
+            f"{'set' if cfg.mla is not None else 'None'}: the "
+            f"pangu_ultra_moe family, and no other, takes a config with "
+            f"latent attention")
     if family == "gpt2":
         return make_gpt2(cfg)
     if family == "llama":
@@ -339,6 +365,8 @@ def make_model(family: str, cfg: TransformerConfig):
         return make_afmoe(cfg)
     if family == "olmo_hybrid":
         return make_olmo_hybrid(cfg)
+    if family == "pangu_ultra_moe":
+        return make_pangu_ultra_moe(cfg)
     raise ValueError(
         f"unknown decode family {family!r}; the families that exist: "
         f"{' | '.join(FAMILIES)}")
@@ -908,6 +936,126 @@ def _build_afmoe_fn(cfg, scfg, mesh, kind: str, bucket=None) -> Callable:
     return _program_name(f"tdx_serve_{kind}_{bucket}")(fn)
 
 
+# ---------------------------------------------------------------------------
+# the pangu_ultra_moe family (models/pangu_ultra_moe.py): a latent group
+# ---------------------------------------------------------------------------
+#
+# The K pool is the latent group (serve/kv_cache.py, ``latent``): one row
+# a token and layer, ``[c_kv ; k_rope]`` padded to the lanes, layer ``i``'s
+# page ``p`` at flat row ``i * P + p``; the V pool is empty and rides
+# through untouched.  Behind the pools the programs carry the held
+# experts' pair counts, as the afmoe family's do:
+#
+#   decode       (params, k_pages, v_pages, pairs, tokens [B], positions [B],
+#                 page_table [B, maxp])
+#   prefill-<b>  (params, k_pages, v_pages, pairs, tokens [1, b], length [1],
+#                 page_table [1, maxp])
+#   chunk-<b>    (params, k_pages, v_pages, pairs, tokens [1, b], start [1],
+#                 end [1], page_table [1, maxp])
+#   -> (logits, k_pages, v_pages, pairs)
+#
+# Every layer writes its tokens' rows through ``_page_writer`` and then
+# attends in the form its kind takes (models/mla.py): decode absorbed, in
+# ``tdx_mla_decode`` over the lane's rows; prefill expanded over the
+# prompt's own latents, and a chunk expanded over the latents of every row
+# its table holds (earlier chunks' and its own), both in
+# ``tdx_mla_prefill``.  No verify-<k> and no cow program.
+
+
+def _build_pangu_fn(cfg, scfg, mesh, kind: str, bucket=None) -> Callable:
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "the pangu_ultra_moe programs are one chip's share of an "
+            "expert-parallel group: the exchange that adds the other chips' "
+            "experts is not built yet (ROADMAP M1(a)), and the latent "
+            "kernels are not partitioned over a mesh")
+    m = cfg.mla
+    P, page = scfg.n_pages, scfg.page_size
+    sm = mla.scale(cfg)
+    # A chunk rebuilds keys and values from the latents of the smallest of
+    # these row counts that holds its context; where the table is at least
+    # eight chunks wide (17,408 rows at the cell's sizes, against chunks
+    # of up to 2,048), else the table's width alone.
+    width = scfg.max_pages_per_seq * page
+    extents = np.asarray([width] if width < 8 * (bucket or width) else
+                         [bucket, 2 * bucket, 4 * bucket, width])
+
+    def body(params, k_pages, v_pages, pairs, tokens, start, end,
+             page_table):
+        p = pangu_ultra_moe.param_tree(params["params"])
+        S = tokens.shape[1]
+        positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
+        valid = positions < end[:, None]
+        angles = mla.rope_angles(cfg, positions)
+        shape = k_pages.shape
+        pool = {"rows": k_pages.reshape((-1,) + shape[2:])}
+        write = _page_writer(page_table, start, end, S, page)
+
+        def attention(i, lp, h):
+            q_nope, q_rope, c_kv, k_rope = mla.project(cfg, lp, h, angles)
+            pool["rows"] = write(pool["rows"], mla.cache_rows(cfg, c_kv,
+                                                              k_rope), i * P)
+            if kind == "decode":
+                o = mla_decode(
+                    mla.decode_query(cfg, lp, q_nope[:, 0], q_rope[:, 0]),
+                    pool["rows"], end, page_table + i * P,
+                    v_width=m.kv_rank, scale=sm)
+                return mla.out(cfg, lp, mla.unabsorb(cfg, lp, o)[:, None])
+            heads = lambda x: jnp.swapaxes(x[0], 0, 1)  # -> [H, S, w]
+
+            def expanded(lat, k_rot):
+                k_nope, v = mla.expand(cfg, lp, lat, heads_major=True)
+                return mla_prefill(heads(q_nope), heads(q_rope), k_nope, v,
+                                   k_rot.astype(cfg.dtype), start[0], end[0],
+                                   scale=sm)
+
+            if kind == "prefill":  # a fresh prompt attends only itself
+                o = expanded(c_kv[0], k_rope[0])
+            else:
+                # The rows of earlier chunks and its own.
+                rows = pool["rows"][page_table[0] + i * P]
+                rows = rows.reshape(-1, rows.shape[-1])
+
+                def upto(n):
+                    return lambda r: expanded(*mla.split_rows(cfg, r[:n]))
+
+                o = jax.lax.switch(
+                    jnp.searchsorted(extents, end[0]),
+                    [upto(n) for n in extents.tolist()], rows)
+            return mla.out(cfg, lp, jnp.swapaxes(o, 0, 1)[None])
+
+        x, counts = pangu_ultra_moe.layers(
+            cfg, p, pangu_ultra_moe.embed_tokens(cfg, p, tokens), valid,
+            attention)
+        if kind == "decode":
+            logits = pangu_ultra_moe.head_logits(cfg, p, x)[:, 0]
+        else:
+            last = jnp.clip(end - 1 - start, 0, S - 1)[:, None, None]
+            x_last = jnp.take_along_axis(x, jnp.broadcast_to(
+                last, (x.shape[0], 1, x.shape[2])), axis=1)
+            logits = pangu_ultra_moe.head_logits(cfg, p, x_last)[0, 0]
+        return (logits, pool["rows"].reshape(shape), v_pages,
+                pairs + counts)
+
+    if kind == "decode":
+        def fn(params, k_pages, v_pages, pairs, tokens, positions,
+               page_table):
+            # Idle and mid-prefill lanes (position 0) attend and route
+            # nothing: a length of 0, the kernel's idle contract.
+            end = jnp.where(positions > 0, positions + 1, 0)
+            return body(params, k_pages, v_pages, pairs, tokens[:, None],
+                        positions, end, page_table)
+
+        return _program_name("tdx_serve_decode")(fn)
+    if kind == "prefill":
+        def fn(params, k_pages, v_pages, pairs, tokens, length, page_table):
+            return body(params, k_pages, v_pages, pairs, tokens,
+                        jnp.zeros_like(length), length, page_table)
+    else:
+        fn = body
+    return _program_name(f"tdx_serve_{kind}_{bucket}")(fn)
+
+
 def build_decode_fn(family: str, cfg: TransformerConfig,
                     scfg: ResolvedServeConfig, mesh=None) -> Callable:
     """The batched decode-step program:
@@ -918,6 +1066,8 @@ def build_decode_fn(family: str, cfg: TransformerConfig,
     the null page, their logits are ignored).  A hybrid stack's programs
     carry the recurrent state too (the section above)."""
     model = make_model(family, cfg)
+    if cfg.mla is not None:
+        return _build_pangu_fn(cfg, scfg, mesh, "decode")
     if cfg.afmoe is not None:
         return _build_afmoe_fn(cfg, scfg, mesh, "decode")
     if _recurrent(cfg):
@@ -959,6 +1109,8 @@ def build_prefill_fn(family: str, cfg: TransformerConfig,
     page_table [1, maxp]) -> (logits [vocab], k_pages, v_pages)`` —
     logits are the LAST VALID position's (the first generated token)."""
     model = make_model(family, cfg)
+    if cfg.mla is not None:
+        return _build_pangu_fn(cfg, scfg, None, "prefill", bucket)
     if cfg.afmoe is not None:
         return _build_afmoe_fn(cfg, scfg, None, "prefill", bucket)
     if _recurrent(cfg):
@@ -1003,6 +1155,8 @@ def build_chunk_prefill_fn(family: str, cfg: TransformerConfig,
     Logits are the last valid position's: meaningful (the first
     generated token) only on the final chunk, ignored otherwise."""
     model = make_model(family, cfg)
+    if cfg.mla is not None:
+        return _build_pangu_fn(cfg, scfg, None, "chunk", bucket)
     if cfg.afmoe is not None:
         return _build_afmoe_fn(cfg, scfg, None, "chunk", bucket)
     if _recurrent(cfg):
@@ -1058,6 +1212,11 @@ def build_verify_fn(family: str, cfg: TransformerConfig,
             "no verify-<k> program for the afmoe family yet: a rejected "
             "draft would have to be rolled back out of a window group that "
             "has already returned the pages behind it")
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            "no verify-<k> program for the pangu_ultra_moe family yet: "
+            "speculation waits on ROADMAP S7 / D6, and the family's own "
+            "drafter (multi-token prediction) on M3")
     if _recurrent(cfg):
         raise NotImplementedError(
             "no verify-<k> program for a stack with recurrent layers: the "
@@ -1253,14 +1412,21 @@ def serve_program_specs(
     pool_sh = pool_sharding(mesh, cfg.kv_heads)
     pool_sds = jax.ShapeDtypeStruct(kv.pool_shape(), cfg.dtype,
                                     sharding=pool_sh)
+    v_sds = jax.ShapeDtypeStruct(kv.v_pool_shape(), cfg.dtype,
+                                 sharding=pool_sh)
     i32 = jnp.int32
     B, maxp = scfg.max_batch, scfg.max_pages_per_seq
     # What every program but init takes, CONSUMES and returns: the two
     # pools and, for a hybrid stack, the recurrent layer group's state
     # behind them (such a stack's one-sequence programs also take the
     # lane's slot; it has no cow and no verify programs).
-    carried, carried_sh = (pool_sds, pool_sds), (pool_sh, pool_sh)
+    carried, carried_sh = (pool_sds, v_sds), (pool_sh, pool_sh)
     slot_sds = lane_sds = ()  # operands behind a one-sequence / lanes table
+    if kv.latent:
+        # The held experts' pair counts, as the afmoe family carries them.
+        carried += (jax.ShapeDtypeStruct((pangu_ultra_moe.n_expert_layers(
+            cfg), cfg.experts.held_experts), i32),)
+        carried_sh += (None,)
     if kv.window is not None:
         # The window group and the held experts' pair counts, and behind
         # every page table the group's own row and its first position.
@@ -1340,7 +1506,7 @@ def serve_program_specs(
             f"chunk-{b}", build_chunk_prefill_fn(family, cfg, scfg, b),
             jax.ShapeDtypeStruct((1, b), i32), one, one,
             jax.ShapeDtypeStruct((1, maxp), i32), *slot_sds))
-    pages_only = kv.state is None and kv.window is None
+    pages_only = kv.state is None and kv.window is None and not kv.latent
     if pages_only:
         specs.append(program("cow", build_cow_fn(), one, one, model=False))
     specs.append(program(
