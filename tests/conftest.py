@@ -6,7 +6,14 @@ the moral equivalent of the reference's CPU-only CI exercising its CUDA
 build (reference .github/workflows/push.yaml:30-48).
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
+import sys
+import threading
+
+import pytest
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -35,6 +42,15 @@ def pytest_configure(config):
         "slow: multi-second cases (long hang injection) excluded from the "
         "tier-1 run's -m 'not slow'; `make chaos-test` includes them",
     )
+    config.addinivalue_line(
+        "markers",
+        f"wall_limit(seconds): the test's own wall limit, for one that "
+        f"needs more than the default {WALL_LIMIT_S} s",
+    )
+    # The stream the wall limit dumps stacks to, taken while no capture
+    # is on (a capturing stream may have no descriptor).
+    global _stacks_to
+    _stacks_to = os.fdopen(os.dup(sys.__stderr__.fileno()), "w")
 
     # A donated buffer that the lowering cannot alias to an output is an
     # error, as it is in ``serve.programs.compile_serving_program`` itself
@@ -43,3 +59,64 @@ def pytest_configure(config):
     # program ignores it locally, by design.)
     config.addinivalue_line(
         "filterwarnings", "error:Some donated buffers were not usable")
+
+
+# Each phase of a test (setup, call, teardown) has a wall limit, so that
+# a hang costs one test and not the whole run.  The slowest test takes
+# about a third of it on a loaded CI box.
+WALL_LIMIT_S = 300
+# Past the limit by this much, a main thread stuck in native code (where
+# the alarm's handler cannot run) ends the process, with every thread's
+# stack on stderr.
+_HARD_GRACE_S = 60
+_stacks_to = None
+
+
+class WallLimitExceeded(BaseException):
+    """Not an ``Exception``: the code under test (an engine requeueing a
+    failed step, say) must not catch it as one of its own faults."""
+
+
+def _on_alarm(signum, frame):
+    faulthandler.dump_traceback(_stacks_to, all_threads=True)
+    raise WallLimitExceeded("the test ran into its wall limit; every "
+                            "thread's stack went to stderr")
+
+
+@contextlib.contextmanager
+def _wall_limit(item):
+    """Arm the alarm over one phase of ``item``, in the main thread only
+    (where a signal handler runs)."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    mark = item.get_closest_marker("wall_limit")
+    limit = mark.args[0] if mark else WALL_LIMIT_S
+    old = signal.signal(signal.SIGALRM, _on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    faulthandler.dump_traceback_later(limit + _HARD_GRACE_S, exit=True,
+                                      file=_stacks_to)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    with _wall_limit(item):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with _wall_limit(item):
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    with _wall_limit(item):
+        return (yield)

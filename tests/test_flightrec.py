@@ -21,6 +21,11 @@ import torchdistx_tpu.config as tdx_config
 from torchdistx_tpu import observe
 from torchdistx_tpu.observe import flightrec
 
+# The compile deadline against the injected 30 s hang: far under the
+# hang, far over a legitimate compile on a loaded CI box (1 s was not;
+# ROADMAP D18).  test_materialize_chaos.py holds the same value.
+HANG_DEADLINE_S = 4.0
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CLI = os.path.join(REPO, "tools", "tdx_trace.py")
 
@@ -148,7 +153,8 @@ class TestTriggers:
         try:
             with tdx_config.override(
                 flight_dir=flight, fault_plan="compile@1=hang:30",
-                materialize_pipeline="off", compile_deadline_s=1.0,
+                materialize_pipeline="off",
+                compile_deadline_s=HANG_DEADLINE_S,
             ):
                 params = materialize_module_jax(
                     deferred_init(torch.nn.Linear, 8, 4)

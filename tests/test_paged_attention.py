@@ -25,6 +25,11 @@ from torchdistx_tpu.models.layers import default_attention
 # ``ops.paged_attention`` the attribute is the function; this is the module.
 pa = importlib.import_module("torchdistx_tpu.ops.paged_attention")
 
+# The reference compiled once a shape: run eagerly, each of its dozen
+# operations would compile on its own at every new shape.
+_reference = jax.jit(paged_attention_reference,
+                     static_argnames="v_page_offset")
+
 
 def _to_pool(tok_major):
     """Token-major pages [P, page, KV, D] -> the pool layout
@@ -45,8 +50,8 @@ def _assert_live_rows_match(out, ref, lengths, atol):
 def _rand_case(seed, *, B, H, KV, D, page, n_pages, maxp, lengths, dtype):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(B, H, D), dtype)
-    kp = _to_pool(jnp.asarray(rng.randn(n_pages, page, KV, D), dtype))
-    vp = _to_pool(jnp.asarray(rng.randn(n_pages, page, KV, D), dtype))
+    kp = jnp.asarray(_to_pool(rng.randn(n_pages, page, KV, D)), dtype)
+    vp = jnp.asarray(_to_pool(rng.randn(n_pages, page, KV, D)), dtype)
     # Page tables point at a shuffled, non-overlapping page assignment —
     # physical discontiguity is the point of the paged layout.
     perm = rng.permutation(n_pages - 1) + 1  # never the null page
@@ -68,7 +73,7 @@ def test_kernel_matches_reference_ragged(dtype, atol, H, KV):
         0, B=B, H=H, KV=KV, D=D, page=page, n_pages=16, maxp=maxp,
         lengths=lengths, dtype=dtype,
     )
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     assert out.dtype == q.dtype
     np.testing.assert_allclose(
@@ -84,7 +89,7 @@ def test_kernel_matches_reference_page_sizes(page):
         1, B=B, H=H, KV=KV, D=D, page=page, n_pages=32, maxp=maxp,
         lengths=lengths, dtype=jnp.float32,
     )
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -98,9 +103,7 @@ def test_idle_lane_outputs_zero():
     )
     out = paged_attention(q, kp, vp, jnp.asarray([0, 5], jnp.int32), table)
     assert np.all(np.asarray(out[0]) == 0.0)
-    ref = paged_attention_reference(
-        q, kp, vp, jnp.asarray([0, 5], jnp.int32), table
-    )
+    ref = _reference(q, kp, vp, jnp.asarray([0, 5], jnp.int32), table)
     np.testing.assert_allclose(np.asarray(out[1]), np.asarray(ref[1]),
                                atol=1e-5)
 
@@ -173,7 +176,7 @@ def test_reference_gqa_grouping_matches_per_head_loop():
         5, B=B, H=H, KV=KV, D=D, page=page, n_pages=8, maxp=maxp,
         lengths=[6, 8], dtype=jnp.float32,
     )
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     groups = H // KV
     # Undo the pool layout by hand: [B, maxp, KV, page, D] -> token-major.
     k = kp[table].transpose(0, 1, 3, 2, 4).reshape(B, maxp * page, KV, D)
@@ -230,7 +233,7 @@ def test_walk_at_block_boundaries(blocks, off):
     q, kp, vp, lens, table = _rand_case(
         10 + blocks, B=2, page=page, n_pages=2 * maxp + 1, maxp=maxp,
         lengths=[n, 1], **WALK)
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -243,7 +246,7 @@ def test_walk_table_width_no_multiple_of_the_block(page):
     q, kp, vp, lens, table = _rand_case(
         20, B=2, page=page, n_pages=2 * maxp + 1, maxp=maxp,
         lengths=[maxp * page, maxp * page - page - 1], **WALK)
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -263,7 +266,7 @@ def test_walk_mixed_batch(lengths):
     q, kp, vp, lens, table = _rand_case(
         21, B=B, page=page, n_pages=B * maxp + 1, maxp=maxp,
         lengths=lengths, **WALK)
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     _assert_live_rows_match(out, ref, lengths, 1e-5)
 
@@ -281,7 +284,7 @@ def test_walk_head_layouts_of_the_served_families(H, KV, D, dtype, atol):
     q, kp, vp, lens, table = _rand_case(
         22, B=4, H=H, KV=KV, D=D, page=page, n_pages=4 * maxp + 1,
         maxp=maxp, lengths=lengths, dtype=dtype)
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     assert out.dtype == q.dtype
     _assert_live_rows_match(out, ref, lengths, atol)
@@ -298,7 +301,7 @@ def test_page_kernel_matches_reference(dtype, atol):
     q, kp, vp, lens, table = _rand_case(
         23, B=4, H=4, KV=4, D=64, page=page, n_pages=4 * maxp + 1,
         maxp=maxp, lengths=lengths, dtype=dtype)
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = pa._paged_attention(q, kp, vp, lens, table, 1, True, walk=False)
     _assert_live_rows_match(out, ref, lengths, atol)
 
@@ -315,7 +318,7 @@ def test_walk_flat_pool_with_a_layer_base():
     table = table % P  # page ids of one layer
     for layer in range(L):
         base = layer * P
-        ref = paged_attention_reference(
+        ref = _reference(
             q, kp[base:base + P], vp[base:base + P], lens, table)
         out = paged_attention(q, kp, vp, lens, table + base)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -345,7 +348,7 @@ def test_nothing_past_the_length_is_touched(walk):
         q, kp, vp, lens, wild, pages_per_block(1, page, D, jnp.float32),
         pltpu.InterpretParams(out_of_bounds_reads="raise",
                               uninitialized_memory="nan"), walk=walk)
-    ref = paged_attention_reference(
+    ref = _reference(
         q, jnp.nan_to_num(kp), jnp.nan_to_num(vp), lens, jnp.asarray(table))
     _assert_live_rows_match(out, ref, lengths, 1e-5)
 
@@ -385,7 +388,7 @@ def test_length_past_the_table_is_held_to_the_table():
     q, kp, vp, _, table = _rand_case(
         26, B=2, page=page, n_pages=8, maxp=maxp, lengths=[0, 0], **WALK)
     lens = jnp.asarray([maxp * page + 40, 9], jnp.int32)
-    ref = paged_attention_reference(q, kp, vp, lens, table)
+    ref = _reference(q, kp, vp, lens, table)
     out = paged_attention(q, kp, vp, lens, table)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -411,15 +414,14 @@ def test_walk_from_a_first_position(ppb, v_off):
     table = jnp.asarray(rng.randint(1, 96, (B, maxp)), jnp.int32)
     out = _paged_attention(q, pool, pool, lengths, table, ppb, True,
                            starts=starts, v_page_offset=v_off)
-    ref = paged_attention_reference(q, pool, pool, lengths, table,
-                                    starts=starts, v_page_offset=v_off)
+    ref = _reference(q, pool, pool, lengths, table, starts=starts,
+                     v_page_offset=v_off)
     live = np.asarray(lengths) > 0
     np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
                                atol=1e-5)
     assert np.all(np.asarray(out)[~live] == 0.0)
     # and it is NOT the whole context: the mask is doing something
-    whole = paged_attention_reference(q, pool, pool, lengths, table,
-                                      v_page_offset=v_off)
+    whole = _reference(q, pool, pool, lengths, table, v_page_offset=v_off)
     assert np.abs(np.asarray(whole)[1] - np.asarray(ref)[1]).max() > 1e-3
 
 

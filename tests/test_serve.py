@@ -112,6 +112,37 @@ def test_eos_retires_early(llama_engine):
     assert out["e"] == [first]  # retired at the first token, during prefill
 
 
+def test_oracle_compiles_one_forward_a_length(llama_params):
+    """The oracle compiles one forward a sequence length (not one program
+    an operation), held per ``(family, cfg)``: a second call over lengths
+    already seen compiles nothing, and its tokens and final logits are
+    the stock model's, applied eagerly at each step."""
+    from torchdistx_tpu.observe import compilelog
+    from torchdistx_tpu.serve.programs import make_model
+
+    compilelog.install()
+    compiles = observe.counter("tdx.jax.backend_compiles")
+    lowered = observe.counter("tdx.jax.lower_s")
+    prompt, n = list(range(3, 44)), 5  # lengths 41..45: no other case's
+    c0 = compiles.value
+    toks, logits = oracle_generate("llama", LLAMA, llama_params, prompt, n)
+    assert compiles.value - c0 <= n
+    c1, l1 = compiles.value, lowered.value
+    again = oracle_generate("llama", LLAMA, llama_params, prompt, n)
+    assert (compiles.value, lowered.value) == (c1, l1)
+    assert again[0] == toks
+    np.testing.assert_array_equal(again[1], logits)
+
+    model, seq, eager = make_model("llama", LLAMA), list(prompt), []
+    for _ in range(n):
+        last = np.asarray(model.apply(
+            llama_params, jnp.asarray([seq], jnp.int32))[0, -1], np.float32)
+        eager.append(int(np.argmax(last)))
+        seq.append(eager[-1])
+    assert toks == eager
+    np.testing.assert_allclose(logits, last, atol=1e-4)
+
+
 def test_page_pool_exhaustion_preempts_and_recovers(llama_params):
     """A pool too small for two long generations forces preemption: the
     youngest lane is requeued (counted), and every output still equals

@@ -14,6 +14,7 @@ differ by the order of float32 sums alone, 1e-5 of logits of order 1;
 moves a logit by 0.1 and more (the planted cases say so)."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -86,10 +87,16 @@ def _prompt(seed, n):
     return [int(t) for t in np.random.default_rng(seed).integers(0, 256, n)]
 
 
+@functools.cache
+def _forward(**faults):
+    """One reference a planted fault (and one with none): its layer
+    programs compile once a padded length, not once a call."""
+    return ref.Forward(C, None, 64, **faults)
+
+
 def _reference(weights, prompt, out, **faults):
     seq = list(prompt) + list(out[:-1])
-    return ref.Forward(C, None, 64, **faults).logits(
-        weights, seq, len(prompt) - 1, len(out))
+    return _forward(**faults).logits(weights, seq, len(prompt) - 1, len(out))
 
 
 def _serve(eng, reqs, **knobs):
@@ -116,9 +123,9 @@ def test_the_configuration_is_the_tiny_preset_but_for_names():
 
 def test_full_forward_equals_the_reference(weights):
     toks = _prompt(1, 90)                       # five windows and a half
-    got = make_afmoe(TCFG).apply(fam.param_tree(weights),
-                                 jnp.asarray([toks], jnp.int32))[0]
-    want = ref.Forward(C, None, 64).logits(weights, toks, 0, len(toks))
+    got = jax.jit(make_afmoe(TCFG).apply)(fam.param_tree(weights),
+                                          jnp.asarray([toks], jnp.int32))[0]
+    want = _forward().logits(weights, toks, 0, len(toks))
     np.testing.assert_allclose(np.asarray(got), want, atol=ATOL)
     assert float(np.std(want)) > 0.5            # logits of order 1, not 0
 
@@ -341,7 +348,7 @@ def test_spans_and_counters_carry_the_routing_and_the_window(engine):
         spans = [e for e in list(observe.tracer().events)[n0:]
                  if e.get("name") == "serve.program"]
     finally:
-        observe.enable(False)
+        observe.enable(None)
     pairs, hit, fullest = (c.value - b for c, b in zip(counters, before))
     assert spans and all(
         {"routed_pairs", "experts_hit", "window_tokens"} <= set(e["args"])
@@ -396,7 +403,7 @@ def test_with_telemetry_off_a_chunk_that_brings_no_logits_waits_for_nothing(
         loud = _serve(engine, req("l"), prefill_chunk=8)["l"][0]
         on = total.value - before
     finally:
-        observe.enable(False)
+        observe.enable(None)
     assert quiet == loud and off == on > 0
     # 40 tokens in chunks of 8: four chunks fetch nothing, the fifth and
     # the decode ticks fetch the counts with their logits

@@ -14,6 +14,7 @@ times under what any of the planted faults below moves them by.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -82,12 +83,18 @@ def _prompt(seed, n):
     return [int(t) for t in np.random.default_rng(seed).integers(0, 256, n)]
 
 
+@functools.cache
+def _forward():
+    """One reference for the file: its layer programs compile once a
+    padded length, not once a call."""
+    return ref.Forward(C, None, 64)
+
+
 def _reference(weights, prompt, out):
     """Reference logits that predict each served token (full forward over
     the prompt and the served tokens before it)."""
     seq = list(prompt) + list(out[:-1])
-    return ref.Forward(C, None, 64).logits(weights, seq, len(prompt) - 1,
-                                           len(out))
+    return _forward().logits(weights, seq, len(prompt) - 1, len(out))
 
 
 def _serve(eng, reqs, **knobs):
@@ -113,18 +120,19 @@ def test_full_forward_equals_the_reference(weights, what):
     toks = _prompt(1, 40)
     p = prog.param_tree(fam.param_tree(weights)["params"])
     if what == "model":
-        want = ref.Forward(C, None, 64).logits(weights, toks, 0, 40)
+        want = _forward().logits(weights, toks, 0, 40)
         got = jax.jit(lambda t: prog.full_forward(TCFG, p, t))(
             jnp.asarray([toks]))[0]
     else:
         v = jax.random.normal(jax.random.PRNGKey(3), (40, 64), jnp.float32)
         lw = {k: a.astype(jnp.float32)
               for k, a in ref.layer_weights(C, weights, 3).items()}
-        want = ref.mamba_mixer(C, None, None, v, lw)
+        want = jax.jit(lambda v, lw: ref.mamba_mixer(C, None, None, v, lw))(
+            v, lw)
         m = jax.tree.map(lambda a: a[3], p["mamba"])
-        got, s, tail = prog.mamba_mixer(
+        got, s, tail = jax.jit(lambda v: prog.mamba_mixer(
             TCFG, m, v[None], jnp.zeros((1, 16, 128)),
-            jnp.zeros((3, 1, 128)), jnp.asarray([40], jnp.int32))
+            jnp.zeros((3, 1, 128)), jnp.asarray([40], jnp.int32)))(v)
         got = got[0]
         assert float(jnp.abs(s).max()) > 1e-3  # the recurrence carries
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL)

@@ -17,6 +17,7 @@ them by (``tests/benchmark/test_bench_olmo_hybrid.py``).
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -88,12 +89,18 @@ def _prompt(seed, n):
     return [int(t) for t in np.random.default_rng(seed).integers(0, 256, n)]
 
 
+@functools.cache
+def _forward():
+    """One reference for the file: its layer programs compile once a
+    padded length, not once a call."""
+    return ref.Forward(C, None, 128)
+
+
 def _reference(weights, prompt, out):
     """Reference logits that predict each served token (full forward over
     the prompt and the served tokens before it)."""
     seq = list(prompt) + list(out[:-1])
-    return ref.Forward(C, None, 128).logits(weights, seq, len(prompt) - 1,
-                                            len(out))
+    return _forward().logits(weights, seq, len(prompt) - 1, len(out))
 
 
 def _serve(eng, reqs, **knobs):
@@ -114,6 +121,11 @@ def _serve(eng, reqs, **knobs):
 # -- the two kernels against the rule a position at a time --------------------
 
 
+# The rule, compiled once a shape (eagerly, each operation around its
+# scan would compile on its own).
+_recurrence = jax.jit(gdn.gdn_recurrence)
+
+
 def _rule_inputs(seed, T, H, dk, dv):
     rng = np.random.default_rng(seed)
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
@@ -131,9 +143,8 @@ def test_the_chunk_kernel_is_the_rule(S, n_valid):
     q, k, v, beta, g, s0 = _rule_inputs(S + n_valid, S, 3, 16, 32)
     o, s = gdn.gdn_chunk(q, k, v, beta, g, s0, jnp.int32(n_valid),
                          interpret=True)
-    want_o, want_s = gdn.gdn_recurrence(q[:n_valid], k[:n_valid],
-                                        v[:n_valid], beta[:n_valid],
-                                        g[:n_valid], s0)
+    want_o, want_s = _recurrence(q[:n_valid], k[:n_valid], v[:n_valid],
+                                 beta[:n_valid], g[:n_valid], s0)
     np.testing.assert_allclose(np.asarray(o[:n_valid]), np.asarray(want_o),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(s), np.asarray(want_s),
@@ -161,7 +172,7 @@ def test_the_decode_kernel_is_the_rule_in_place(lanes, live):
         if not live[b]:
             np.testing.assert_array_equal(after[layer, b], before[layer, b])
             continue
-        want_o, want_s = gdn.gdn_recurrence(
+        want_o, want_s = _recurrence(
             q[b:b + 1], k[b:b + 1], v[b:b + 1], beta[b:b + 1], g[b:b + 1],
             jnp.asarray(s_b))
         np.testing.assert_allclose(np.asarray(o[b]), np.asarray(want_o[0]),
@@ -182,20 +193,21 @@ def test_full_forward_equals_the_reference(weights, what):
     toks = _prompt(1, 100)
     p = prog.param_tree(fam.param_tree(weights)["params"])
     if what == "model":
-        want = ref.Forward(C, None, 128).logits(weights, toks, 0, 100)
+        want = _forward().logits(weights, toks, 0, 100)
         got = jax.jit(lambda t: prog.full_forward(TCFG, p, t))(
             jnp.asarray([toks]))[0]
     else:
         x = jax.random.normal(jax.random.PRNGKey(3), (100, 64), jnp.float32)
         lw = {k: a.astype(jnp.float32)
               for k, a in ref.layer_weights(C, weights, 4).items()}
-        want = ref.linear_layer(C, None, None, x, lw)
+        want = jax.jit(lambda x, lw: ref.linear_layer(C, None, None, x, lw))(
+            x, lw)
         m = jax.tree.map(lambda a: a[3], p["gdn"])
         f = jax.tree.map(lambda a: a[4], p["ffn"])
         mixer = lambda h: prog.gdn_mixer(
             TCFG, m, h, jnp.zeros((1, 16, 64)), jnp.zeros((3, 1, 128)),
             jnp.asarray([100], jnp.int32))[0]
-        got = prog.block(TCFG, f, x[None], mixer)[0]
+        got = jax.jit(lambda x: prog.block(TCFG, f, x[None], mixer))(x)[0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL)
 
 

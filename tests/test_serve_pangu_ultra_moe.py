@@ -134,6 +134,12 @@ def test_lanes_are_independent(engine):
 
 # -- the two kernels against their oracles -----------------------------------
 
+# The oracles compiled once a shape: eagerly, each operation would compile
+# on its own.
+_decode_reference = jax.jit(mla_ops.mla_decode_reference,
+                            static_argnames="v_width")
+_prefill_reference = jax.jit(mla_ops.mla_prefill_reference)
+
 
 @pytest.mark.parametrize("block_tokens", [8, 512])
 def test_the_decode_kernel_equals_its_oracle(monkeypatch, block_tokens):
@@ -148,8 +154,7 @@ def test_the_decode_kernel_equals_its_oracle(monkeypatch, block_tokens):
                         .reshape(B, maxp), jnp.int32)
     lengths = jnp.asarray([7, 0, 22], jnp.int32)
     got = mla_ops.mla_decode(q, pool, lengths, table, v_width=R, scale=0.3)
-    want = mla_ops.mla_decode_reference(q, pool, lengths, table, v_width=R,
-                                        scale=0.3)
+    want = _decode_reference(q, pool, lengths, table, v_width=R, scale=0.3)
     np.testing.assert_allclose(np.asarray(got)[[0, 2]],
                                np.asarray(want)[[0, 2]], atol=1e-5)
     assert not np.asarray(got[1]).any()                   # idle: a zero row
@@ -166,7 +171,7 @@ def test_the_prefill_kernel_equals_its_oracle(monkeypatch, start, end):
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     args = (f(H, S, dn), f(H, S, dr), f(H, T, dn), f(H, T, dv), f(T, dr))
     got = mla_ops.mla_prefill(*args, start, end, scale=0.2)
-    want = mla_ops.mla_prefill_reference(*args, start, end, scale=0.2)
+    want = _prefill_reference(*args, start, end, scale=0.2)
     n = end - start
     np.testing.assert_allclose(np.asarray(got)[:, :n],
                                np.asarray(want)[:, :n], atol=1e-5)

@@ -1931,6 +1931,14 @@ def spin_up_replica(
     return engine
 
 
+@functools.lru_cache(maxsize=16)
+def _oracle_forward(family: str, cfg: TransformerConfig):
+    """The stock model's forward over one sequence, compiled once a
+    length: the last position's logits."""
+    model = make_model(family, cfg)
+    return jax.jit(lambda params, toks: model.apply(params, toks)[0, -1])
+
+
 def oracle_generate(
     family: str,
     cfg: TransformerConfig,
@@ -1943,14 +1951,19 @@ def oracle_generate(
     growing sequence through the stock flax model, argmax each step.
     Returns ``(generated_tokens, final_step_logits)`` — what the engine
     must reproduce for the same request, whatever batching, paging,
-    preemption, or faults happened along the way."""
-    model = make_model(family, cfg)
+    preemption, or faults happened along the way.
+
+    Each step is one compiled forward at the sequence's exact length
+    (no padding, so every reduction runs over what the stock model
+    reads), held per ``(family, cfg)``: a length seen before compiles
+    nothing."""
+    forward = _oracle_forward(family, cfg)
     toks = list(prompt)
     out: List[int] = []
     logits_last = None
     for _ in range(max_new_tokens):
-        logits = model.apply(params, jnp.asarray([toks], jnp.int32))
-        logits_last = np.asarray(logits[0, -1], np.float32)
+        logits_last = np.asarray(
+            forward(params, np.asarray([toks], np.int32)), np.float32)
         t = int(np.argmax(logits_last))
         out.append(t)
         toks.append(t)
